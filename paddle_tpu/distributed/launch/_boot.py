@@ -1,9 +1,9 @@
 """Worker bootstrap for the launch controller.
 
 Two jobs before the user script becomes __main__:
-- CPU pinning (when PADDLE_LAUNCH_CPU_DEVICES is set): a TPU PJRT plugin
-  can override the JAX_PLATFORMS env var, so pinning must go through the
-  jax config API inside the worker process (see device.pin_cpu).
+- CPU pinning (when PADDLE_LAUNCH_CPU_DEVICES is set): the pin goes
+  through the jax config API inside the worker process, before anything
+  initializes a backend (see device.pin_cpu).
 - Liveness heartbeat (when PADDLE_HEARTBEAT_FILE is set): start the beat
   thread the controller's hang watchdog relies on (reference
   fleet/elastic/manager.py keepalive).

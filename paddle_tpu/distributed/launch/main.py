@@ -8,7 +8,10 @@ not one per accelerator — a v5p-16 pod slice with 4 hosts is
 - on a single node (`--nnodes 1`, the default) can still spawn N local
   processes with a virtual CPU mesh for testing multi-process rendezvous
   (`--nproc_per_node N --devices cpu`) — the reference's
-  single-node-multi-proc dev loop;
+  single-node-multi-proc dev loop. Without `--devices cpu` it refuses
+  N > 1: a chip belongs to one process at a time, every local worker
+  would see every local chip, and the second to reach them fails or
+  hangs;
 - exports the PADDLE_* env contract consumed by parallel/env.py
   (PADDLE_TRAINER_ID, PADDLE_TRAINERS_NUM, PADDLE_MASTER), mirroring the
   reference's env contract;
@@ -102,7 +105,16 @@ def _parse_args(argv=None):
                    help="collective (the only mode; ps is descoped)")
     p.add_argument("training_script", help="entry script")
     p.add_argument("training_script_args", nargs=argparse.REMAINDER)
-    return p.parse_args(argv)
+    args = p.parse_args(argv)
+    if args.nproc_per_node > 1 and args.devices != "cpu":
+        p.error(
+            f"--nproc_per_node {args.nproc_per_node} without --devices "
+            "cpu: one process drives all of a host's chips (a chip "
+            "belongs to one process at a time, and every local worker "
+            "would open all of them). Use --nproc_per_node 1 on an "
+            "accelerator host, or --devices cpu for the virtual-device "
+            "dev loop.")
+    return args
 
 
 def _worker_env(args, local_rank: int) -> dict:
@@ -211,9 +223,8 @@ def _popen(args, lr, out) -> _Worker:
             env["PADDLE_HEARTBEAT_STEP_MODE"] = "1"
     if args.devices == "cpu" or hb_path:
         # route through the bootstrap: the CPU pin must happen in-process
-        # (a TPU PJRT plugin can override JAX_PLATFORMS — see
-        # device.pin_cpu) and the heartbeat thread must start before the
-        # user script (see heartbeat.py)
+        # before jax initializes (device.pin_cpu) and the heartbeat
+        # thread must start before the user script (see heartbeat.py)
         cmd = [sys.executable, "-m",
                "paddle_tpu.distributed.launch._boot",
                args.training_script, *args.training_script_args]
@@ -313,8 +324,8 @@ def launch(argv: Optional[List[str]] = None) -> int:
             # the worker ASKED for this restart (resilience watchdog: a
             # hung step it will recover from by resuming at the LATEST
             # snapshot) — reference ELASTIC_EXIT_CODE=101 protocol,
-            # fleet/elastic/manager.py:30. Budgeted separately so tunnel
-            # flaps don't consume the crash-restart budget.
+            # fleet/elastic/manager.py:30. Budgeted separately so hung
+            # steps don't consume the crash-restart budget.
             elastic += 1
             mon_elastic.add()
             # degraded-world handshake: a worker that lost devices

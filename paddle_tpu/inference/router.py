@@ -1248,6 +1248,30 @@ class EngineRouter:
         return [np.asarray(r.tokens, np.int32) for r in reqs]
 
 
+def _place_replicas(params, replicas: int, family, tp_axis: str):
+    """(meshes, param trees) that put replica i on local device i (mod
+    the device count): a one-device mesh each, and the param tree
+    uploaded once per device used. (None, the tree as given) on a
+    one-device host or for a family with no SERVING_PARAM_SPECS, where
+    the engines run unplaced on the default device."""
+    import jax
+    from ..parallel.mesh import build_mesh
+    from .serving import family_for
+    devices = jax.local_devices()
+    fam = family_for(family) if isinstance(family, str) else family
+    if len(devices) < 2 or fam.serving_specs is None:
+        return None, [params] * replicas
+    on_device = {}
+    meshes, placed = [], []
+    for i in range(replicas):
+        dev = devices[i % len(devices)]
+        if dev not in on_device:
+            on_device[dev] = jax.device_put(params, dev)
+        meshes.append(build_mesh({tp_axis: 1}, devices=[dev]))
+        placed.append(on_device[dev])
+    return meshes, placed
+
+
 def create_router(params, cfg, replicas: int = 2, family: str = "gpt",
                   max_queue: int = 0, queue_policy: str = "reject",
                   concurrent: bool = True,
@@ -1256,12 +1280,16 @@ def create_router(params, cfg, replicas: int = 2, family: str = "gpt",
                   roles: Optional[Sequence[str]] = None,
                   admission=None, journal_dir: Optional[str] = None,
                   **engine_kw) -> EngineRouter:
-    """Build an EngineRouter over `replicas` identical ServingEngines
-    sharing ONE param tree (read-only at decode — on a single host the
-    replicas share the arrays; in a real deployment each replica's
-    params live on its own devices). `meshes` optionally gives each
-    replica its own tensor-parallel mesh (inference/serving.py mesh=)
-    — the dp(router) x tp(engine) composition. `tracing` turns on
+    """Build an EngineRouter over `replicas` identical ServingEngines.
+    On a host with several devices each replica gets a device of its
+    own — replica i lives on local device i (mod the device count), its
+    parameters, KV pool and tick state all placed there through a
+    one-device mesh — so four replicas on a four-chip host fill four
+    chips, not chip 0 four times; replicas that land on the same device
+    share ONE uploaded param tree (read-only at decode), as all of them
+    do on a one-device host. `meshes` instead gives each replica a
+    tensor-parallel mesh of the caller's choosing (inference/serving.py
+    mesh=) — the dp(router) x tp(engine) composition. `tracing` turns on
     request-scoped tracing at the ROUTER (the engines inherit the
     trace through dispatch — they need no tracer of their own). A
     `telemetry_jsonl=` engine kwarg fans out per replica
@@ -1281,7 +1309,11 @@ def create_router(params, cfg, replicas: int = 2, family: str = "gpt",
         raise ValueError(f"meshes ({len(meshes)}) must match "
                          f"replicas ({replicas})")
     tele = engine_kw.pop("telemetry_jsonl", None)
-    engines = [ServingEngine(params, cfg, family=family,
+    placed = [params] * replicas
+    if meshes is None:
+        meshes, placed = _place_replicas(
+            params, replicas, family, engine_kw.get("tp_axis", "tp"))
+    engines = [ServingEngine(placed[i], cfg, family=family,
                              mesh=None if meshes is None else meshes[i],
                              telemetry_jsonl=(f"{tele}.r{i}" if tele
                                               else None),

@@ -1151,7 +1151,12 @@ class ServingEngine:
             from ..kernels.decode_attention import cache_pspecs
             from ..parallel.mesh import sharding_for
             from jax.sharding import PartitionSpec
-            specs = cache_pspecs(self.paged, self.tp_axis)
+            # tp == 1 (a replica placed on one device): nothing to
+            # split, and a spec that names a size-one axis is not the
+            # spec jit hands back (it returns P()), so the fresh pool
+            # and the donated one would key two jit cache entries
+            specs = (cache_pspecs(self.paged, self.tp_axis)
+                     if self.tp > 1 else {})
             shapes = jax.eval_shape(mk)
             self._cache_pin = {
                 k: sharding_for(specs.get(k, PartitionSpec()),
@@ -1871,7 +1876,7 @@ class ServingEngine:
     def _on_stall_retry(self, attempt: int) -> None:
         """Watchdog backoff observer: count it, and leave a black box
         on the FIRST stall of a tick — a pull that needed backoff is
-        the tunnel-flap post-mortem case even when it recovers."""
+        worth a post-mortem even when it recovers."""
         self._m_retry.add()
         self._flight.note(serving_stall_attempt=attempt,
                           tick=self._ticks)

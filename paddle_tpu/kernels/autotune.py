@@ -6,7 +6,9 @@ winner). TPU-native: the tunables are Pallas grid/block parameters; each
 candidate costs a compile, so tuning is opt-in
 (paddle_tpu.set_flags({'use_autotune': True}) or PADDLE_TPU_AUTOTUNE=1)
 and winners persist to a JSON cache keyed by (op, signature) so the
-compile cost is paid once per machine, not per process.
+compile cost is paid once, not per process. The cache lives inside the
+checkout (perf/autotune.json), where git shows it: a tuned block size
+from a file the repository does not know must not steer a kernel.
 """
 from __future__ import annotations
 
@@ -18,8 +20,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 _CACHE: Dict[str, Any] = {}
 _CACHE_PATH = os.environ.get(
     "PADDLE_TPU_AUTOTUNE_CACHE",
-    os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
-                 "autotune.json"))
+    os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), "perf", "autotune.json"))
 _loaded = False
 _stats = {"hits": 0, "misses": 0, "tuned": 0}
 

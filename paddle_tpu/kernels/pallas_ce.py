@@ -13,7 +13,7 @@ Backward is one pass: d_logits tile = (softmax − onehot) · g, rebuilt
 from the saved per-row logsumexp.
 
 Wired via jax.custom_vjp behind losses.fused_softmax_ce when the
-backend is TPU-class and shapes tile; the jax-level form remains the
+backend is TPU and shapes tile; the jax-level form remains the
 fallback and the numerics oracle.
 """
 from __future__ import annotations

@@ -49,7 +49,7 @@ def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
     """reference: paddle.distributed.spawn. Single-controller JAX drives all
     local chips from one process — spawn degenerates to a direct call. A
     request for nprocs>1 would otherwise "pass" while silently running
-    world_size=1 (VERDICT r2 weak #6), so it warns loudly."""
+    world_size=1, so it warns loudly."""
     if nprocs not in (-1, 0, 1):
         import warnings
         warnings.warn(

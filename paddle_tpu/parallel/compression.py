@@ -85,8 +85,7 @@ def dgc_psum(grad, residual, axis_name: str, k_frac: float = 0.01):
     # psum of per-member [W, k] rows rather than all_gather: identical
     # wire content, and psum's output is vma-invariant so the caller can
     # declare replicated out_specs (all_gather's isn't inferred).
-    from ..utils.compat import axis_size
-    w = axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     k = sent.shape[0]
     all_sent = jax.lax.psum(

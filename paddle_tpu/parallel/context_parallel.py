@@ -201,8 +201,7 @@ def _cp_call(local_fn, q, k, v, mesh, axis, causal):
     qp, kp, vp = _pad_seq(q, n), _pad_seq(k, n), _pad_seq(v, n)
     kv_len = k.shape[1] if kp.shape[1] != k.shape[1] else None
     pspec = P(None, axis, None, None)
-    from ..utils.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(local_fn, axis_name=axis, causal=causal,
                           kv_len=kv_len),
         mesh=mesh, in_specs=(pspec, pspec, pspec), out_specs=pspec)

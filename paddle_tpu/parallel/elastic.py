@@ -351,7 +351,7 @@ class ElasticTrainer:
         if lost and len(lost) >= len(self.world):
             # EVERY lease stale at once is indistinguishable from the
             # monitoring clock having stalled (host suspend, a
-            # minutes-long remote compile) — re-pulse and re-check:
+            # minutes-long compile) — re-pulse and re-check:
             # organically stale leases recover, wedged (truly dead)
             # ones stay stale and the replan proceeds (to a
             # NoFeasiblePlanError naming the constraint if the whole

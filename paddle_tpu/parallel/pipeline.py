@@ -27,7 +27,13 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 
 from .mesh import get_mesh
-from ..utils.compat import pcast
+
+
+def vary(x, axes):
+    """Retype `x` as device-varying over every axis of `axes` it does not
+    vary over yet (`jax.lax.pcast` refuses an axis that already is)."""
+    missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int,
@@ -60,8 +66,11 @@ def spmd_pipeline(stage_fn: Callable, n_stages: int, n_microbatches: int,
     1F1B-shaped backward, scheduled by XLA with the ppermute overlapping
     the next tick's compute.
 
-    Must be called inside a shard_map manual over `axis_name`, where each
-    rank holds its leading-dim slice.
+    Must be called inside a shard_map manual over `axis_name` with
+    `check_vma=True`, where each rank holds its leading-dim slice. The
+    ring's carries are typed varying over `axis_name` plus whatever manual
+    axes `microbatched_x` already varies over, and `stage_fn` must hand
+    back an activation of the type it was given (as it must the shape).
 
     with_aux=True: `stage_fn(chunk_params, x) -> (y, aux_scalar)` and each
     microbatch's aux accumulates ALONG ITS JOURNEY — a per-slot f32 rides
@@ -105,6 +114,9 @@ def spmd_pipeline(stage_fn: Callable, n_stages: int, n_microbatches: int,
         n_ticks = n_microbatches + p - 1
         mb_shape = x_mb.shape[1:]
         perm = [(i, (i + 1) % p) for i in range(p)]
+        # scan and cond need one vma type per carry: the activation's own
+        # axes plus the ring's
+        ring_axes = tuple(jax.typeof(x_mb).vma | {axis_name})
 
         # with_aux is a trace-time constant: the aux ring (its carry,
         # ppermute) exists ONLY when requested — the dense pipeline
@@ -120,9 +132,9 @@ def spmd_pipeline(stage_fn: Callable, n_stages: int, n_microbatches: int,
             # stage 0 ingests microbatch t (clamped); every other stage
             # keeps its circulating activation
             idx = jnp.clip(t, 0, n_microbatches - 1)
-            inject = pcast(
+            inject = vary(
                 jax.lax.dynamic_index_in_dim(x_mb, idx, 0, keepdims=False),
-                axis_name, to="varying")
+                ring_axes)
             inp = jnp.where(stage == 0, inject, state)
             # the last stage finishes hop p-1: emit microbatch t - (p-1)
             out_idx = t - (p - 1)
@@ -162,21 +174,18 @@ def spmd_pipeline(stage_fn: Callable, n_stages: int, n_microbatches: int,
                 return (state, outputs, busy), None
             return (state, outputs), None
 
-        # pcast-to-varying: carries are device-varying over pp from tick one,
-        # and scan/cond require carry vma types to be invariant
-        def vary(z):
-            return pcast(z, axis_name, to="varying")
-
-        state0 = vary(jnp.zeros(mb_shape, x_mb.dtype))
-        outputs0 = vary(jnp.zeros((n_microbatches,) + mb_shape, x_mb.dtype))
+        state0 = vary(jnp.zeros(mb_shape, x_mb.dtype), ring_axes)
+        outputs0 = vary(jnp.zeros((n_microbatches,) + mb_shape, x_mb.dtype),
+                        ring_axes)
         if with_aux:
-            aux0 = vary(jnp.zeros((), jnp.float32))
-            aux_out0 = vary(jnp.zeros((n_microbatches,), jnp.float32))
+            aux0 = vary(jnp.zeros((), jnp.float32), ring_axes)
+            aux_out0 = vary(jnp.zeros((n_microbatches,), jnp.float32),
+                            ring_axes)
             (_, _, outputs, aux_out), _ = jax.lax.scan(
                 tick, (state0, aux0, outputs0, aux_out0),
                 jnp.arange(n_ticks))
         elif schedule_stats:
-            busy0 = vary(jnp.zeros((), jnp.float32))
+            busy0 = vary(jnp.zeros((), jnp.float32), (axis_name,))
             (_, outputs, busy), _ = jax.lax.scan(
                 tick, (state0, outputs0, busy0), jnp.arange(n_ticks))
         else:
@@ -218,29 +227,15 @@ def pipeline_forward(stage_fn, stacked_params, x_mb, n_stages,
     body = stage_fn
     if remat:
         body = jax.checkpoint(stage_fn)
-    # argument validation (the interleave rejection) still fires on
-    # every build — the capability gate below only guards the
-    # shard_map lowering itself
     piped = spmd_pipeline(body, n_stages, n_microbatches,
                           interleave=interleave, with_aux=with_aux)
-    from ..utils.compat import spmd_pipeline_supported
-    if not spmd_pipeline_supported():
-        # partial-auto shard_map (pp manual, dp/mp under GSPMD) FATALLY
-        # aborts legacy XLA's partitioner — refuse cleanly instead of
-        # taking the whole process down (utils/compat.py; the dryrun
-        # degrades to layer-weight pp sharding on these builds)
-        raise NotImplementedError(
-            "the SPMD pipeline needs partial-auto shard_map, which "
-            "this jax/XLA build cannot partition "
-            "(utils.compat.spmd_pipeline_supported)")
     param_specs = jax.tree_util.tree_map(lambda _: P("pp"), stacked_params)
     # check_vma=True is load-bearing: partial-manual shard_map with
     # check_vma=False is broken in jax 0.9 (its internal _unmatch builds a
     # spec over ALL mesh axes and rejects itself). The masked-psum output
     # broadcast makes the result genuinely replicated over pp, so the vma
     # check passes.
-    from ..utils.compat import shard_map
-    sm = shard_map(
+    sm = jax.shard_map(
         piped, mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=(P(), P()) if with_aux else P(),

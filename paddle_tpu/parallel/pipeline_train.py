@@ -16,11 +16,11 @@ the steady-state bubble is (pp-1)/(m+pp-1) per phase — the planner's
 (pp-1)/m model, not the (pp-1)× serial fill of layer-sharded
 execution.
 
-Why FULL-manual: the partial-auto formulation (pp manual, dp/fsdp/tp
-left to GSPMD — parallel/pipeline.pipeline_forward) fatally aborts
-this container's legacy XLA partitioner
-(utils.compat.spmd_pipeline_supported), so every axis here is
-hand-partitioned inside one shard_map over the WHOLE mesh:
+Why FULL-manual: the GSPMD formulation (pp manual, dp/fsdp/tp left to
+the partitioner — parallel/pipeline.pipeline_forward) leaves the tp and
+ZeRO-3 collectives where the partitioner puts them; here every axis is
+hand-partitioned inside one shard_map over the WHOLE mesh, so each
+collective below is one the code names:
 
 - tp: Megatron column/row-parallel — qkv/up matmuls consume this
   rank's column shard (heads/ffn columns), row-parallel outputs
@@ -37,16 +37,32 @@ hand-partitioned inside one shard_map over the WHOLE mesh:
   parallel.pipeline.spmd_pipeline's circulate schedule over the
   microbatched activations.
 
-Gradient correctness under legacy shard_map (check_rep=False, where
-psum transposes to psum): the differentiated scalar is the per-device
-PARTIAL loss — CE masked to the LAST pipeline stage and divided by
-dp·fsdp·tp — so the per-device contributions sum to the global loss
-exactly once and the collective transposes compose to the exact
-adjoint (validated to ~1e-7 relative against the unsharded grads).
-After the backward, each gradient leaf is psum'd over exactly the
-mesh axes its PartitionSpec does NOT name: axes the leaf is sharded
-over already carry complete shard-gradients (the gather transposes
-summed them), axes it is replicated over hold per-rank partials.
+Gradient correctness: the region is typed (`check_vma=True`), so every
+value is either varying over a mesh axis (a per-rank value) or
+invariant over it (one value all ranks hold), a psum takes varying to
+invariant, and autodiff transposes each retyping to its exact adjoint
+(psum <-> pcast-to-varying). Two rules follow, one per kind of axis:
+
+- dp, fsdp, pp carry DIFFERENT data per rank (batch shards, stages).
+  Every parameter leaf is pcast to varying over those of the three its
+  PartitionSpec does not name BEFORE the loss is traced, so its
+  cotangent accumulates per rank through both scans with no collective
+  inside them, and is psum'd once after the backward over exactly the
+  axes it was cast over. The differentiated scalar is the per-rank
+  PARTIAL loss — CE masked to the LAST pipeline stage and divided by
+  dp·fsdp — whose psum over (dp, fsdp, pp) is the global mean.
+- tp carries the SAME activations on every rank. Leaves the spec does
+  not shard over tp (norm scales, row-parallel biases) stay invariant:
+  the residual stream is invariant over tp, it turns varying where it
+  meets a column shard, and the transpose of that retyping is the
+  Megatron backward all-reduce. Their gradients come out complete on
+  every rank, the loss is not replicated over tp, and nothing divides
+  by it.
+
+The out_specs check is the guard: a loss or a bubble that is typed
+varying over any axis, or a new leaf that is not typed as its spec
+says, fails at trace time (validated to ~1e-7 relative against the
+unsharded grads).
 
 The step honors the facade contract `(params, opt_state, batch) ->
 (loss, new_params, new_opt)` (plus a trailing bubble-fraction scalar
@@ -65,8 +81,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .mesh import _clean_spec, leaf_path_name as _leaf_name
-from .pipeline import spmd_pipeline
-from ..utils.compat import shard_map
+from .pipeline import spmd_pipeline, vary
 
 __all__ = ["make_pp_step_fn"]
 
@@ -105,15 +120,15 @@ def _vocab_parallel_embed(wte, tokens, tp_axis: str):
 def _vocab_parallel_ce(logits, targets, tp_axis: str):
     """models/losses.fused_softmax_ce over vocab-sharded logits
     [.., V/tp]: the logsumexp and the target gather each cross the
-    vocab shards with one psum; the global max rides a (stop-gradient)
-    all_gather because legacy jax has no pmax differentiation rule —
-    subtracting a constant leaves the math exact either way. Returns
-    the mean loss over all positions."""
+    vocab shards with one psum; the global max is a pmax of a
+    stop-gradient value (pmax has no differentiation rule, and
+    subtracting a constant leaves the math exact either way). Every
+    reduction ends invariant over tp, and so does the loss. Returns the
+    mean loss over all positions."""
     lf = logits.astype(jnp.float32)
     ti = jax.lax.axis_index(tp_axis)
     v_loc = lf.shape[-1]
-    mx = jax.lax.stop_gradient(jnp.max(
-        jax.lax.all_gather(jnp.max(lf, -1), tp_axis, axis=0), axis=0))
+    mx = jax.lax.pmax(jax.lax.stop_gradient(jnp.max(lf, -1)), tp_axis)
     se = jax.lax.psum(jnp.sum(jnp.exp(lf - mx[..., None]), -1), tp_axis)
     lse = mx + jnp.log(se)
     tl = targets.astype(jnp.int32) - ti * v_loc
@@ -418,7 +433,8 @@ def make_pp_step_fn(cfg, plan, mesh, lr: float = 3e-4,
     specs: Dict = plan.specs or {}
     ce_fn = {"gpt": _gpt_pp_ce, "llama": _llama_pp_ce}[family]
     axis_names = tuple(str(a) for a in mesh.axis_names)
-    n_grid = dp * fsdp * tp  # loss-replication factor (pp is masked)
+    # the axes whose ranks see different data (module docstring)
+    data_axes = tuple(a for a in axis_names if a != tp_axis)
 
     import jax.tree_util as jtu
 
@@ -435,17 +451,13 @@ def make_pp_step_fn(cfg, plan, mesh, lr: float = 3e-4,
             return P(("dp", "fsdp"), *([None] * (nd - 1))) if nd else P()
         return jax.tree_util.tree_map(pin, tree)
 
-    def _reduce_grads(grads):
-        """psum each leaf over exactly the axes its spec does NOT name:
-        sharded axes already carry complete shard-gradients (the gather
-        transposes reduce-scattered them), replicated axes hold
-        per-rank partials (dp batch shards, the pp stage mask, the
-        tp-replicated norm/bias paths)."""
-        def red(path, g):
-            named = _spec_axes(specs.get(_leaf_name(path), P()))
-            over = tuple(a for a in axis_names if a not in named)
-            return jax.lax.psum(g, over) if over else g
-        return jtu.tree_map_with_path(red, grads)
+    def _reduce_grad(g, p):
+        """psum a per-rank gradient over the data axes its parameter is
+        invariant over — the axes `vary` cast it over; axes it is
+        sharded over already carry complete shard-gradients (the gather
+        transposes reduce-scattered them)."""
+        over = tuple(a for a in data_axes if a not in jax.typeof(p).vma)
+        return jax.lax.psum(g, over) if over else g
 
     def local_step(params, opt_state, batch):
         toks = batch["tokens"] if isinstance(batch, dict) else batch
@@ -458,20 +470,20 @@ def make_pp_step_fn(cfg, plan, mesh, lr: float = 3e-4,
             ce, stats = ce_fn(p, toks, cfg, tp, tp_axis, pp,
                               microbatches, overlap)
             stage = jax.lax.axis_index("pp")
-            # per-device PARTIAL loss: masked to the LAST stage (where
+            # per-rank PARTIAL loss: masked to the LAST stage (where
             # the pipeline's outputs are real — the mask also routes
             # the head/final-norm cotangents to exactly one stage) and
-            # divided by the dp·fsdp·tp replication, so the per-device
-            # contributions sum to the global mean exactly once —
-            # under check_rep=False psum transposes to psum, and this
-            # is the formulation whose adjoint is exact
-            part = ce * (stage == pp - 1).astype(ce.dtype) / n_grid
+            # divided by the dp·fsdp batch shards, so the partials sum
+            # to the global mean exactly once
+            part = ce * (stage == pp - 1).astype(ce.dtype) / (dp * fsdp)
             return part, stats
 
         (part, stats), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
-        loss = jax.lax.psum(part, axis_names)
-        grads = _reduce_grads(grads)
+            loss_fn, has_aux=True)(
+                jax.tree_util.tree_map(lambda a: vary(a, data_axes),
+                                       params))
+        loss = jax.lax.psum(part, data_axes)
+        grads = jax.tree_util.tree_map(_reduce_grad, grads, params)
         from ..models.gpt import apply_adamw
         new_params, new_opt = apply_adamw(grads, params, opt_state, lr,
                                           **adamw_kw)
@@ -488,9 +500,9 @@ def make_pp_step_fn(cfg, plan, mesh, lr: float = 3e-4,
         out_specs = (P(), in_specs[0], in_specs[1])
         if with_stats:
             out_specs = out_specs + (P(),)
-        sm = shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, axis_names=set(axis_names),
-                       check_vma=False)
+        sm = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, axis_names=set(axis_names),
+                           check_vma=True)
         return sm(params, opt_state, batch)
 
     step.plan = plan

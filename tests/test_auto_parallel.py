@@ -172,7 +172,7 @@ class TestEngine:
 class TestEngineGPT:
     def test_engine_fit_gpt_on_hybrid_mesh(self):
         """Engine.fit drives the flagship GPT under dp2×pp2×mp2 markup
-        (the VERDICT acceptance case: Engine on the GPT dryrun config)."""
+        (the acceptance case: Engine on the GPT dryrun config)."""
         import jax.numpy as jnp
         from paddle_tpu.models.gpt import GPTConfig, GPTModel
         from paddle_tpu.parallel.mesh import build_mesh, use_mesh

@@ -2,7 +2,7 @@
 (reference python/paddle/distributed/auto_parallel/static/tuner/
 parallel_tuner.py:40 + cost/base_cost.py). The contract pinned here:
 legality pruning, memory pruning, the qualitative orderings the cost
-model exists to encode, and — the VERDICT r4 gate — that the predicted
+model exists to encode, and — the ranking gate — that the predicted
 ranking matches the MEASURED step-time ranking of hand-built configs on
 the 8-device CPU mesh."""
 import time
@@ -206,7 +206,7 @@ class TestBestMeshAxes:
 
 
 class TestPredictedVsMeasured:
-    """The VERDICT gate: predicted ranking == measured step-time ranking
+    """The ranking gate: predicted ranking == measured step-time ranking
     for hand-built configs on the virtual 8-device mesh. Configs are
     chosen so the ordering is driven by structure (pipeline bubble, TP
     collective volume vs pure DP), not measurement noise."""

@@ -74,7 +74,7 @@ def test_raw_jax_array_params_save_true_shards(tmp_path):
 
 
 def test_mesh_reshape_dp2mp4_to_dp4mp2(tmp_path):
-    """The VERDICT's acceptance case: save under dp2xmp4, load under
+    """The acceptance case: save under dp2xmp4, load under
     dp4xmp2, bitwise parity."""
     rng = np.random.RandomState(0)
     w = jnp.asarray(rng.randn(8, 8).astype(np.float32))

@@ -1,0 +1,212 @@
+"""Compile, for a DESCRIBED TPU v5e (2x2) that is not attached, the jitted
+bodies chip_smoke.py runs, at the widths it runs them: every Pallas
+kernel entry point, the GPT-350M train step on one chip and under both
+four-chip plans, and the GPT-1.3B decode tick. Nothing executes and no
+array is made — shapes only (`jax.eval_shape`) — so these guard every
+later PR against what interpret mode cannot see (a block that does not
+fit VMEM, a slice off the tiling, a kernel GSPMD cannot partition, a step
+that does not fit 16 GB) at no chip time. A compile that passes is not a
+chip run.
+
+The topology is described inside the module-scoped `topo` fixture, never
+at import: only one process may hold the TPU library, and every xdist
+worker imports every test file. Code that asks `jax.default_backend()`
+would take its CPU branch here, so each test steers it to "tpu" with
+monkeypatch — in the test, not through an option of the program.
+"""
+import functools
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+
+HBM_BYTES = 16e9
+SIZES = chip_smoke.REAL
+# static names (a parametrize argument must not need the topology):
+# chip_smoke.kernel_cases(SIZES) is checked against this list in the test
+KERNELS = ["flash_fwd_hd64", "flash_bwd_hd64", "flash_fwd_hd128",
+           "flash_bwd_hd128", "jax_flash", "splash", "ce", "ce_fused",
+           "fused_adamw", "quant_matmul_k2048", "quant_matmul_k8192"]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                               # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep it off around these."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prior = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prior)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch, no_compile_cache):
+    """The program as it runs on the chip: the gates take their TPU
+    branch, and matmuls keep jax's default precision (tests/conftest.py
+    pins "highest" for the CPU parity tests, which Mosaic refuses for the
+    kernels' bf16 operands: "Bad lhs type")."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.default_matmul_precision("default"):
+        yield
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _device_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def _collectives(compiled) -> dict:
+    text = compiled.as_text()
+    return {k: text.count(f" {k}(") + text.count(f" {k}-start(")
+            for k in ("all-reduce", "all-gather", "reduce-scatter",
+                      "collective-permute", "all-to-all")}
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_compiles_for_v5e(name, topo, as_tpu):
+    cases = {c[0]: c for c in chip_smoke.kernel_cases(SIZES)}
+    assert sorted(cases) == sorted(KERNELS)
+    _, fn, _oracle, shapes, _tol = cases[name]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = jax.jit(fn).lower(*_on(one_chip, shapes)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def _lower_train(plan, devices):
+    from paddle_tpu.models.facade import make_train_step
+    from paddle_tpu.models.gpt import (init_gpt_params, init_opt_state,
+                                       train_step)
+    cfg = chip_smoke._gpt_cfg(SIZES.train_model)
+    params = jax.eval_shape(
+        lambda: init_gpt_params(cfg, jax.random.PRNGKey(0)))
+    opt = jax.eval_shape(init_opt_state, params)
+    toks = jax.ShapeDtypeStruct((SIZES.train_batch, cfg.max_seq_len + 1),
+                                jnp.int32)
+    step = make_train_step(train_step, cfg=cfg, lr=1e-3,
+                           mesh=plan.build_mesh(devices=devices), plan=plan)
+    step._build((params, opt, toks))
+    return step._jit.lower(params, opt, toks).compile()
+
+
+def test_gpt_350m_train_step_compiles_on_one_chip(topo, as_tpu):
+    from paddle_tpu.parallel.planner import plan_train
+    cfg = chip_smoke._gpt_cfg(SIZES.train_model)
+    plan = plan_train(cfg, 1, SIZES.train_batch)
+    compiled = _lower_train(plan, list(topo.devices[:1]))
+    # the loss head is the Pallas CE pair (forward and backward)
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert _device_bytes(compiled) < HBM_BYTES
+    assert not any(_collectives(compiled).values())
+
+
+@pytest.mark.parametrize("degrees", [
+    {}, dict(dp=2, fsdp=1, tp=2), dict(dp=1, fsdp=1, tp=2, pp=2)],
+    ids=["planned", "dp2_tp2", "tp2_pp2"])
+def test_gpt_350m_train_step_compiles_on_four_chips(degrees, topo, as_tpu):
+    """The GSPMD step holds a Mosaic kernel (the CE), which the
+    partitioner refuses unless the call sits in a full-manual shard_map
+    (models/losses._ce_rows_over_mesh); the pipelined step is one typed
+    full-manual region (parallel/pipeline_train.py)."""
+    from paddle_tpu.parallel.planner import plan_train
+    cfg = chip_smoke._gpt_cfg(SIZES.train_model)
+    plan = plan_train(cfg, 4, SIZES.train_batch, **degrees)
+    compiled = _lower_train(plan, list(topo.devices))
+    assert _device_bytes(compiled) < HBM_BYTES
+    colls = _collectives(compiled)
+    assert colls["all-reduce"] > 0               # the gradient reduction
+    if plan.pp > 1:
+        assert colls["collective-permute"] > 0   # the stage ring
+    else:
+        assert "tpu_custom_call" in compiled.as_text()
+    if degrees.get("tp", 1) > 1 and plan.pp == 1:
+        # vocab-parallel logits reach the row-split CE by all-to-all
+        assert colls["all-to-all"] > 0
+
+
+@pytest.mark.parametrize("tp", [1, 4])
+def test_gpt_1p3b_paged_decode_tick_compiles(tp, topo, as_tpu):
+    """The serving engine's decode tick over the paged pool at the
+    smoke's shape (8 slots, 1024 positions, pages of 16), on one chip
+    and sharded four ways the way ServingEngine(mesh=) places it."""
+    from paddle_tpu.inference.serving import _decode_tick, family_for
+    from paddle_tpu.kernels.decode_attention import cache_pspecs
+    from paddle_tpu.models.gpt import init_gpt_params
+    from paddle_tpu.parallel.mesh import sharding_for
+    cfg = chip_smoke._gpt_cfg(SIZES.serve_model)
+    fam = family_for("gpt")
+    mesh = Mesh(np.array(topo.devices[:tp]), ("tp",))
+    rep = NamedSharding(mesh, P())
+    S = jax.ShapeDtypeStruct
+
+    shapes = jax.eval_shape(
+        lambda: init_gpt_params(cfg, jax.random.PRNGKey(0)))
+    params = {n: S(v.shape, v.dtype, sharding=sharding_for(
+        fam.serving_specs.get(n, P()), mesh, shape=v.shape))
+        for n, v in shapes.items()}
+    n, ps = SIZES.slots, 16
+    max_pages = -(-SIZES.max_len // ps)
+    pool = (cfg.num_layers, n * max_pages + 1, ps, cfg.num_heads,
+            cfg.head_dim)
+    cache = {"k": S(pool, cfg.dtype), "v": S(pool, cfg.dtype),
+             "pt": S((n, max_pages), jnp.int32)}
+    specs = cache_pspecs(True, "tp")
+    pin = {k: sharding_for(specs.get(k, P()), mesh, shape=v.shape)
+           for k, v in cache.items()}
+    cache = {k: S(v.shape, v.dtype, sharding=pin[k])
+             for k, v in cache.items()}
+
+    def rep_of(shape, dtype):
+        return S(shape, dtype, sharding=rep)
+    state = tuple(rep_of((n,), dt) for dt in (
+        jnp.int32, jnp.int32, jnp.bool_, jnp.float32, jnp.int32,
+        jnp.int32, jnp.int32))
+    tick = jax.jit(
+        functools.partial(_decode_tick, fwd=fam.forward_cached, cfg=cfg,
+                          max_top_k=0, guard=True,
+                          oor_pos=max_pages * ps, cache_pin=pin, tele=True),
+        donate_argnums=(1, 2), static_argnames=("sampling",))
+    compiled = tick.lower(params, cache, state, rep_of((2,), jnp.uint32),
+                          rep_of((n,), jnp.float32),
+                          sampling=False).compile()
+    per_device = _device_bytes(compiled)
+    assert per_device < HBM_BYTES
+    if tp > 1:
+        # row-parallel matmuls reduce over tp, and no device holds the
+        # whole parameter tree
+        assert _collectives(compiled)["all-reduce"] > 0
+        whole = sum(int(np.prod(v.shape)) * v.dtype.itemsize
+                    for v in shapes.values())
+        assert compiled.memory_analysis().argument_size_in_bytes < whole

@@ -15,7 +15,7 @@ from paddle_tpu.parallel.compression import (
     compressed_psum, dgc_compress, dgc_decompress, dgc_psum,
     local_sgd_sync)
 from paddle_tpu.parallel.mesh import build_mesh
-from paddle_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def _mesh8():

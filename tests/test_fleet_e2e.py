@@ -1,4 +1,4 @@
-"""End-to-end fleet-API hybrid training test (VERDICT r2 item 9).
+"""End-to-end fleet-API hybrid training test.
 
 Reference analog: the collective fleet suites
 (test/collective/fleet/hybrid_parallel_mp_layers.py and

@@ -191,7 +191,7 @@ class TestKVCacheDecode:
                                    rtol=2e-3)
 
     def test_greedy_generate_parity_vs_nocache(self):
-        """The VERDICT acceptance test: greedy decode with KV cache equals
+        """The acceptance test: greedy decode with KV cache equals
         argmax over the no-cache full forward at every step."""
         cfg = _small_cfg()
         params = init_gpt_params(cfg, jax.random.PRNGKey(0))

@@ -88,8 +88,7 @@ def test_switch_router_gets_task_gradient():
 def test_gpt_moe_pipeline_aux_parity():
     """MoE aux loss circulates with the activations under pipeline
     parallelism: pipelined loss == CE(full batch) + w * mean of the
-    per-microbatch aux computed by the NON-pipelined path (VERDICT r2
-    weak #3 acceptance)."""
+    per-microbatch aux computed by the NON-pipelined path."""
     import functools
     from paddle_tpu.models.gpt import (GPTConfig, init_gpt_params,
                                        shard_gpt_params, gpt_loss,

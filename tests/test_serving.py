@@ -360,38 +360,25 @@ class TestExposure:
 
 
 class TestCompileCacheHelpers:
-    def test_xla_cache_dir_and_env_override(self, tmp_path, monkeypatch):
+    def test_xla_cache_dir_is_in_the_checkout(self):
         from paddle_tpu.utils import compile_cache as cc
         import os
         d = cc.xla_cache_dir()
         assert os.path.isdir(d) and d.endswith(os.path.join("perf",
                                                             "xla_cache"))
-        monkeypatch.setenv("PADDLE_TPU_XLA_CACHE_DIR",
-                           str(tmp_path / "cc"))
-        assert cc.xla_cache_dir() == str(tmp_path / "cc")
-        assert os.path.isdir(str(tmp_path / "cc"))
 
-    def test_sync_policy(self):
-        """TPU-class platforms enable the cache, CPU disables it."""
+    def test_sync_policy(self, monkeypatch):
+        """With nothing set from outside, the TPU gets the checkout's
+        cache and the CPU gets none."""
         from paddle_tpu.utils import compile_cache as cc
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         prior = jax.config.jax_compilation_cache_dir
         try:
+            jax.config.update("jax_compilation_cache_dir", None)
             cc.sync_compile_cache_for("tpu")
-            assert jax.config.jax_compilation_cache_dir is not None
+            assert jax.config.jax_compilation_cache_dir == \
+                cc.xla_cache_dir()
             cc.sync_compile_cache_for("cpu")
             assert jax.config.jax_compilation_cache_dir is None
         finally:
             jax.config.update("jax_compilation_cache_dir", prior)
-
-    def test_bench_reexports(self):
-        """bench.py (and through it bench_ladder/tpu_campaign) resolve
-        the helpers from the ONE package home."""
-        import importlib.util, os
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        spec = importlib.util.spec_from_file_location(
-            "bench_mod", os.path.join(root, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        from paddle_tpu.utils import compile_cache as cc
-        assert bench.xla_cache_dir is cc.xla_cache_dir
-        assert bench.sync_compile_cache_for is cc.sync_compile_cache_for

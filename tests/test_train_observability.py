@@ -568,5 +568,5 @@ class TestDiffFailures:
     def test_repo_baseline_file_parses(self):
         d = __import__("diff_failures")
         ids = d.parse_baseline(d.DEFAULT_BASELINE)
-        assert len(ids) >= 5          # the env set (shrinks over PRs)
+        assert len(ids) <= 13         # the 2026-08-07 set; only shrinks
         assert all(id_.startswith("tests/") for id_ in ids)
