@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device:
+100 * (1 - busy / window), from the profiler trace (benchmark/trace). No
+trace, nothing to read."""
+
+
+def read(record):
+    trace = record.get("trace")
+    if not trace or not trace.get("window_s"):
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
