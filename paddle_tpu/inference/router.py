@@ -107,7 +107,7 @@ from .admission import AdmissionController, QuotaExceededError
 from .host_kv import HostKVTier
 from .serving import (BackpressureError, PoolExhaustedError,
                       ServingEngine, TERMINAL_REASONS)
-from ..profiler import monitor
+from ..profiler import RecordEvent, monitor
 
 __all__ = ["EngineRouter", "RouterRequest", "create_router"]
 
@@ -622,6 +622,10 @@ class EngineRouter:
         whose step ESCAPES (the engine self-heals internally — an
         escape means the replica is gone) dies here and its in-flight
         requests requeue."""
+        with RecordEvent("serving.router_tick"):
+            return self._step()
+
+    def _step(self):
         events: List[tuple] = []
         if _FAULT_HOOK is not None:
             actions = _FAULT_HOOK(self._ticks) or {}

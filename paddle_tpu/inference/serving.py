@@ -241,7 +241,8 @@ the tick's one host pull; profiler/serving_telemetry, records via
 tick_records() / telemetry_jsonl=), request-scoped tracing
 (tracing= — parented spans submit -> prefill chunks -> decode ->
 the exactly-once terminal _finish; profiler/tracing) and
-RecordEvent spans around every prefill/decode tick —
+RecordEvent spans through the tick (serving.tick > admit > prefill,
+upload, decode_tick — docs/observability.md has the table) —
 tools/telemetry_report.py summarizes them (including TTFT /
 inter-token-latency percentiles from `export_slo_jsonl` and a
 "kv pool" block), tools/bench_serving.py measures the engine against
@@ -612,11 +613,12 @@ def _decode_tick(params, cache, state, base_key, poison, *, fwd, cfg,
     lg = logits[:, 0].astype(jnp.float32)
     if guard:
         lg = lg * poison[:, None]
-    if sampling:
-        keys = _slot_keys(base_key, req_ids, gen_idx)
-        nxt = _sample(lg, temps, top_ks, keys, max_top_k)
-    else:
-        nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        if sampling:
+            keys = _slot_keys(base_key, req_ids, gen_idx)
+            nxt = _sample(lg, temps, top_ks, keys, max_top_k)
+        else:
+            nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
     nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
     bad = jnp.zeros_like(active)
     if guard:
@@ -1572,6 +1574,10 @@ class ServingEngine:
         decode step (quarantining poisoned rows), then enforce
         deadlines on the survivors. Returns this tick's
         (request, token) emissions in slot order."""
+        with RecordEvent("serving.tick"):
+            return self._step()
+
+    def _step(self):
         events: List[tuple] = []
         actions = {}
         if _FAULT_HOOK is not None:
@@ -1603,7 +1609,8 @@ class ServingEngine:
                 break       # head-of-line waits for pages (FCFS); live
                 #             slots free pages as they finish
             self._queue.popleft()
-            self._admit_guarded(slot, head, events, actions)
+            with RecordEvent("serving.admit", request=head.id):
+                self._admit_guarded(slot, head, events, actions)
 
         if self._active.any():
             self._decode_guarded(events, actions)
@@ -1943,24 +1950,8 @@ class ServingEngine:
                     # private before the scatter (idempotent: a retry
                     # finds them already allocated)
                     self._prepare_tick_pages()
-                    if self._pt_dirty:
-                        self._cache["pt"] = self._rep(self._ptab)
-                        self._pt_dirty = False
-                if self._dirty:
-                    self._dstate = (
-                        self._rep(self._cur_tok),
-                        self._rep(self._positions),
-                        self._rep(self._active),
-                        self._rep(self._temps),
-                        self._rep(self._top_ks),
-                        self._rep(self._req_ids),
-                        self._rep(self._gen_idx))
-                    if self.mt_k > 1:
-                        # the scan's early-exit inputs ride the same
-                        # dirty-rebuild cadence as the state tuple
-                        self._daux = (self._rep(self._eos_ids),
-                                      self._rep(self._max_new))
-                    self._dirty = False
+                if self._dirty or (self.paged and self._pt_dirty):
+                    self._upload_dirty()
                 sampling = bool(np.any(self._temps[self._active] > 0.0))
                 poison = self._poison_ones
                 if poison_slot is not None and self.guardrails:
@@ -1968,8 +1959,9 @@ class ServingEngine:
                     p[int(poison_slot) % self.num_slots] = np.nan
                     poison = self._rep(p)
                 poison_slot = None        # injected at most once
-                t_dev0 = time.perf_counter()
-                with RecordEvent("serving.decode_tick"):
+                with RecordEvent("serving.decode_tick",
+                                 active=int(self._active.sum()),
+                                 slots=self.num_slots) as dev:
                     if self.spec:
                         dpoison = self._poison_ones
                         if draft_slot is not None:
@@ -1999,7 +1991,7 @@ class ServingEngine:
                         nxt, self._cache, self._dstate = out
                         toks = self._pull(nxt, stall_s)
                         tele_row = None
-                tick_ms = (time.perf_counter() - t_dev0) * 1e3
+                tick_ms = dev.dur_s * 1e3
                 stall_s = 0.0
                 break
             except StepHungError as e:
@@ -2057,6 +2049,30 @@ class ServingEngine:
             # download, and the device state stays clean unless an
             # eviction dirties it
             self._emit_token(i, req, tok, events, tick_now)
+
+    def _upload_dirty(self) -> None:
+        """Rebuild whatever the host mirrors dirtied since the last tick
+        (page table, slot-state tuple, the multi-tick scan's aux pair):
+        the uploads that stand between two device programs."""
+        with RecordEvent("serving.upload"):
+            if self.paged and self._pt_dirty:
+                self._cache["pt"] = self._rep(self._ptab)
+                self._pt_dirty = False
+            if self._dirty:
+                self._dstate = (
+                    self._rep(self._cur_tok),
+                    self._rep(self._positions),
+                    self._rep(self._active),
+                    self._rep(self._temps),
+                    self._rep(self._top_ks),
+                    self._rep(self._req_ids),
+                    self._rep(self._gen_idx))
+                if self.mt_k > 1:
+                    # the scan's early-exit inputs ride the same
+                    # dirty-rebuild cadence as the state tuple
+                    self._daux = (self._rep(self._eos_ids),
+                                  self._rep(self._max_new))
+                self._dirty = False
 
     def _emit_token(self, i: int, req: Request, tok: int,
                     events: list, tick_now: float,
@@ -2222,24 +2238,27 @@ class ServingEngine:
             req._sp_queue = None
             sp_pf = req.trace.begin("prefill", slot=slot, true_len=t0,
                                     bucket=tb, attempt=req.trace.attempt)
-        t_pf0 = time.perf_counter()
-        with RecordEvent("serving.prefill"):
-            first, self._cache = self._prefill(
-                self._params, self._cache, self._rep(padded),
-                self._rep(t0, np.int32), self._rep(slot, np.int32),
+        # the uploads are the admission's host work (serving.admit);
+        # serving.prefill is the program alone: dispatch through the
+        # first token's pull returning
+        args = (self._rep(padded), self._rep(t0, np.int32),
+                self._rep(slot, np.int32),
                 self._rep([req.temperature], np.float32),
                 self._rep([req.top_k], np.int32),
-                self._rep([req.id], np.int32), self._base_key,
+                self._rep([req.id], np.int32))
+        with RecordEvent("serving.prefill", request=req.id, true_len=t0,
+                         bucket=tb) as pf:
+            first, self._cache = self._prefill(
+                self._params, self._cache, *args, self._base_key,
                 sampling=req.temperature > 0.0)
             # first generated token — the admission's one host pull,
             # under the same watchdog as the tick's
             tok = int(self._pull(first))
-        pf_ms = (time.perf_counter() - t_pf0) * 1e3
         if req.trace is not None:
             req.trace.end(sp_pf, final=True)
         if self._tick_log is not None:
-            self._tick_log.record_prefill(self._ticks, pf_ms, t0, tb,
-                                          True, slot)
+            self._tick_log.record_prefill(self._ticks, pf.dur_s * 1e3, t0,
+                                          tb, True, slot)
         self._m_pre.add()
         if self._quant_info:
             self._m_qmm.add(self._qmm_full)
@@ -2461,24 +2480,22 @@ class ServingEngine:
                                     chunk_start=start, chunk_len=clen,
                                     bucket=cb, final=final,
                                     attempt=req.trace.attempt)
-        t_pf0 = time.perf_counter()
-        with RecordEvent("serving.prefill"):
-            first, self._cache = self._prefill(
-                self._params, self._cache, self._rep(padded),
-                self._rep(clen, np.int32),
-                self._rep(start, np.int32),
-                self._rep(slot, np.int32),
+        args = (self._rep(padded), self._rep(clen, np.int32),
+                self._rep(start, np.int32), self._rep(slot, np.int32),
                 self._rep([req.temperature], np.float32),
                 self._rep([req.top_k], np.int32),
-                self._rep([req.id], np.int32), self._base_key,
+                self._rep([req.id], np.int32))
+        with RecordEvent("serving.prefill", request=req.id, true_len=clen,
+                         bucket=cb) as pf:
+            first, self._cache = self._prefill(
+                self._params, self._cache, *args, self._base_key,
                 sampling=final and req.temperature > 0.0)
             tok = int(self._pull(first)) if final else None
-        pf_ms = (time.perf_counter() - t_pf0) * 1e3
         if req.trace is not None:
             req.trace.end(sp_pf)
         if self._tick_log is not None:
-            self._tick_log.record_prefill(self._ticks, pf_ms, clen, cb,
-                                          final, slot)
+            self._tick_log.record_prefill(self._ticks, pf.dur_s * 1e3,
+                                          clen, cb, final, slot)
         self._m_chunks.add()
         if self._quant_info:
             self._m_qmm.add(self._qmm_full)

@@ -137,6 +137,7 @@ def _mha_fwd_jit(q, k, v, causal, block_q, block_k, interpret, kv_len):
             pltpu.VMEM((bq, 128), jnp.float32),   # m (lane-broadcast)
             pltpu.VMEM((bq, 128), jnp.float32),   # l
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q2, k2, v2)
 
@@ -321,6 +322,7 @@ def _mha_bwd_jit(q, k, v, out, lse, do, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sqp, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(*in_arrs)
 
@@ -345,6 +347,7 @@ def _mha_bwd_jit(q, k, v, out, lse, do, causal, block_q, block_k,
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(*in_arrs)
 

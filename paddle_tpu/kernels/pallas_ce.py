@@ -162,6 +162,7 @@ def _ce_fused(logits2d, targets, block_t=128, block_v=512,
                         pltpu.VMEM((block_t, 128), jnp.float32),
                         pltpu.VMEM((block_t, 128), jnp.float32),
                         pltpu.VMEM((block_t, 128), jnp.float32)],
+        name="ce_fused",
         interpret=interpret,
     )(x, tg)
     return loss[:T, 0], dx[:T, :V]
@@ -195,6 +196,7 @@ def _ce_fwd(logits2d, targets, block_t=128, block_v=512, interpret=False):
         scratch_shapes=[pltpu.VMEM((block_t, 128), jnp.float32),
                         pltpu.VMEM((block_t, 128), jnp.float32),
                         pltpu.VMEM((block_t, 128), jnp.float32)],
+        name="ce_fwd",
         interpret=interpret,
     )(x, tg)
     return loss[:T, 0], lse[:T, 0]
@@ -227,6 +229,7 @@ def _ce_bwd(logits2d, targets, lse, g, block_t=128, block_v=512,
         ],
         out_specs=pl.BlockSpec((block_t, block_v), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(x.shape, logits2d.dtype),
+        name="ce_bwd",
         interpret=interpret,
     )(x, tg, lse2, g2)
     return dx[:T, :V]

@@ -93,6 +93,7 @@ def _leaf_update(p, g, m, v, scal, interpret=False):
         out_shape=[jax.ShapeDtypeStruct(pt.shape, p.dtype),
                    jax.ShapeDtypeStruct(pt.shape, jnp.float32),
                    jax.ShapeDtypeStruct(pt.shape, jnp.float32)],
+        name="adamw_update",
         interpret=interpret,
     )(srow, pt, gt, mt, vt)
     unpad = lambda t: t.reshape(-1)[:n].reshape(shape)

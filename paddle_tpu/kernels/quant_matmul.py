@@ -190,6 +190,7 @@ def _pallas_quant_matmul(x2d, w_q, scale, block_m=128, block_n=128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], w.shape[1]),
                                        jnp.float32),
+        name="quant_matmul",
         interpret=interpret,
     )(x, w, s)
     return y[:M, :N]

@@ -358,31 +358,37 @@ def _block(params_l, x, cfg):
     """One transformer block on stacked-layer slice params_l.
     Returns (x, aux) — aux is the MoE load-balancing loss (0 for dense)."""
     h = _sp_constraint(x, cfg)
-    a_in = _ln(h, params_l["ln1_scale"], params_l["ln1_bias"],
-               cfg.layer_norm_eps)
-    a = _attention(a_in, params_l["qkv_w"],
-                   params_l.get("qkv_b"), params_l["attn_out_w"],
-                   params_l.get("attn_out_b"), cfg)
+    # the named scopes are metadata: they put the phase's name on every
+    # HLO op (forward, recomputation and transpose) for the device trace
+    with jax.named_scope("attention"):
+        a_in = _ln(h, params_l["ln1_scale"], params_l["ln1_bias"],
+                   cfg.layer_norm_eps)
+        a = _attention(a_in, params_l["qkv_w"],
+                       params_l.get("qkv_b"), params_l["attn_out_w"],
+                       params_l.get("attn_out_b"), cfg)
     h = _sp_constraint(h + a, cfg)
-    m_in = _ln(h, params_l["ln2_scale"], params_l["ln2_bias"],
-               cfg.layer_norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if cfg.num_experts > 0:
-        m, aux = _moe_ffn(m_in, params_l["gate_w"], params_l["moe_up_w"],
-                          params_l["moe_up_b"], params_l["moe_down_w"],
-                          params_l["moe_down_b"], cfg)
-    else:
-        ffn = _dense_ffn
-        if cfg.remat and cfg.remat_policy == "all_but_mlp":
-            # nested checkpoint JUST around the FFN: everything else in
-            # the block is saved (no block-level remat for this policy —
-            # see _apply_stack), but none of the 4D-wide FFN internals
-            # can be (a names-based policy fails here: gelu decomposes
-            # into unnamed elementwise primitives whose outputs remain
-            # saveable, so the cut just moves onto them)
-            ffn = jax.checkpoint(_dense_ffn)
-        m = ffn(m_in, params_l["mlp_up_w"], params_l.get("mlp_up_b"),
-                params_l["mlp_down_w"], params_l.get("mlp_down_b"))
+    with jax.named_scope("mlp"):
+        m_in = _ln(h, params_l["ln2_scale"], params_l["ln2_bias"],
+                   cfg.layer_norm_eps)
+        if cfg.num_experts > 0:
+            m, aux = _moe_ffn(m_in, params_l["gate_w"],
+                              params_l["moe_up_w"], params_l["moe_up_b"],
+                              params_l["moe_down_w"],
+                              params_l["moe_down_b"], cfg)
+        else:
+            ffn = _dense_ffn
+            if cfg.remat and cfg.remat_policy == "all_but_mlp":
+                # nested checkpoint JUST around the FFN: everything else
+                # in the block is saved (no block-level remat for this
+                # policy — see _apply_stack), but none of the 4D-wide
+                # FFN internals can be (a names-based policy fails here:
+                # gelu decomposes into unnamed elementwise primitives
+                # whose outputs remain saveable, so the cut just moves
+                # onto them)
+                ffn = jax.checkpoint(_dense_ffn)
+            m = ffn(m_in, params_l["mlp_up_w"], params_l.get("mlp_up_b"),
+                    params_l["mlp_down_w"], params_l.get("mlp_down_b"))
     return _sp_constraint(h + m, cfg), aux
 
 
@@ -518,9 +524,10 @@ def _gpt_forward_impl(params, tokens, cfg: GPTConfig):
     # back onto the shards — then the row lookup is rank-local. The
     # tied LM head below keeps consuming the SHARDED table: the
     # vocab-parallel matmul never needs full rows.
-    wte = mesh_constraint(params["wte"], P(None, None))
-    x = jnp.take(wte, tokens, axis=0).astype(cfg.dtype)
-    x = x + params["wpe"][:S][None].astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        wte = mesh_constraint(params["wte"], P(None, None))
+        x = jnp.take(wte, tokens, axis=0).astype(cfg.dtype)
+        x = x + params["wpe"][:S][None].astype(cfg.dtype)
     x = _sp_constraint(x, cfg)
 
     block_keys = _BLOCK_KEYS_MOE if cfg.num_experts > 0 else _BLOCK_KEYS_DENSE
@@ -533,9 +540,12 @@ def _gpt_forward_impl(params, tokens, cfg: GPTConfig):
     # assignment that GSPMD then bridges with a per-iteration
     # collective-permute inside the backward while loop
     x = _sp_constraint(x, cfg)
-    x = _ln(x, params["ln_f_scale"], params["ln_f_bias"], cfg.layer_norm_eps)
-    # tied LM head (vocab-parallel matmul — mp shards the vocab dim)
-    logits = jnp.einsum("bsd,vd->bsv", x, params["wte"].astype(x.dtype))
+    with jax.named_scope("ce_head"):
+        x = _ln(x, params["ln_f_scale"], params["ln_f_bias"],
+                cfg.layer_norm_eps)
+        # tied LM head (vocab-parallel matmul — mp shards the vocab dim)
+        logits = jnp.einsum("bsd,vd->bsv", x,
+                            params["wte"].astype(x.dtype))
     logits = mesh_constraint(logits, P(("dp", "fsdp"), None, _model_axis()))
     return logits, aux
 
@@ -559,7 +569,8 @@ def gpt_loss(params, batch, cfg: GPTConfig):
     tokens = batch["tokens"] if isinstance(batch, dict) else batch
     inp, tgt = tokens[:, :-1], tokens[:, 1:]
     logits, aux = _gpt_forward_impl(params, inp, cfg)
-    loss = fused_softmax_ce(logits, tgt)
+    with jax.named_scope("ce_head"):
+        loss = fused_softmax_ce(logits, tgt)
     if cfg.num_experts > 0:
         loss = loss + cfg.moe_aux_weight * aux
     return loss
@@ -623,9 +634,10 @@ def train_step(params, opt_state, batch, cfg: GPTConfig, lr=3e-4,
                beta1=0.9, beta2=0.95, eps=1e-8, weight_decay=0.1):
     loss, grads = jax.value_and_grad(
         lambda p: gpt_loss(p, batch, cfg))(params)
-    new_params, new_opt = apply_adamw(
-        grads, params, opt_state, lr, beta1=beta1, beta2=beta2, eps=eps,
-        weight_decay=weight_decay)
+    with jax.named_scope("optimizer"):
+        new_params, new_opt = apply_adamw(
+            grads, params, opt_state, lr, beta1=beta1, beta2=beta2,
+            eps=eps, weight_decay=weight_decay)
     return loss, new_params, new_opt
 
 
@@ -715,15 +727,19 @@ def _cached_attention(x, params_l, kc, vc, pos, cfg, pt=None):
     v = v.reshape(B, T, H, hd)
     from ..kernels.decode_attention import (cached_attention, gather_pages,
                                             write_kv, write_kv_paged)
-    if pt is None:
-        kc = write_kv(kc, k, pos)
-        vc = write_kv(vc, v, pos)
-        ctx = cached_attention(q, kc, vc, pos)
-    else:
-        kc = write_kv_paged(kc, pt, k, pos)
-        vc = write_kv_paged(vc, pt, v, pos)
-        ctx = cached_attention(q, gather_pages(kc, pt),
-                               gather_pages(vc, pt), pos)
+    with jax.named_scope("kv_update"):
+        if pt is None:
+            kc = write_kv(kc, k, pos)
+            vc = write_kv(vc, v, pos)
+        else:
+            kc = write_kv_paged(kc, pt, k, pos)
+            vc = write_kv_paged(vc, pt, v, pos)
+    with jax.named_scope("decode_attention"):
+        if pt is None:
+            ctx = cached_attention(q, kc, vc, pos)
+        else:
+            ctx = cached_attention(q, gather_pages(kc, pt),
+                                   gather_pages(vc, pt), pos)
     ctx = ctx.reshape(B, T, D).astype(x.dtype)
     out = leaf_matmul(ctx, params_l, "attn_out_w")
     if params_l.get("attn_out_b") is not None:
@@ -759,19 +775,21 @@ def gpt_forward_cached(params, tokens, cache, pos, cfg: GPTConfig,
     the dense layout."""
     B, T = tokens.shape
     pt = cache.get("pt")
-    x = jnp.take(params["wte"], tokens, axis=0).astype(cfg.dtype)
-    if jnp.ndim(pos) == 0:
-        wpe = jax.lax.dynamic_slice_in_dim(params["wpe"], pos, T,
-                                           axis=0)[None]
-    else:
-        # mode="clip": the serving decode tick parks inactive rows at
-        # an out-of-table sentinel position (their K/V scatters to the
-        # scratch page); the default "fill" would embed them as NaN,
-        # and NaN written to scratch poisons every later gather of it
-        wpe = jnp.take(params["wpe"],
-                       pos[:, None] + jnp.arange(T), axis=0,
-                       mode="clip")
-    x = x + wpe.astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["wte"], tokens, axis=0).astype(cfg.dtype)
+        if jnp.ndim(pos) == 0:
+            wpe = jax.lax.dynamic_slice_in_dim(params["wpe"], pos, T,
+                                               axis=0)[None]
+        else:
+            # mode="clip": the serving decode tick parks inactive rows
+            # at an out-of-table sentinel position (their K/V scatters
+            # to the scratch page); the default "fill" would embed them
+            # as NaN, and NaN written to scratch poisons every later
+            # gather of it
+            wpe = jnp.take(params["wpe"],
+                           pos[:, None] + jnp.arange(T), axis=0,
+                           mode="clip")
+        x = x + wpe.astype(cfg.dtype)
 
     block_keys = _BLOCK_KEYS_MOE if cfg.num_experts > 0 else _BLOCK_KEYS_DENSE
     # weight-only int8 serving (quantization/serving.py): quantized
@@ -790,44 +808,50 @@ def gpt_forward_cached(params, tokens, cache, pos, cfg: GPTConfig,
     def scan_fn(x, layer_in):
         params_l, kc, vc = layer_in
         h = x
-        a_in = _ln(h, params_l["ln1_scale"], params_l["ln1_bias"],
-                   cfg.layer_norm_eps)
-        a, kc, vc = _cached_attention(a_in, params_l, kc, vc, pos, cfg,
-                                      pt=pt)
+        with jax.named_scope("attention"):
+            a_in = _ln(h, params_l["ln1_scale"], params_l["ln1_bias"],
+                       cfg.layer_norm_eps)
+            a, kc, vc = _cached_attention(a_in, params_l, kc, vc, pos,
+                                          cfg, pt=pt)
         h = h + a
-        m_in = _ln(h, params_l["ln2_scale"], params_l["ln2_bias"],
-                   cfg.layer_norm_eps)
-        if cfg.num_experts > 0:
-            m, _aux = _moe_ffn(m_in, params_l["gate_w"],
-                               params_l["moe_up_w"], params_l["moe_up_b"],
-                               params_l["moe_down_w"],
-                               params_l["moe_down_b"], cfg)
-        else:
-            # leaf_matmul-routed FFN (same contraction as _dense_ffn;
-            # the quantized tree swaps each matmul for the fused
-            # dequant-matmul per leaf)
-            mh = leaf_matmul(m_in, params_l, "mlp_up_w")
-            if params_l.get("mlp_up_b") is not None:
-                mh = mh + params_l["mlp_up_b"].astype(mh.dtype)
-            mh = jax.nn.gelu(mh)
-            m = leaf_matmul(mh, params_l, "mlp_down_w")
-            if params_l.get("mlp_down_b") is not None:
-                m = m + params_l["mlp_down_b"].astype(m.dtype)
+        with jax.named_scope("mlp"):
+            m_in = _ln(h, params_l["ln2_scale"], params_l["ln2_bias"],
+                       cfg.layer_norm_eps)
+            if cfg.num_experts > 0:
+                m, _aux = _moe_ffn(m_in, params_l["gate_w"],
+                                   params_l["moe_up_w"],
+                                   params_l["moe_up_b"],
+                                   params_l["moe_down_w"],
+                                   params_l["moe_down_b"], cfg)
+            else:
+                # leaf_matmul-routed FFN (same contraction as
+                # _dense_ffn; the quantized tree swaps each matmul for
+                # the fused dequant-matmul per leaf)
+                mh = leaf_matmul(m_in, params_l, "mlp_up_w")
+                if params_l.get("mlp_up_b") is not None:
+                    mh = mh + params_l["mlp_up_b"].astype(mh.dtype)
+                mh = jax.nn.gelu(mh)
+                m = leaf_matmul(mh, params_l, "mlp_down_w")
+                if params_l.get("mlp_down_b") is not None:
+                    m = m + params_l["mlp_down_b"].astype(m.dtype)
         return h + m, (kc, vc)
 
     x, (kcs, vcs) = jax.lax.scan(
         scan_fn, x, (stacked, cache["k"], cache["v"]),
         unroll=max(1, min(getattr(cfg, "decode_scan_unroll", 1),
                           n_layers)))
-    x = _ln(x, params["ln_f_scale"], params["ln_f_bias"], cfg.layer_norm_eps)
-    if "head_q" in params:
-        # quantized tied head: a transposed int8 copy ([D, V] +
-        # per-vocab scales) so `wte` itself stays fp for the embedding
-        # gather (quantization/serving.py)
-        logits = quant_matmul(x, params["head_q"], params["head_scale"])
-    else:
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["wte"].astype(x.dtype))
+    with jax.named_scope("lm_head"):
+        x = _ln(x, params["ln_f_scale"], params["ln_f_bias"],
+                cfg.layer_norm_eps)
+        if "head_q" in params:
+            # quantized tied head: a transposed int8 copy ([D, V] +
+            # per-vocab scales) so `wte` itself stays fp for the
+            # embedding gather (quantization/serving.py)
+            logits = quant_matmul(x, params["head_q"],
+                                  params["head_scale"])
+        else:
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                params["wte"].astype(x.dtype))
     out = {"k": kcs, "v": vcs}
     if pt is not None:
         out["pt"] = pt

@@ -6,15 +6,21 @@ tables), timer.py (throughput/ips benchmark auto-attached to DataLoader);
 C++ substrate paddle/fluid/platform/profiler/ (RecordEvent spans into a
 host-event recorder + CUPTI tracer, chrome-trace export).
 
-TPU-native design — two complementary recorders behind one API:
-- Host spans: `RecordEvent` keeps a process-local span log (name, wall-time,
-  nesting depth). On TPU the host side is dispatch/input-pipeline work; this
-  is what `summary()` tabulates and what the ips timer reads. Zero deps.
-- Device/XLA trace: when a trace dir is configured (`on_trace_ready=
-  export_chrome_tracing(dir)` or `Profiler(trace_dir=...)`), start/stop wrap
-  `jax.profiler.start_trace/stop_trace`, producing a TensorBoard-loadable
-  XLA trace with per-op device timelines; `RecordEvent` doubles as a
-  `jax.profiler.TraceAnnotation` so host spans appear on that timeline too.
+TPU-native design — ONE recorder, `RecordEvent`, seen from two sides:
+- Host spans: every `RecordEvent` lands in a bounded process-local ring
+  (`SPAN_RING` records: name, start, duration, nesting depth, thread, the
+  span's counts, and whether a profiler session was recording). On TPU the
+  host side is dispatch/scheduling work; this is what `summary()` tabulates,
+  what the flight recorder dumps and what the benchmark's per-layer readers
+  ask for (`get_profiler_spans()`).
+- Device/XLA trace: the same `RecordEvent` is a
+  `jax.profiler.TraceAnnotation` carrying the same counts as the event's
+  stats, so while a profiler session runs (`jax.profiler.start_trace`, this
+  module's `Profiler(trace_dir=...)`, the benchmark's `--trace 1`) the span
+  sits on the device trace's own clock beside the XLA ops. "Tracing on"
+  means exactly that — a session is recording; there is no other switch.
+  With none running an annotation records nothing and a span costs one
+  `deque.append`.
   `export_chrome_trace(path)` additionally renders the host spans as a
   standalone chrome-trace JSON (Perfetto / chrome://tracing), written
   beside the device trace on Profiler.stop().
@@ -26,11 +32,14 @@ step-metrics JSONL pipeline; `flight_recorder` is the crash black box.
 """
 from __future__ import annotations
 
+import collections
 import enum
 import json
 import threading
 import time
 from typing import Callable, Iterable, Optional
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .timer import benchmark  # noqa: F401  (reference: profiler/timer.py)
 from . import monitor  # noqa: F401  (reference: platform/monitor.h)
@@ -81,13 +90,27 @@ def _default_scheduler(_step: int) -> ProfilerState:
 
 
 # ------------------------------------------------------------- span recorder
+SPAN_RING = 65536       # completed spans kept; a server's life is unbounded
+
+
+class Span(collections.namedtuple(
+        "Span", "name start dur_s depth tid counts in_trace")):
+    """One completed span: perf_counter start and duration in seconds,
+    nesting depth on its thread (a span's parent is the enclosing span
+    of depth - 1 on the same tid), the span's counts (dict or None) and
+    whether a profiler session was recording when it began. A tuple
+    still: the first five fields are the historical record."""
+    __slots__ = ()
+
+
 class _SpanLog:
-    """Process-local completed-span log (the HostEventRecorder analog)."""
+    """Process-local ring of completed `Span`s (the HostEventRecorder
+    analog). Writers only `deque.append`, which is atomic — no lock on
+    the hot path; readers copy through `snapshot()`."""
 
     def __init__(self):
-        self._lock = threading.Lock()
         self._tls = threading.local()
-        self.spans = []          # (name, start, dur_s, depth, tid)
+        self.spans = collections.deque(maxlen=SPAN_RING)
         self.enabled = True
 
     def depth(self) -> int:
@@ -96,49 +119,73 @@ class _SpanLog:
     def push(self):
         self._tls.depth = self.depth() + 1
 
-    def pop(self, name: str, start: float):
+    def pop(self, name: str, start: float, dur_s: float, counts=None,
+            in_trace: bool = False):
         d = self.depth() - 1
         self._tls.depth = d
         if self.enabled:
-            with self._lock:
-                self.spans.append((name, start, time.perf_counter() - start,
-                                   d, threading.get_ident()))
+            self.spans.append(Span(name, start, dur_s, d,
+                                   threading.get_ident(), counts, in_trace))
+
+    def snapshot(self) -> list:
+        """The ring copied, oldest first. Another thread (a concurrent
+        router's worker, the checkpoint thread) may append mid-copy, and
+        a deque refuses to be iterated then: copy again."""
+        while True:
+            try:
+                return list(self.spans)
+            except RuntimeError:
+                continue
 
     def clear(self):
-        with self._lock:
-            self.spans = []
+        self.spans.clear()
 
 
 _LOG = _SpanLog()
 
 
 class RecordEvent:
-    """Span context manager / decorator (reference utils.py:37). Records a
-    host span and annotates the XLA trace when one is active."""
+    """Span context manager / decorator (reference utils.py:37). The
+    keyword arguments are the span's COUNTS (ints, floats, short
+    strings): they ride the `TraceAnnotation` into the device trace as
+    the event's stats and sit in the span's ring record; `set(**counts)`
+    adds the ones known only at the end. After exit `dur_s` holds the
+    duration, so a call site needs no clock pair of its own."""
 
-    def __init__(self, name: str, event_type=None):
+    def __init__(self, name: str, event_type=None, **counts):
         self.name = name
+        self.counts = counts or None
+        self.dur_s = None
         self._start = None
         self._annot = None
+        self._in_trace = False
 
     def begin(self):
+        self._in_trace = _TraceAnnotation.is_enabled()
         self._start = time.perf_counter()
         _LOG.push()
-        try:
-            import jax
-            self._annot = jax.profiler.TraceAnnotation(self.name)
-            self._annot.__enter__()
-        except Exception:
-            self._annot = None
+        self._annot = _TraceAnnotation(self.name, **(self.counts or {}))
+        self._annot.__enter__()
         return self
 
-    def end(self):
+    def set(self, **counts):
+        """Counts known only inside the span (tokens emitted, ...)."""
+        if self.counts is None:
+            self.counts = counts
+        else:
+            self.counts.update(counts)
         if self._annot is not None:
-            self._annot.__exit__(None, None, None)
-            self._annot = None
-        if self._start is not None:
-            _LOG.pop(self.name, self._start)
-            self._start = None
+            self._annot.set_metadata(**counts)
+
+    def end(self):
+        if self._start is None:
+            return
+        self._annot.__exit__(None, None, None)
+        self._annot = None
+        self.dur_s = time.perf_counter() - self._start
+        _LOG.pop(self.name, self._start, self.dur_s, self.counts,
+                 self._in_trace)
+        self._start = None
 
     __enter__ = begin
 
@@ -151,7 +198,7 @@ class RecordEvent:
 
         @functools.wraps(fn)
         def wrapped(*a, **k):
-            with RecordEvent(self.name):
+            with RecordEvent(self.name, **(self.counts or {})):
                 return fn(*a, **k)
         return wrapped
 
@@ -167,17 +214,20 @@ def export_chrome_trace(path: str, spans=None) -> str:
 
     Atomic tmp+rename write; returns `path`."""
     import os
-    spans = _LOG.spans if spans is None else spans
+    spans = _LOG.snapshot() if spans is None else spans
     pid = os.getpid()
     events = []
     for rec in list(spans):
         name, start, dur = rec[0], rec[1], rec[2]
         tid = rec[4] if len(rec) > 4 else 0
-        events.append({
+        event = {
             "name": name, "ph": "X", "cat": "host",
             "ts": round(start * 1e6, 3), "dur": round(dur * 1e6, 3),
             "pid": pid, "tid": tid,
-        })
+        }
+        if len(rec) > 5 and rec[5]:
+            event["args"] = dict(rec[5])
+        events.append(event)
     doc = {"traceEvents": events, "displayTimeUnit": "ms",
            "otherData": {"producer": "paddle_tpu.profiler"}}
     d = os.path.dirname(os.path.abspath(path))
@@ -305,7 +355,7 @@ class Profiler:
         profiler_statistic tables, host side)."""
         unit = {"s": 1.0, "ms": 1e3, "us": 1e6}.get(time_unit, 1e3)
         agg = {}
-        for name, _start, dur, _depth, *_tid in _LOG.spans:
+        for name, _start, dur, *_rest in _LOG.snapshot():
             c, tot, mx = agg.get(name, (0, 0.0, 0.0))
             agg[name] = (c + 1, tot + dur, max(mx, dur))
         lines = [f"{'name':<40} {'calls':>6} {'total':>10} {'avg':>10} "
@@ -330,8 +380,9 @@ class Profiler:
 
 
 def get_profiler_spans():
-    """Raw completed host spans [(name, start, dur_s, depth), ...]."""
-    return list(_LOG.spans)
+    """The ring's completed spans, oldest first:
+    [Span(name, start, dur_s, depth, tid, counts, in_trace), ...]."""
+    return _LOG.snapshot()
 
 
 def clear_profiler_spans():

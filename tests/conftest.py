@@ -45,6 +45,11 @@ def _reset_monitor_registry():
     mod = sys.modules.get("paddle_tpu.profiler.monitor")
     if mod is not None:
         mod.registry().reset()
+    # the RecordEvent span ring is process-global too: a traced test's
+    # in_trace spans must not be read by the next test's readers
+    prof = sys.modules.get("paddle_tpu.profiler")
+    if prof is not None:
+        prof.clear_profiler_spans()
     yield
 
 

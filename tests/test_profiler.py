@@ -68,6 +68,116 @@ class TestRecordEvent:
         assert any(s[0] == "manual" for s in get_profiler_spans())
 
 
+class TestSpanRing:
+    """The one recorder's record (docs/observability.md "Traces"):
+    (name, start, dur_s, depth, tid, counts, in_trace) in a bounded
+    ring, the counts in the device trace as the event's stats."""
+
+    def test_the_ring_is_bounded(self):
+        from paddle_tpu import profiler
+        clear_profiler_spans()
+        for i in range(profiler.SPAN_RING + 10):
+            with RecordEvent("flood", i=i):
+                pass
+        spans = get_profiler_spans()
+        assert len(spans) == profiler.SPAN_RING
+        assert spans[0][5]["i"] == 10           # the oldest fell out
+        assert spans[-1][5]["i"] == profiler.SPAN_RING + 9
+        clear_profiler_spans()
+        assert get_profiler_spans() == []
+
+    def test_a_copy_survives_appends_from_other_threads(self):
+        """Readers copy the ring while a concurrent router's workers, the
+        checkpoint thread or the watchdog append to it."""
+        import threading
+        clear_profiler_spans()
+        stop = threading.Event()
+
+        def writer():
+            while not stop.is_set():
+                with RecordEvent("bg"):
+                    pass
+        threads = [threading.Thread(target=writer) for _ in range(3)]
+        for t in threads:
+            t.start()
+        try:
+            for _ in range(200):
+                assert all(s.name == "bg" for s in get_profiler_spans())
+        finally:
+            stop.set()
+            for t in threads:
+                t.join()
+        clear_profiler_spans()
+
+    def test_counts_duration_and_the_five_field_prefix(self):
+        import threading
+        clear_profiler_spans()
+        with RecordEvent("outer", slot=3, kind="dense") as outer:
+            with RecordEvent("inner") as inner:
+                time.sleep(0.002)
+            outer.set(tokens=7)
+        inner_rec, outer_rec = get_profiler_spans()
+        assert len(outer_rec) == 7
+        assert outer_rec._fields == ("name", "start", "dur_s", "depth",
+                                     "tid", "counts", "in_trace")
+        assert outer_rec.counts is outer_rec[5] and not outer_rec.in_trace
+        name, start, dur, depth, tid = outer_rec[:5]
+        assert (name, depth, tid) == ("outer", 0, threading.get_ident())
+        assert dur == outer.dur_s >= inner.dur_s >= 0.002
+        assert start <= inner_rec[1]
+        assert outer_rec[5] == {"slot": 3, "kind": "dense", "tokens": 7}
+        assert inner_rec[3] == 1 and inner_rec[5] is None
+        # consumers that unpack the historical prefix keep working
+        for (n, s, d, dep, *_t) in get_profiler_spans():
+            assert isinstance(n, str) and d >= 0 and dep in (0, 1)
+
+    def test_in_trace_and_counts_reach_the_xplane(self, tmp_path):
+        """Tracing on means a profiler session is recording: in_trace
+        is sampled from it, and the counts arrive in the .xplane.pb as
+        the host event's stats."""
+        import glob
+        import jax
+        from jax.profiler import ProfileData
+        clear_profiler_spans()
+        with RecordEvent("before"):
+            pass
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with RecordEvent("traced.span", true_len=5, bucket=8) as ev:
+                ev.set(tokens=2)
+        finally:
+            jax.profiler.stop_trace()
+        with RecordEvent("after"):
+            pass
+        flags = {s[0]: s[6] for s in get_profiler_spans()}
+        assert flags == {"before": False, "traced.span": True,
+                         "after": False}
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        found = [dict(e.stats) for plane in ProfileData.from_file(path).planes
+                 for line in plane.lines for e in line.events
+                 if e.name == "traced.span"]
+        assert len(found) == 1
+        stats = {k: int(v) for k, v in found[0].items()
+                 if k in ("true_len", "bucket", "tokens")}
+        assert stats == {"true_len": 5, "bucket": 8, "tokens": 2}
+
+    def test_chrome_trace_carries_the_counts(self, tmp_path):
+        import json
+        from paddle_tpu.profiler import export_chrome_trace
+        clear_profiler_spans()
+        with RecordEvent("with_counts", n=4):
+            pass
+        with RecordEvent("bare"):
+            pass
+        path = export_chrome_trace(str(tmp_path / "host.json"))
+        events = {e["name"]: e for e in json.load(open(path))["traceEvents"]}
+        assert events["with_counts"]["args"] == {"n": 4}
+        assert "args" not in events["bare"]
+
+
 class TestProfiler:
     def test_step_loop_and_summary(self):
         clear_profiler_spans()
