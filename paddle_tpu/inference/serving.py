@@ -16,8 +16,10 @@ TPU-native design (everything jit-shaped, nothing dynamic on device):
   stacked KV cache ({"k","v"} buffers of [L, N, max_len, KV, hd] — the
   k/v pair realizes the single [L, 2, N, ...] buffer of the design
   with per-leaf donation, so XLA aliases both across ticks and the
-  cache never leaves the device). All writes are in-place
-  `dynamic_update_slice`es (kernels/decode_attention.write_kv).
+  cache never leaves the device). All writes are in place and touch
+  only the step's rows (kernels/decode_attention.write_kv: the cached
+  forwards carry the pool through their layer scan and write at
+  [layer, slot, position]).
 - **One jitted mixed decode step.** Every tick advances ALL slots one
   token under per-slot position/active masks: the per-row-position
   `forward_cached` (models/gpt.py, models/llama.py) runs the N tokens
@@ -899,9 +901,11 @@ class ServingEngine:
         self._cache = self._new_cache()
         self._base_key = self._rep(jax.random.PRNGKey(seed))
 
-        # at T=1 the layer scan's cache slice/restack dominates the
-        # matvecs: fully unroll shallow stacks (bit-identical numerics —
-        # models/gpt.py decode_scan_unroll). 0 = auto, 1 = keep the scan.
+        # fully unroll shallow stacks: at T=1 the matvecs are tiny and
+        # the loop's own per-layer steps weigh in (bit-identical numerics
+        # — models/gpt.py decode_scan_unroll; the KV pool rides the
+        # scan's carry either way and is never sliced or restacked).
+        # 0 = auto, 1 = keep the scan.
         # Auto only applies when the config still carries the field's
         # default (1): an explicitly tuned cfg.decode_scan_unroll wins.
         cfg_unroll = getattr(cfg, "decode_scan_unroll", None)

@@ -20,6 +20,13 @@ One implementation serves BOTH cache-position shapes:
   stays per-query-position causal, and multi-token per-row writes
   drop (never clamp) positions past the cache end.
 
+The cached forwards (models/gpt.py, models/llama.py) hold the cache as
+ONE stacked pool [L, ...] per k/v and carry it whole through their
+layer scan: `write_kv` / `write_kv_paged` with `layer=` write only the
+step's new rows at [layer, ...], in place, and `layer_view` reads the
+layer back for the attention — the pool is never sliced into per-layer
+buffers nor restacked (a 3.2 GB pool moved ~5x a tick that way).
+
 GQA is native: kc/vc carry KV heads; queries fold their group axis into
 the einsum so repeated KV is never materialized (models/llama.py's
 decode-bandwidth trade).
@@ -53,8 +60,9 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["write_kv", "cached_attention", "decode_attn_impl",
-           "gather_pages", "write_kv_paged", "attn_math_impl",
-           "cache_pspecs", "attended_tokens", "kv_view_extent"]
+           "gather_pages", "write_kv_paged", "layer_view",
+           "attn_math_impl", "cache_pspecs", "attended_tokens",
+           "kv_view_extent"]
 
 
 def cache_pspecs(paged: bool, tp_axis: str = "tp"):
@@ -101,7 +109,7 @@ def attn_math_impl(impl: str | None = None) -> str:
     return "dense" if impl == "paged" else impl
 
 
-def gather_pages(pages, table):
+def gather_pages(pages, table, layer=None):
     """Re-linearize per-slot cache views from the page pool.
 
     pages [P, page_size, KV, hd]; table [B, max_pages] int32 of
@@ -110,24 +118,28 @@ def gather_pages(pages, table):
     p // page_size at offset p % page_size) — so `cached_attention`
     over the view is bit-identical to the dense [B, S, ...] cache.
     Unmapped table entries point at the reserved scratch page 0; the
-    position mask keeps its garbage at an exact softmax 0."""
+    position mask keeps its garbage at an exact softmax 0. With
+    `layer` (a traced index) `pages` is the stacked pool
+    [L, P, page_size, KV, hd] and the pages are gathered straight from
+    [layer, page] — the layer's pages are never sliced out first."""
     B, mp = table.shape
-    ps = pages.shape[1]
-    v = jnp.take(pages, table.reshape(-1), axis=0)     # [B*mp, ps, KV, hd]
-    return v.reshape(B, mp * ps, *pages.shape[2:])
+    v = pages.at[_at_layer(layer) + (table.reshape(-1),)].get(mode="fill")
+    return v.reshape(B, mp * pages.shape[-3], *pages.shape[-2:])
 
 
-def write_kv_paged(pages, table, k, pos):
+def write_kv_paged(pages, table, k, pos, layer=None):
     """Scatter the step's k (or v) [B, T, KV, hd] into the page pool
     [P, page_size, KV, hd] through the per-slot table [B, max_pages].
     Token t of row b sits at logical position pos(+t) -> physical
     (table[b, p // ps], p % ps). Rows whose table maps to the scratch
     page (freed slots, positions past a slot's allocation) write
-    garbage there — never attended. The scatter is the paged analog of
-    write_kv's dynamic_update_slice: XLA keeps it in-place on the
-    donated pool buffer."""
+    garbage there — never attended. With `layer` (a traced index)
+    `pages` is the stacked pool [L, P, page_size, KV, hd] and the rows
+    land at [layer, page, offset] — the cached forwards' form: the
+    scatter touches the step's B*T rows only and XLA keeps it in place
+    on the donated pool carried through the layer scan."""
     B, T = k.shape[:2]
-    ps = pages.shape[1]
+    ps = pages.shape[-3]
     qpos = _query_positions(pos, B, T)                 # [B, T]
     raw_idx = qpos // ps
     page_idx = jnp.clip(raw_idx, 0, table.shape[1] - 1)
@@ -137,32 +149,59 @@ def write_kv_paged(pages, table, k, pos):
     page_id = jnp.where(raw_idx < table.shape[1], page_id, 0)
     off = qpos % ps
     upd = k.astype(pages.dtype).reshape(B * T, *k.shape[2:])
-    return pages.at[page_id.reshape(-1), off.reshape(-1)].set(upd)
+    return pages.at[_at_layer(layer) + (page_id.reshape(-1),
+                                        off.reshape(-1))].set(upd)
 
 
-def write_kv(kc, k, pos):
+def write_kv(kc, k, pos, layer=None):
     """Write the step's k (or v) [B, T, KV, hd] into the cache
     [B, S, KV, hd] at position(s) `pos` — scalar (one
-    dynamic_update_slice; XLA aliases the donated buffer) or [B]
-    per-row (each slot writes at its own offset, the serving engine's
-    in-place slot write). Per-row multi-token writes (T > 1 — the
-    speculative verify pass lands the current token + gamma drafts in
-    one call) go through a scatter whose out-of-bounds rows DROP: a
+    dynamic_update_slice) or [B] per-row (each slot writes at its own
+    offset, the serving engine's in-place slot write; T == 1 clamps a
+    position past the end onto the last slot, as a
+    dynamic_update_slice would). Per-row multi-token writes (T > 1 —
+    the speculative verify pass lands the current token + gamma drafts
+    in one call) go through a scatter whose out-of-bounds rows DROP: a
     draft position past the cache end must vanish, not clamp onto (and
-    corrupt) the row's tail the way dynamic_update_slice's
-    start-index clamping would."""
+    corrupt) the row's tail.
+
+    With `layer` (a traced index) `kc` is the stacked pool
+    [L, B, S, KV, hd] and the same rows land at [layer, ...] — the
+    cached forwards' form. Either way only the step's rows are
+    written, so on a donated buffer (or one carried through the layer
+    scan) XLA updates in place and the rest of the pool never moves."""
     k = k.astype(kc.dtype)
+    at = _at_layer(layer)
     if jnp.ndim(pos) == 0:
-        return jax.lax.dynamic_update_slice(kc, k, (0, pos, 0, 0))
+        return jax.lax.dynamic_update_slice(
+            kc, k[(None,) * len(at)], at + (0, pos, 0, 0))
     B, T = k.shape[:2]
+    rows = jnp.arange(B, dtype=jnp.int32)
     if T == 1:
-        return jax.vmap(
-            lambda c, u, p: jax.lax.dynamic_update_slice(c, u, (p, 0, 0))
-        )(kc, k, pos)
+        p = jnp.clip(pos, 0, kc.shape[-3] - 1)
+        return kc.at[at + (rows, p)].set(k[:, 0],
+                                         mode="promise_in_bounds")
     qpos = _query_positions(pos, B, T)                 # [B, T]
-    rows = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None],
-                            (B, T))
-    return kc.at[rows, qpos].set(k, mode="drop")
+    return kc.at[at + (jnp.broadcast_to(rows[:, None], (B, T)),
+                       qpos)].set(k, mode="drop")
+
+
+def layer_view(pool, layer, table=None):
+    """What layer `layer` (a traced index) of the stacked pool holds,
+    as `cached_attention` wants it: the dense row block
+    [B, S, KV, hd] read straight out of [L, B, S, KV, hd], or — with
+    the page `table` — the layer's pages re-linearized per slot
+    (`gather_pages`). A read that XLA fuses into its consumer: the
+    cached forwards carry the pool whole and never restack it."""
+    if table is None:
+        return jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+    return gather_pages(pool, table, layer)
+
+
+def _at_layer(layer):
+    """Index prefix of the write forms: () for one layer's cache,
+    (layer,) for the stacked pool."""
+    return () if layer is None else (layer,)
 
 
 def attended_tokens(positions, active):

@@ -73,10 +73,9 @@ class GPTConfig:
     # default stays 1 (numerics identical either way)
     scan_unroll: int = 1
     # unroll for the CACHED decode path's layer scan (forward_cached):
-    # at T=1 the scan's per-layer cache slice/restack dominates the tiny
-    # matvecs (measured 3.3 -> 2.0 ms/tick on the CPU serving bench at
-    # 2L x 128d x 8 slots), so the serving engine auto-raises this for
-    # shallow models; numerics are bit-identical either way
+    # at T=1 the matvecs are tiny and the loop's own per-layer steps
+    # weigh in, so the serving engine auto-raises this for shallow
+    # models; numerics are bit-identical either way
     decode_scan_unroll: int = 1
     sequence_parallel: bool = True            # SP on the 'mp' axis
     # context parallelism for long sequences: "none" | "ring" | "ulysses";
@@ -690,11 +689,14 @@ GPT3_CONFIGS = {
 # KV-cache decode path (reference: FusedMultiTransformer inference decoder,
 # incubate/nn/layer/fused_transformer.py:1022, and the inference
 # AnalysisPredictor's decoder workloads). TPU-native: the cache is one
-# stacked [L, B, max_len, H, hd] buffer per k/v whose layer axis scans with
-# the stacked params; prefill writes the prompt's k/v while running the
-# causal forward, decode steps are single-token dense attention over the
-# cache (a bandwidth-bound matvec — flash tiling buys nothing at T=1, and
-# dense masking keeps kv_len dynamic under jit).
+# stacked [L, B, max_len, H, hd] buffer per k/v, carried WHOLE through the
+# layer scan (the stacked params and the layer index are what scans): each
+# layer writes only the step's new rows at [layer, ...] in place and reads
+# its own rows back for the attention, so no layer's cache is sliced out or
+# restacked. Prefill writes the prompt's k/v while running the causal
+# forward, decode steps are single-token dense attention over the cache (a
+# bandwidth-bound matvec — flash tiling buys nothing at T=1, and dense
+# masking keeps kv_len dynamic under jit).
 # --------------------------------------------------------------------------
 def init_kv_cache(cfg: GPTConfig, batch: int, max_len: int):
     """→ {"k","v": [L, B, max_len, H, hd]} in the activation dtype."""
@@ -703,14 +705,15 @@ def init_kv_cache(cfg: GPTConfig, batch: int, max_len: int):
             "v": jnp.zeros(shape, cfg.dtype)}
 
 
-def _cached_attention(x, params_l, kc, vc, pos, cfg, pt=None):
-    """One block's attention with cache update. x [B,T,D]; kc/vc
-    [B,max_len,H,hd] (dense) or [P,page_size,H,hd] pages with the
-    per-slot page table `pt` [B,max_pages] (the serving engine's paged
-    pool); pos = number of tokens already in the cache — a scalar
-    (whole-batch decode) or a [B] vector of per-row positions (the
-    serving engine's slot pool, where every slot advances
-    independently). Returns (attn_out, kc, vc). The cache write and the
+def _cached_attention(x, params_l, layer, kc, vc, pos, cfg, pt=None):
+    """Block `layer`'s attention with cache update. x [B,T,D]; kc/vc
+    the STACKED pools [L,B,max_len,H,hd] (dense) or [L,P,page_size,H,hd]
+    pages with the per-slot page table `pt` [B,max_pages] (the serving
+    engine's paged pool); pos = number of tokens already in the cache —
+    a scalar (whole-batch decode) or a [B] vector of per-row positions
+    (the serving engine's slot pool, where every slot advances
+    independently). Returns (attn_out, kc, vc) — the same pools with
+    the step's rows written at [layer, ...]. The cache write and the
     masked attention go through the selectable decode-attention seam
     (kernels/decode_attention.py; registry kernel 'decode_attention');
     the paged path scatters the write through the table and attends a
@@ -725,21 +728,18 @@ def _cached_attention(x, params_l, kc, vc, pos, cfg, pt=None):
     q = q.reshape(B, T, H, hd)
     k = k.reshape(B, T, H, hd)
     v = v.reshape(B, T, H, hd)
-    from ..kernels.decode_attention import (cached_attention, gather_pages,
+    from ..kernels.decode_attention import (cached_attention, layer_view,
                                             write_kv, write_kv_paged)
     with jax.named_scope("kv_update"):
         if pt is None:
-            kc = write_kv(kc, k, pos)
-            vc = write_kv(vc, v, pos)
+            kc = write_kv(kc, k, pos, layer)
+            vc = write_kv(vc, v, pos, layer)
         else:
-            kc = write_kv_paged(kc, pt, k, pos)
-            vc = write_kv_paged(vc, pt, v, pos)
+            kc = write_kv_paged(kc, pt, k, pos, layer)
+            vc = write_kv_paged(vc, pt, v, pos, layer)
     with jax.named_scope("decode_attention"):
-        if pt is None:
-            ctx = cached_attention(q, kc, vc, pos)
-        else:
-            ctx = cached_attention(q, gather_pages(kc, pt),
-                                   gather_pages(vc, pt), pos)
+        ctx = cached_attention(q, layer_view(kc, layer, pt),
+                               layer_view(vc, layer, pt), pos)
     ctx = ctx.reshape(B, T, D).astype(x.dtype)
     out = leaf_matmul(ctx, params_l, "attn_out_w")
     if params_l.get("attn_out_b") is not None:
@@ -759,7 +759,7 @@ def gpt_forward_cached(params, tokens, cache, pos, cfg: GPTConfig,
     [B] vector of per-row slot positions (inference/serving.py: each
     slot holds its own request mid-stream).
 
-    `layers` (static) truncates the stacked scan to the FIRST `layers`
+    `layers` (static) truncates the layer scan to the FIRST `layers`
     blocks, with the final norm + tied LM head applied to the
     truncated stack's output — the self-draft pass of speculative
     decoding (inference/spec_decode.py). The cache must then be the
@@ -805,14 +805,14 @@ def gpt_forward_cached(params, tokens, cache, pos, cfg: GPTConfig,
         n_layers = int(layers)
     from ..kernels.quant_matmul import leaf_matmul, quant_matmul
 
-    def scan_fn(x, layer_in):
-        params_l, kc, vc = layer_in
-        h = x
+    def scan_fn(carry, layer_in):
+        h, kc, vc = carry
+        params_l, layer = layer_in
         with jax.named_scope("attention"):
             a_in = _ln(h, params_l["ln1_scale"], params_l["ln1_bias"],
                        cfg.layer_norm_eps)
-            a, kc, vc = _cached_attention(a_in, params_l, kc, vc, pos,
-                                          cfg, pt=pt)
+            a, kc, vc = _cached_attention(a_in, params_l, layer, kc, vc,
+                                          pos, cfg, pt=pt)
         h = h + a
         with jax.named_scope("mlp"):
             m_in = _ln(h, params_l["ln2_scale"], params_l["ln2_bias"],
@@ -834,10 +834,14 @@ def gpt_forward_cached(params, tokens, cache, pos, cfg: GPTConfig,
                 m = leaf_matmul(mh, params_l, "mlp_down_w")
                 if params_l.get("mlp_down_b") is not None:
                     m = m + params_l["mlp_down_b"].astype(m.dtype)
-        return h + m, (kc, vc)
+        return (h + m, kc, vc), None
 
-    x, (kcs, vcs) = jax.lax.scan(
-        scan_fn, x, (stacked, cache["k"], cache["v"]),
+    # the pools ride the CARRY, never xs/ys: as xs the scan would slice
+    # each layer out into its own buffer, as ys restack every layer's
+    # whole slice into a new pool and copy that onto the donated one
+    (x, kcs, vcs), _ = jax.lax.scan(
+        scan_fn, (x, cache["k"], cache["v"]),
+        (stacked, jnp.arange(n_layers, dtype=jnp.int32)),
         unroll=max(1, min(getattr(cfg, "decode_scan_unroll", 1),
                           n_layers)))
     with jax.named_scope("lm_head"):

@@ -227,10 +227,11 @@ def train_step(params, opt_state, batch, cfg: LlamaConfig, lr=3e-4,
 
 
 # --------------------------------------------------------------------------
-# KV-cache decode (same design as models/gpt.py:575 — stacked [L, ...]
-# cache scanned with the stacked params; dense masked attention over the
-# cache at decode). The GQA payoff lands here: the cache holds KV heads,
-# not query heads, shrinking HBM traffic per decoded token by H/KV.
+# KV-cache decode (same design as models/gpt.py — one stacked [L, ...]
+# cache carried whole through the layer scan and written in place at
+# [layer, ...]; dense masked attention over the cache at decode). The
+# GQA payoff lands here: the cache holds KV heads, not query heads,
+# shrinking HBM traffic per decoded token by H/KV.
 # --------------------------------------------------------------------------
 def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int):
     """-> {"k","v": [L, B, max_len, KV, hd]} in the activation dtype."""
@@ -290,12 +291,13 @@ def llama_forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
     if layers is not None:
         stacked = {k: v[:layers] for k, v in stacked.items()}
         n_layers = int(layers)
-    from ..kernels.decode_attention import (cached_attention, gather_pages,
+    from ..kernels.decode_attention import (cached_attention, layer_view,
                                             write_kv, write_kv_paged)
     from ..kernels.quant_matmul import leaf_matmul, quant_matmul
 
-    def scan_fn(x, layer_in):
-        lp, kc, vc = layer_in
+    def scan_fn(carry, layer_in):
+        x, kc, vc = carry
+        lp, layer = layer_in
         h = _rmsnorm(x, lp["attn_norm"], cfg.rms_eps)
         q = leaf_matmul(h, lp, "q_w").reshape(B, T, H, hd)
         k = leaf_matmul(h, lp, "k_w").reshape(B, T, KV, hd)
@@ -303,23 +305,25 @@ def llama_forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
         q = _apply_rope(q, cos, sin)
         k = _apply_rope(k, cos, sin)
         if pt is None:
-            kc = write_kv(kc, k, pos)
-            vc = write_kv(vc, v, pos)
-            ctx = cached_attention(q, kc, vc, pos)
+            kc = write_kv(kc, k, pos, layer)
+            vc = write_kv(vc, v, pos, layer)
         else:
-            kc = write_kv_paged(kc, pt, k, pos)
-            vc = write_kv_paged(vc, pt, v, pos)
-            ctx = cached_attention(q, gather_pages(kc, pt),
-                                   gather_pages(vc, pt), pos)
+            kc = write_kv_paged(kc, pt, k, pos, layer)
+            vc = write_kv_paged(vc, pt, v, pos, layer)
+        ctx = cached_attention(q, layer_view(kc, layer, pt),
+                               layer_view(vc, layer, pt), pos)
         ctx = ctx.reshape(B, T, H * hd).astype(x.dtype)
         x = x + leaf_matmul(ctx, lp, "o_w")
         h = _rmsnorm(x, lp["ffn_norm"], cfg.rms_eps)
         gated = jax.nn.silu(leaf_matmul(h, lp, "gate_w")) * \
             leaf_matmul(h, lp, "up_w")
-        return x + leaf_matmul(gated, lp, "down_w"), (kc, vc)
+        return (x + leaf_matmul(gated, lp, "down_w"), kc, vc), None
 
-    x, (kcs, vcs) = jax.lax.scan(
-        scan_fn, x, (stacked, cache["k"], cache["v"]),
+    # the pools ride the carry and are written in place at [layer, ...]
+    # (models/gpt.py gpt_forward_cached — same form, same reason)
+    (x, kcs, vcs), _ = jax.lax.scan(
+        scan_fn, (x, cache["k"], cache["v"]),
+        (stacked, jnp.arange(n_layers, dtype=jnp.int32)),
         unroll=max(1, min(getattr(cfg, "decode_scan_unroll", 1),
                           n_layers)))
     x = _rmsnorm(x, params["norm_f"], cfg.rms_eps)

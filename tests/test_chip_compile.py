@@ -156,11 +156,12 @@ def test_gpt_350m_train_step_compiles_on_four_chips(degrees, topo, as_tpu):
         assert colls["all-to-all"] > 0
 
 
-@pytest.mark.parametrize("tp", [1, 4])
-def test_gpt_1p3b_paged_decode_tick_compiles(tp, topo, as_tpu):
-    """The serving engine's decode tick over the paged pool at the
-    smoke's shape (8 slots, 1024 positions, pages of 16), on one chip
-    and sharded four ways the way ServingEngine(mesh=) places it."""
+def _lower_decode_tick(topo, tp, slots, max_len, page_size=0):
+    """The serving engine's greedy decode tick for GPT-1.3B (`tele` and
+    `guard` on, as ServingEngine builds it), compiled for `tp` described
+    chips the way ServingEngine(mesh=) places it, over the dense pool or
+    — with `page_size` — the paged one. -> (compiled, pool shape, the
+    parameter tree's shapes)."""
     from paddle_tpu.inference.serving import _decode_tick, family_for
     from paddle_tpu.kernels.decode_attention import cache_pspecs
     from paddle_tpu.models.gpt import init_gpt_params
@@ -176,13 +177,18 @@ def test_gpt_1p3b_paged_decode_tick_compiles(tp, topo, as_tpu):
     params = {n: S(v.shape, v.dtype, sharding=sharding_for(
         fam.serving_specs.get(n, P()), mesh, shape=v.shape))
         for n, v in shapes.items()}
-    n, ps = SIZES.slots, 16
-    max_pages = -(-SIZES.max_len // ps)
-    pool = (cfg.num_layers, n * max_pages + 1, ps, cfg.num_heads,
-            cfg.head_dim)
-    cache = {"k": S(pool, cfg.dtype), "v": S(pool, cfg.dtype),
-             "pt": S((n, max_pages), jnp.int32)}
-    specs = cache_pspecs(True, "tp")
+    n, oor_pos = slots, None
+    if page_size:
+        max_pages = -(-max_len // page_size)
+        pool = (cfg.num_layers, n * max_pages + 1, page_size,
+                cfg.num_heads, cfg.head_dim)
+        oor_pos = max_pages * page_size
+    else:
+        pool = (cfg.num_layers, n, max_len, cfg.num_heads, cfg.head_dim)
+    cache = {"k": S(pool, cfg.dtype), "v": S(pool, cfg.dtype)}
+    if page_size:
+        cache["pt"] = S((n, max_pages), jnp.int32)
+    specs = cache_pspecs(bool(page_size), "tp")
     pin = {k: sharding_for(specs.get(k, P()), mesh, shape=v.shape)
            for k, v in cache.items()}
     cache = {k: S(v.shape, v.dtype, sharding=pin[k])
@@ -195,14 +201,52 @@ def test_gpt_1p3b_paged_decode_tick_compiles(tp, topo, as_tpu):
         jnp.int32, jnp.int32))
     tick = jax.jit(
         functools.partial(_decode_tick, fwd=fam.forward_cached, cfg=cfg,
-                          max_top_k=0, guard=True,
-                          oor_pos=max_pages * ps, cache_pin=pin, tele=True),
+                          max_top_k=0, guard=True, oor_pos=oor_pos,
+                          cache_pin=pin, tele=True),
         donate_argnums=(1, 2), static_argnames=("sampling",))
     compiled = tick.lower(params, cache, state, rep_of((2,), jnp.uint32),
                           rep_of((n,), jnp.float32),
                           sampling=False).compile()
-    per_device = _device_bytes(compiled)
-    assert per_device < HBM_BYTES
+    return compiled, pool, shapes
+
+
+def _pool_movers(compiled, pool, tp=1) -> list:
+    """Instructions of the compiled tick that produce a WHOLE new KV
+    pool (per-device shape): a `copy` or a fresh `AllocateBuffer`. The
+    in-place row write (a scatter / dynamic-update-slice fusion on the
+    donated, aliased buffer) also has the pool's shape and is what the
+    tick should be left with."""
+    dims = list(pool)
+    dims[3] //= tp                       # head-sharded (cache_pspecs)
+    shape = "bf16[" + ",".join(map(str, dims)) + "]"
+    return [ln.strip()[:160] for ln in compiled.as_text().splitlines()
+            if f"= {shape}" in ln
+            and (" copy(" in ln or "AllocateBuffer" in ln)]
+
+
+def test_gpt_1p3b_dense_decode_tick_moves_no_pool(topo, as_tpu):
+    """The benchmark's serving cell (16 slots x 1024, dense pool): the
+    layer scan carries the two 1.6 GB pools and writes the tick's 16 new
+    rows in place. Riding the scan as xs/ys they cost two pool-sized
+    `copy`s, two `AllocateBuffer`s and 5.77 GB of temporaries."""
+    compiled, pool, _ = _lower_decode_tick(topo, 1, slots=16, max_len=1024)
+    assert _pool_movers(compiled, pool) == []
+    ma = compiled.memory_analysis()
+    pools = 2 * int(np.prod(pool)) * 2
+    assert ma.alias_size_in_bytes >= pools
+    assert ma.temp_size_in_bytes < 3.0e9
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("tp", [1, 4])
+def test_gpt_1p3b_paged_decode_tick_compiles(tp, topo, as_tpu):
+    """The serving engine's decode tick over the paged pool at the
+    smoke's shape (8 slots, 1024 positions, pages of 16), on one chip
+    and sharded four ways the way ServingEngine(mesh=) places it."""
+    compiled, pool, shapes = _lower_decode_tick(
+        topo, tp, slots=SIZES.slots, max_len=SIZES.max_len, page_size=16)
+    assert _device_bytes(compiled) < HBM_BYTES
+    assert _pool_movers(compiled, pool, tp) == []
     if tp > 1:
         # row-parallel matmuls reduce over tp, and no device holds the
         # whole parameter tree
