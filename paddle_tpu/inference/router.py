@@ -1312,6 +1312,12 @@ def create_router(params, cfg, replicas: int = 2, family: str = "gpt",
     if meshes is not None and len(meshes) != replicas:
         raise ValueError(f"meshes ({len(meshes)}) must match "
                          f"replicas ({replicas})")
+    from .serving import UnsupportedOptionError, family_for
+    fam = family_for(family) if isinstance(family, str) else family
+    for option, asked in (("journal_dir", journal_dir is not None),
+                          ("migration", roles is not None)):
+        if asked and option in fam.refuses:
+            raise UnsupportedOptionError(fam.name, option)
     tele = engine_kw.pop("telemetry_jsonl", None)
     placed = [params] * replicas
     if meshes is None:

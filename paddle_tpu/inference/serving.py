@@ -261,7 +261,7 @@ import hashlib
 import json
 import sys
 import time
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
@@ -317,33 +317,97 @@ class ServingFaultError(RuntimeError):
 
 
 # --------------------------------------------------------------- families
+class UnsupportedOptionError(ValueError):
+    """An engine option that the model family cannot run yet was asked
+    for (`.option` names it, `.family` the family): the engine refuses
+    at construction rather than serve wrongly."""
+
+    def __init__(self, family: str, option: str, why: str = ""):
+        super().__init__(
+            f"family {family!r} does not support {option}"
+            + (f": {why}" if why else ""))
+        self.family = family
+        self.option = option
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelFamily:
     """The seam a model family exposes to the engine: a cached forward
     that accepts per-row positions (slot-indexed writes) and a cache
-    factory. Both flagship decoders qualify; any future family that
-    implements the same contract plugs in here. `serving_specs` is the
-    family's module-level tensor-parallel spec table (leaf name ->
-    PartitionSpec over the serving mesh's 'tp' axis — models/gpt.py /
-    models/llama.py SERVING_PARAM_SPECS); None means the family cannot
-    shard (mesh= is then refused)."""
+    factory. Any family that implements the contract plugs in here.
+
+    The cache is a dict of POOLS BY LAYER KIND, each
+    [layers of that kind, slots, positions, KV, hd] with the slot on
+    axis 1: the uniform families have one kind, `{"k", "v"}` over
+    `max_len` positions; a family with window layers adds a ring pool
+    (`{"k_win", "v_win"}` over `window` positions). The engine
+    allocates, donates and counts the dict whole; only the family reads
+    a pool. (The paged layout and the snapshot paths still assume the
+    uniform `{"k", "v"}` and are among what such a family `refuses`.)
+
+    `serving_specs` is the family's module-level tensor-parallel spec
+    table (leaf name -> PartitionSpec over the serving mesh's 'tp' axis
+    — models/gpt.py / models/llama.py SERVING_PARAM_SPECS); None means
+    the family cannot shard (mesh= is then refused). `prefill` replaces
+    the engine's own bucketed prefill (a fresh `init_cache(cfg, 1,
+    bucket)`, the forward, the row copied into the slot) where a pool
+    is not written that way. `counts` is for a family whose forward
+    leaves a row of int32 counts in the cache's "stats" leaf: it turns
+    the pulled row into span counts by name. The forward then takes
+    `live=` (which rows are real tokens), and the row rides the tick's
+    one pull onto the `serving.decode_tick` / `serving.prefill` spans. `refuses` names the engine options (`REFUSABLE`) that raise
+    UnsupportedOptionError for this family."""
     name: str
     forward_cached: Callable    # (params, tokens[B,T], cache, pos, cfg)
-    init_cache: Callable        # (cfg, batch, max_len) -> {"k","v"}
+    init_cache: Callable        # (cfg, batch, max_len) -> pools by kind
     serving_specs: Optional[dict] = None
+    prefill: Optional[Callable] = None   # (params, cache, padded [1,Tb],
+    #                 true_len, slot, cfg) -> (last logits [1,V], cache)
+    counts: Optional[Callable] = None    # (cfg, stats row) -> dict
+    refuses: Tuple[str, ...] = ()
+
+
+# engine options a family may refuse, as UnsupportedOptionError names them
+REFUSABLE = ("kv_layout='paged'", "prefill_chunk", "spec_decode",
+             "multi_tick", "mesh", "quant", "host_kv_bytes", "migration",
+             "journal_dir")
+
+
+def _gpt_family() -> ModelFamily:
+    from ..models import gpt
+    return ModelFamily("gpt", gpt.gpt_forward_cached, gpt.init_kv_cache,
+                       gpt.SERVING_PARAM_SPECS)
+
+
+def _llama_family() -> ModelFamily:
+    from ..models import llama
+    return ModelFamily("llama", llama.llama_forward_cached,
+                       llama.init_kv_cache, llama.SERVING_PARAM_SPECS)
+
+
+def _cohere2_moe_family() -> ModelFamily:
+    from ..models import cohere2_moe as m
+    return ModelFamily("cohere2_moe", m.cohere2_moe_forward_cached,
+                       m.init_kv_cache, None, prefill=m.prefill_into_slot,
+                       counts=m.span_counts, refuses=REFUSABLE)
+
+
+# name -> factory; a family's module is imported when it is asked for
+_FAMILIES = {"gpt": _gpt_family, "llama": _llama_family,
+             "cohere2_moe": _cohere2_moe_family}
 
 
 def family_for(name: str) -> ModelFamily:
-    if name == "gpt":
-        from ..models import gpt
-        return ModelFamily("gpt", gpt.gpt_forward_cached,
-                           gpt.init_kv_cache, gpt.SERVING_PARAM_SPECS)
-    if name == "llama":
-        from ..models import llama
-        return ModelFamily("llama", llama.llama_forward_cached,
-                           llama.init_kv_cache,
-                           llama.SERVING_PARAM_SPECS)
-    raise ValueError(f"unknown model family {name!r} (gpt|llama)")
+    if name not in _FAMILIES:
+        raise ValueError(f"unknown model family {name!r} "
+                         f"({'|'.join(_FAMILIES)})")
+    return _FAMILIES[name]()
+
+
+def _pool_bytes(cache) -> int:
+    """Device bytes of the K/V pools of a cache dict (every kind)."""
+    return sum(int(v.nbytes) for k, v in cache.items()
+               if k not in ("pt", "stats"))
 
 
 # -------------------------------------------------------------- page pool
@@ -582,7 +646,7 @@ def _pin_cache(cache, pin):
 #   (cur_tok, positions, active, temps, top_ks, req_ids, gen_idx)
 def _decode_tick(params, cache, state, base_key, poison, *, fwd, cfg,
                  max_top_k, sampling, guard, oor_pos=None,
-                 cache_pin=None, tele=False):
+                 cache_pin=None, tele=False, counting=False):
     """THE mixed step: all N slots advance one token. Each slot's
     current token is written at its own position; sampling runs in-jit;
     inactive slots compute too (fixed shape) but their output is masked
@@ -601,7 +665,9 @@ def _decode_tick(params, cache, state, base_key, poison, *, fwd, cfg,
     `tele` (static, baked per engine) additionally returns the
     TICK_FIELDS int32 row (profiler/serving_telemetry) computed from
     values the tick already holds — it rides the same host pull as
-    the token array and never touches the stream math."""
+    the token array and never touches the stream math. `counting`
+    (static, ModelFamily.counts) hands the forward the active mask as
+    `live=`: its counts leave idle slots out."""
     toks, positions, active, temps, top_ks, req_ids, gen_idx = state
     # under the paged layout the pool is SHARED across rows, so an
     # inactive row (mid-chunked-prefill, its table already mapping
@@ -611,7 +677,8 @@ def _decode_tick(params, cache, state, base_key, poison, *, fwd, cfg,
     # their cache row outright, so oor_pos stays None there)
     fpos = (positions if oor_pos is None
             else jnp.where(active, positions, oor_pos))
-    logits, cache = fwd(params, toks[:, None], cache, fpos, cfg)
+    logits, cache = fwd(params, toks[:, None], cache, fpos, cfg,
+                        **({"live": active[:, None]} if counting else {}))
     lg = logits[:, 0].astype(jnp.float32)
     if guard:
         lg = lg * poison[:, None]
@@ -646,7 +713,7 @@ def _decode_tick(params, cache, state, base_key, poison, *, fwd, cfg,
 
 def _prefill_slot(params, cache, padded, true_len, slot, temps, top_ks,
                   req_ids, base_key, *, fwd, init_cache, cfg, max_top_k,
-                  sampling, guard, cache_pin=None):
+                  sampling, guard, cache_pin=None, family_prefill=None):
     """Bucketed prefill of ONE request into slot `slot`: run the padded
     prompt through a fresh single-row BUCKET-length cache (bit-identical
     K/V and logits to the greedy driver's full-length prefill — the
@@ -657,11 +724,18 @@ def _prefill_slot(params, cache, padded, true_len, slot, temps, top_ks,
     the bucket length only (true_len/slot are traced scalars). With
     `guard` (static, baked per engine) a non-finite first-token logit
     row folds into a -1 sentinel token — the quarantine verdict rides
-    the pull the admission already makes."""
-    mini = init_cache(cfg, 1, padded.shape[1])
-    logits, mini = fwd(params, padded, mini, 0, cfg)
-    last = jax.lax.dynamic_slice_in_dim(
-        logits, true_len - 1, 1, axis=1)[:, 0].astype(jnp.float32)
+    the pull the admission already makes. `family_prefill`
+    (ModelFamily.prefill) stands in for the forward and the row copy
+    where the family's pools are not written that way."""
+    if family_prefill is not None:
+        last, cache = family_prefill(params, cache, padded, true_len, slot,
+                                     cfg)
+        mini = None
+    else:
+        mini = init_cache(cfg, 1, padded.shape[1])
+        logits, mini = fwd(params, padded, mini, 0, cfg)
+        last = jax.lax.dynamic_slice_in_dim(
+            logits, true_len - 1, 1, axis=1)[:, 0].astype(jnp.float32)
     if sampling:
         keys = _slot_keys(base_key, req_ids, jnp.zeros((1,), jnp.int32))
         first = _sample(last, temps, top_ks, keys, max_top_k)[0]
@@ -669,12 +743,13 @@ def _prefill_slot(params, cache, padded, true_len, slot, temps, top_ks,
         first = jnp.argmax(last, axis=-1).astype(jnp.int32)[0]
     if guard:
         first = jnp.where(jnp.all(jnp.isfinite(last)), first, -1)
-    cache = {
-        "k": jax.lax.dynamic_update_slice(
-            cache["k"], mini["k"], (0, slot, 0, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(
-            cache["v"], mini["v"], (0, slot, 0, 0, 0)),
-    }
+    if mini is not None:
+        cache = {
+            "k": jax.lax.dynamic_update_slice(
+                cache["k"], mini["k"], (0, slot, 0, 0, 0)),
+            "v": jax.lax.dynamic_update_slice(
+                cache["v"], mini["v"], (0, slot, 0, 0, 0)),
+        }
     return first, _pin_cache(cache, cache_pin)
 
 
@@ -757,6 +832,23 @@ class ServingEngine:
                        else family)
         self.cfg = cfg
         self.num_slots = int(num_slots)
+        # what the family cannot run yet is refused by name; an option
+        # left at 'auto' resolves to off for it instead
+        refuses = self.family.refuses
+        if mesh is not None:
+            self._refuse("mesh")
+        if "spec_decode" in refuses and spec_decode == "auto":
+            spec_decode = "off"
+        if "multi_tick" in refuses and multi_tick in (0, "auto"):
+            multi_tick = 1
+        if "kv_layout='paged'" in refuses and kv_layout == "auto":
+            kv_layout = "dense"
+        if "quant" in refuses and quant == "auto":
+            quant = "off"
+        if prefill_chunk:
+            self._refuse("prefill_chunk")
+        if host_kv_bytes:
+            self._refuse("host_kv_bytes")
         # --------------------------------------- tensor-parallel serving
         # mesh= shards THIS engine's decode tick over `tp_axis`: params
         # per the family's module-level SERVING_PARAM_SPECS (the
@@ -794,6 +886,8 @@ class ServingEngine:
         # whether drafts CAN run: set_spec_drafts (brownout) may flip
         # self.spec live, but only back up to this construction-time cap
         self._spec_capable = self.spec
+        if self.spec:
+            self._refuse("spec_decode")
         n_layers = int(getattr(cfg, "num_layers", 0))
         self.spec_gamma = int(gamma)
         self.spec_draft_layers = int(draft_layers) or max(1, n_layers // 2)
@@ -822,6 +916,8 @@ class ServingEngine:
         # the jit cache keys of engines with different K never collide.
         from .multi_tick import resolve_multi_tick
         self.mt_k = resolve_multi_tick(multi_tick)
+        if self.mt_k > 1:
+            self._refuse("multi_tick")
         # per-dispatch emission width: how many tokens one host pull
         # may carry per slot (spec emits gamma+1 columns per tick)
         self._tick_span = self.mt_k * ((self.spec_gamma + 1)
@@ -835,6 +931,8 @@ class ServingEngine:
             raise ValueError(f"kv_layout {kv_layout!r} "
                              "(auto|dense|paged)")
         self.paged = kv_layout == "paged"
+        if self.paged:
+            self._refuse("kv_layout='paged'")
         self.page_size = int(page_size)
         self.prefill_chunk = int(prefill_chunk)
         self.prefix_sharing = bool(prefix_sharing)
@@ -872,6 +970,8 @@ class ServingEngine:
         # pull per tick, same trace ceilings.
         from ..kernels.quant_matmul import resolve_quant
         self.quant = resolve_quant(quant)
+        if self.quant:
+            self._refuse("quant")
         self._serving_specs = self.family.serving_specs
         self._quant_info = None
         if self.quant:
@@ -1036,12 +1136,11 @@ class ServingEngine:
         # x per-page bytes, republished with the page gauges
         self._m_kv_bytes = monitor.gauge("serving.kv_pool_bytes")
         self._m_oom = monitor.counter("serving.oom_forensics")
-        _kb = self._cache["k"]
         if self.paged:
-            self._page_bytes = 2 * _kb.nbytes // self.num_pages
+            self._page_bytes = _pool_bytes(self._cache) // self.num_pages
             self._publish_pool_gauges()
         else:
-            self._m_kv_bytes.set(2 * _kb.nbytes)
+            self._m_kv_bytes.set(_pool_bytes(self._cache))
         # ------------------------------------------ host-tier KV offload
         # paged + prefix_sharing only: the pool's LRU eviction demotes
         # registered pages to host ndarrays instead of dropping them,
@@ -1090,6 +1189,11 @@ class ServingEngine:
             self._qmm_draft = (self._quant_info["per_layer"]
                                * self.spec_draft_layers
                                + self._quant_info["head"])
+
+    def _refuse(self, option: str) -> None:
+        """Raise UnsupportedOptionError if the family refuses `option`."""
+        if option in self.family.refuses:
+            raise UnsupportedOptionError(self.family.name, option)
 
     # -------------------------------------------------------- page pool
     def _init_paged_cache(self):
@@ -1229,7 +1333,8 @@ class ServingEngine:
                                   cfg=run_cfg, max_top_k=self.max_top_k,
                                   guard=self.guardrails, oor_pos=_oor,
                                   cache_pin=self._cache_pin,
-                                  tele=self._tick_tele),
+                                  tele=self._tick_tele,
+                                  counting=bool(self.family.counts)),
                 donate_argnums=(1, 2), static_argnames=("sampling",))
         self._decode_variants[bool(spec)] = fn
         return fn
@@ -1291,7 +1396,8 @@ class ServingEngine:
                                   init_cache=self.family.init_cache,
                                   cfg=run_cfg, max_top_k=self.max_top_k,
                                   guard=self.guardrails,
-                                  cache_pin=self._cache_pin),
+                                  cache_pin=self._cache_pin,
+                                  family_prefill=self.family.prefill),
                 donate_argnums=(1,), static_argnames=("sampling",))
 
     def pool_stats(self) -> dict:
@@ -1381,6 +1487,13 @@ class ServingEngine:
         `profiler.mem_audit.audit_serving_memory` diffs against the
         compiled decode tick, and the first page of an oom_forensics
         dump."""
+        if self.family.refuses:
+            # no cost-model dims for this family: what the device holds
+            weights = sum(int(v.nbytes) for v in
+                          jax.tree_util.tree_leaves(self._params))
+            kv = _pool_bytes(self._cache)
+            return {"weights": weights, "kv_pool_device": kv,
+                    "total": weights + kv}
         from ..cost_model import jnp_dtype_bytes, serving_memory_ledger
         return serving_memory_ledger(
             self.cfg, family=self.family.name,
@@ -1988,13 +2101,22 @@ class ServingEngine:
                     # [N, gamma+1] emission matrix under spec) — with
                     # in-tick telemetry the TICK_FIELDS row rides the
                     # SAME pull (a tuple fetch through the one _pull)
+                    # — and so do a counting family's counts, onto the span
                     if self._tick_tele:
                         nxt, trow, self._cache, self._dstate = out
-                        toks, tele_row = self._pull((nxt, trow), stall_s)
+                        fetch = (nxt, trow)
                     else:
                         nxt, self._cache, self._dstate = out
-                        toks = self._pull(nxt, stall_s)
-                        tele_row = None
+                        fetch = (nxt,)
+                    if self.family.counts:
+                        fetch += (self._cache["stats"],)
+                    got = self._pull(fetch if len(fetch) > 1 else nxt,
+                                     stall_s)
+                    got = got if len(fetch) > 1 else (got,)
+                    toks = got[0]
+                    tele_row = got[1] if self._tick_tele else None
+                    if self.family.counts:
+                        dev.set(**self.family.counts(self.cfg, got[-1]))
                 tick_ms = dev.dur_s * 1e3
                 stall_s = 0.0
                 break
@@ -2257,7 +2379,12 @@ class ServingEngine:
                 sampling=req.temperature > 0.0)
             # first generated token — the admission's one host pull,
             # under the same watchdog as the tick's
-            tok = int(self._pull(first))
+            if self.family.counts:
+                first, stats = self._pull((first, self._cache["stats"]))
+                pf.set(**self.family.counts(self.cfg, stats))
+            else:
+                first = self._pull(first)
+            tok = int(first)
         if req.trace is not None:
             req.trace.end(sp_pf, final=True)
         if self._tick_log is not None:
@@ -2703,6 +2830,7 @@ class ServingEngine:
         scheduler's context — the same contract as submit/cancel).
         Raises ServingFaultError under the injected migrate_raise
         fault so drills exercise the fallback-to-replay path."""
+        self._refuse("migration")
         slot = req.slot
         if (req.done or slot is None or req._pf_next is not None
                 or not self._active[slot]):
@@ -2758,6 +2886,7 @@ class ServingEngine:
         eos/length checks continue where the source left off), or None
         when this engine cannot take it (no free slot / pages / shape
         limits) — the caller falls back to requeue-replay."""
+        self._refuse("migration")
         prompt = np.asarray(snap["prompt"], np.int32).reshape(-1)
         t0 = prompt.shape[0]
         max_new = int(snap["max_new_tokens"])
@@ -2862,6 +2991,7 @@ class ServingEngine:
         left. requests_completed still advances so submitted-completed
         stays a true in-flight gauge. Returns False when the request
         already resolved."""
+        self._refuse("migration")
         if req.done:
             return False
         if req.slot is not None:

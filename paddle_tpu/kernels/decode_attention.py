@@ -62,7 +62,8 @@ import jax.numpy as jnp
 __all__ = ["write_kv", "cached_attention", "decode_attn_impl",
            "gather_pages", "write_kv_paged", "layer_view",
            "attn_math_impl", "cache_pspecs", "attended_tokens",
-           "kv_view_extent"]
+           "kv_view_extent", "ring_positions", "ring_rows",
+           "blocked_attention"]
 
 
 def cache_pspecs(paged: bool, tp_axis: str = "tp"):
@@ -153,7 +154,7 @@ def write_kv_paged(pages, table, k, pos, layer=None):
                                         off.reshape(-1))].set(upd)
 
 
-def write_kv(kc, k, pos, layer=None):
+def write_kv(kc, k, pos, layer=None, ring: bool = False):
     """Write the step's k (or v) [B, T, KV, hd] into the cache
     [B, S, KV, hd] at position(s) `pos` — scalar (one
     dynamic_update_slice) or [B] per-row (each slot writes at its own
@@ -169,9 +170,25 @@ def write_kv(kc, k, pos, layer=None):
     [L, B, S, KV, hd] and the same rows land at [layer, ...] — the
     cached forwards' form. Either way only the step's rows are
     written, so on a donated buffer (or one carried through the layer
-    scan) XLA updates in place and the rest of the pool never moves."""
+    scan) XLA updates in place and the rest of the pool never moves.
+
+    `ring` is the window layers' form: the cache axis S is a ring and
+    position p lands on row p mod S, so a slot keeps its last S
+    positions and nothing more (`ring_positions` says which position a
+    row holds). One call writes at most S positions: a longer run
+    would land two positions on one row."""
     k = k.astype(kc.dtype)
     at = _at_layer(layer)
+    if ring:
+        B, T = k.shape[:2]
+        S = kc.shape[-3]
+        if T > S:
+            raise ValueError(f"a ring of {S} rows cannot take {T} "
+                             "positions in one write")
+        rows = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None],
+                                (B, T))
+        return kc.at[at + (rows, _query_positions(pos, B, T) % S)].set(
+            k, mode="promise_in_bounds")
     if jnp.ndim(pos) == 0:
         return jax.lax.dynamic_update_slice(
             kc, k[(None,) * len(at)], at + (0, pos, 0, 0))
@@ -235,7 +252,28 @@ def _query_positions(pos, B, T):
     return pos[:, None] + offs
 
 
-def cached_attention(q, kc, vc, pos, impl: str | None = None):
+def ring_positions(qpos, ring: int):
+    """The position each ring row holds as a query at `qpos` [...] sees
+    it -> [..., ring]: the largest p <= qpos with p mod ring == row.
+    Negative where the slot has not reached the row yet (what lies
+    there is a previous occupant's, and the mask drops it)."""
+    rows = jnp.arange(ring, dtype=jnp.int32)
+    return qpos[..., None] - (qpos[..., None] - rows) % ring
+
+
+def ring_rows(true_len, ring: int):
+    """After a prompt of `true_len` positions: the position whose K/V
+    ring row r must hold -> [ring] (the newest p < true_len with
+    p mod ring == r; where there is none yet, r itself, which the mask
+    drops until decode writes it). The engine's slot write gathers
+    these out of a prefill's position-ordered cache."""
+    return jnp.maximum(ring_positions(jnp.asarray(true_len - 1, jnp.int32),
+                                      ring),
+                       jnp.arange(ring, dtype=jnp.int32))
+
+
+def cached_attention(q, kc, vc, pos, impl: str | None = None,
+                     window: int | None = None):
     """Masked attention of q [B, T, H, hd] against the cache kc/vc
     [B, S, KV, hd]; query t of row b sits at absolute position
     `pos[b] + t` (pos scalar or [B]) and sees cache slots <= that
@@ -244,10 +282,21 @@ def cached_attention(q, kc, vc, pos, impl: str | None = None):
     Slots above the row's own position are masked to -inf before the
     softmax, so stale cache contents (a freed slot's previous request,
     bucket-pad garbage beyond the true prompt length) contribute an
-    exact 0.0 — the serving engine's correctness rests on this."""
+    exact 0.0 — the serving engine's correctness rests on this.
+
+    With `window` the cache axis is a ring (`write_kv(ring=True)`): row
+    r holds position `ring_positions(qpos)[r]`, and the query sees it
+    iff that position is one the slot has written (>= 0) and lies in
+    (qpos - window, qpos]. The operands then stay in the cache dtype
+    with float32 accumulation and a float32 softmax (impl 'native'): a
+    float32 copy of a 16k-position layer would not fit beside it."""
     B, T, H, hd = q.shape
     S, KV = kc.shape[1], kc.shape[2]
     G = H // KV
+    if impl == "native":
+        return _native_attention(q, kc, vc, pos, window)
+    if window is not None:
+        raise ValueError("a window mask needs impl='native'")
     impl = attn_math_impl(impl)
     if impl not in ("dense", "mixed"):
         raise ValueError(
@@ -267,3 +316,90 @@ def cached_attention(q, kc, vc, pos, impl: str | None = None):
     ctx = jnp.einsum("bkgts,bskd->btkgd", p.astype(dot_dt)
                      if impl == "mixed" else p, vc.astype(dot_dt))
     return ctx.reshape(B, T, H, hd).astype(jnp.float32)
+
+
+def _native_attention(q, kc, vc, pos, window):
+    """cached_attention's impl 'native': operands in the cache dtype,
+    float32 accumulation and softmax; `window` None is the plain
+    position mask over a position-ordered cache, else the ring mask."""
+    B, T, H, hd = q.shape
+    S, KV = kc.shape[1], kc.shape[2]
+    qg = q.astype(kc.dtype).reshape(B, T, KV, H // KV, hd)
+    s = jnp.einsum("btkgd,bskd->bkgts", qg, kc,
+                   preferred_element_type=jnp.float32) / math.sqrt(hd)
+    qpos = _query_positions(pos, B, T)                             # B,T
+    if window is None:
+        mask = jnp.arange(S, dtype=jnp.int32)[None, :] <= qpos[..., None]
+    else:
+        held = ring_positions(qpos, S)                             # B,T,S
+        mask = (held >= 0) & (qpos[..., None] - held < window)
+    s = jnp.where(mask[:, None, None, :, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    ctx = jnp.einsum("bkgts,bskd->btkgd", p.astype(vc.dtype), vc,
+                     preferred_element_type=jnp.float32)
+    return ctx.reshape(B, T, H, hd)
+
+
+_MASKED = -1e30     # finite: a block a row sees nothing of leaves no nan
+
+
+def blocked_attention(q, k, v, window: int | None = None,
+                      block: int = 512, q_offset=0):
+    """Causal attention of a prompt's queries against the prompt's keys,
+    in blocks: k/v [B, T, KV, hd] at positions 0..T-1, q [B, Tq, H, hd]
+    at positions q_offset.. (a traced multiple of the block; the whole
+    prompt by default) -> ctx [B, Tq, H, hd] in q's dtype. Query i sees
+    key j iff j <= i and, with `window`, i - j < window. A block of query rows walks the key
+    blocks it can see with a running softmax (float32 max, sum and
+    accumulator; operands in their own dtype), so the scores of a 16k
+    prompt never exist at once, and key blocks wholly ahead of the
+    rows or wholly outside the window are never touched."""
+    B, Tq, H, hd = q.shape
+    T, KV = k.shape[1], k.shape[2]
+    G = H // KV
+    bs = min(block, Tq)
+    if Tq % bs or T % bs:
+        raise ValueError(f"{Tq} queries over {T} keys are no whole "
+                         f"blocks of {bs}")
+    nb = Tq // bs
+    first_block = q_offset // bs
+    qg = q.astype(k.dtype).reshape(B, nb, bs, KV, G, hd)
+    offs = jnp.arange(bs, dtype=jnp.int32)
+    scale = 1.0 / math.sqrt(hd)
+
+    def rows_of(i):
+        qi = jax.lax.dynamic_index_in_dim(qg, i, 1, keepdims=False)
+        i = i + first_block
+        qpos = i * bs + offs
+
+        def keys_of(j, carry):
+            m, l, acc = carry
+            kj = jax.lax.dynamic_slice_in_dim(k, j * bs, bs, axis=1)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * bs, bs, axis=1)
+            s = jnp.einsum("bqkgd,bskd->bkgqs", qi, kj,
+                           preferred_element_type=jnp.float32) * scale
+            kpos = j * bs + offs
+            mask = kpos[None, :] <= qpos[:, None]
+            if window is not None:
+                mask &= qpos[:, None] - kpos[None, :] < window
+            s = jnp.where(mask, s, _MASKED)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+            shrink = jnp.exp(m - m_new)
+            l = l * shrink + p.sum(axis=-1)
+            acc = acc * shrink[..., None] + jnp.einsum(
+                "bkgqs,bskd->bkgqd", p.astype(vj.dtype), vj,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        first = 0 if window is None else \
+            jnp.maximum(i * bs - (window - 1), 0) // bs
+        init = (jnp.full((B, KV, G, bs), _MASKED, jnp.float32),
+                jnp.zeros((B, KV, G, bs), jnp.float32),
+                jnp.zeros((B, KV, G, bs, hd), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(first, i + 1, keys_of, init)
+        ctx = (acc / l[..., None]).astype(q.dtype)    # B,KV,G,bs,hd
+        return jnp.transpose(ctx, (0, 3, 1, 2, 4))    # B,bs,KV,G,hd
+
+    out = jax.lax.map(rows_of, jnp.arange(nb, dtype=jnp.int32))
+    return jnp.moveaxis(out, 0, 1).reshape(B, Tq, H, hd)

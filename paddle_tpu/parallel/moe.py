@@ -13,6 +13,15 @@ ICI all-to-all that the reference performs with NCCL global_scatter. No
 host-driven routing, fully jit/vjp compatible, static shapes (dropped
 tokens beyond capacity contribute zero, exactly like the reference's
 capacity overflow).
+
+Beside it, for serving: a DROPLESS layer (`sigmoid_topk`,
+`dropless_experts`) that is told which experts of the published count
+it holds, routes over all of them, and computes its own experts' part
+for exactly the tokens that chose them — sort by expert, one grouped
+matmul per projection (`jax.lax.ragged_dot`). No capacity, so a token's
+output never depends on which other tokens share its step. What the
+absent experts would add is left out: there is no exchange here and no
+code that stands in for one.
 """
 from __future__ import annotations
 
@@ -84,6 +93,96 @@ def topk_gating(probs, k: int, capacity: int, normalize: bool = None):
     ce = jnp.mean(top1, axis=0)                                # token frac
     aux_loss = E * jnp.sum(me * ce)
     return dispatch, combine, aux_loss
+
+
+def sigmoid_topk(router_logits, k: int, normalize: bool = True):
+    """Sigmoid selection over ALL published experts: router_logits
+    [T, E] -> (choice [T, k] int32, weight [T, k] float32). The scores
+    are sigmoids, the k largest are chosen, and with `normalize` the
+    chosen scores are divided by their sum. Float32 throughout: a
+    near-tie for the k-th place must fall the way the reference's does."""
+    scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    weight, choice = jax.lax.top_k(scores, k)
+    if normalize:
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    return choice.astype(jnp.int32), weight
+
+
+# rows up to which every held expert computes every row (`dense`): the
+# step is then bound by reading the experts' weights once, which the
+# dense form does and nothing else; the sorted form's sort, gathers and
+# grouped matmuls cost more than they save (PERF.md section 6, PR 31)
+DENSE_FORM_ROWS = 64
+
+
+def dropless_experts(x, choice, weight, gate_w, up_w, down_w, *,
+                     first: int = 0, live=None, layer=None):
+    """The held experts' part of a gated-SiLU expert layer, no drops.
+
+    x [T, D]; choice / weight [T, k] from `sigmoid_topk` (indices over
+    the published experts); gate_w / up_w [Eh, D, F], down_w [Eh, F, D]:
+    the Eh experts `first .. first + Eh - 1` that live here. Every
+    (token, choice) pair that names a held expert is computed —
+    `sum_k weight_k * E_k(x)` over the held choices — and the others
+    add nothing. `live` [T] bool marks the rows that are real tokens
+    (bucket padding and idle slots are not): they are computed like any
+    other, only the load leaves them out. With `layer` (an index) the
+    weights are a stack [L, Eh, ...] and layer `layer`'s experts are
+    meant: the grouped matmuls then run over the whole stack with every
+    other layer's groups empty, so that no layer's 400 MB is sliced out
+    into a buffer of its own first.
+
+    -> (y [T, D] float32, load [Eh] int32: live pairs on each held
+    expert).
+
+    Two forms, chosen by the static row count. Up to DENSE_FORM_ROWS
+    every held expert computes every row and the weights select (0 for
+    an expert a row did not choose). Past it the pairs are sorted by
+    held expert (absent experts' pairs last, in a group no matmul
+    reads), the rows gathered once, three grouped matmuls
+    (`jax.lax.ragged_dot`) run over the sorted rows, and the results
+    are gathered back and summed per token; a row budget of T x k holds
+    every case."""
+    T, k = choice.shape
+    held = gate_w.shape[-3]
+    local = choice.reshape(-1) - first
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    if live is None:
+        live = jnp.ones((T,), bool)
+    load = jnp.bincount(jnp.where(jnp.repeat(live, k), local, held),
+                        length=held + 1)[:held].astype(jnp.int32)
+    if T <= DENSE_FORM_ROWS:
+        if layer is not None:
+            gate_w, up_w, down_w = gate_w[layer], up_w[layer], down_w[layer]
+        h = jax.nn.silu(jnp.einsum("td,edf->etf", x, gate_w)) \
+            * jnp.einsum("td,edf->etf", x, up_w)
+        out = jnp.einsum("etf,efd->etd", h, down_w,
+                         preferred_element_type=jnp.float32)
+        mix = jnp.sum(jnp.where(
+            local.reshape(T, k, 1) == jnp.arange(held), weight[..., None],
+            0.0), axis=1)                                   # [T, Eh]
+        return jnp.einsum("etd,te->td", out, mix,
+                          precision=jax.lax.Precision.HIGHEST), load
+    order = jnp.argsort(local, stable=True)                 # [T*k]
+    sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+    if layer is not None:
+        stack = gate_w.shape[0]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((stack * held,), jnp.int32), sizes, (layer * held,))
+        gate_w, up_w, down_w = (w.reshape((stack * held,) + w.shape[2:])
+                                for w in (gate_w, up_w, down_w))
+    rows = jnp.take(x, order // k, axis=0)                  # [T*k, D]
+    h = jax.nn.silu(jax.lax.ragged_dot(rows, gate_w, sizes)) \
+        * jax.lax.ragged_dot(rows, up_w, sizes)
+    out = jax.lax.ragged_dot(h, down_w, sizes,
+                             preferred_element_type=jnp.float32)
+    back = jnp.argsort(order)                               # pair -> row
+    # rows past the held groups are whatever the grouped matmul left
+    # there: select, do not multiply, so that nothing of them survives
+    pair = jnp.where((local < held)[:, None],
+                     jnp.take(out, back, axis=0)
+                     * weight.reshape(-1)[:, None], 0.0)
+    return pair.reshape(T, k, -1).sum(axis=1), load
 
 
 @dataclasses.dataclass
