@@ -107,6 +107,10 @@ def _freeze(x):
         return ("npdtype", x.name)
     if isinstance(x, np.ndarray):
         return ("nparr", x.shape, x.dtype.name, x.tobytes())
+    if isinstance(x, (int, float, complex)):
+        # 1 == 1.0 == True and they hash alike: without the type, `x + 1`
+        # on an int tensor would reuse the closure that baked in `1.0`
+        return (type(x), x)
     return x
 
 

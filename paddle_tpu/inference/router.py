@@ -419,7 +419,8 @@ class EngineRouter:
                     {"idx": r.idx, "alive": r.alive,
                      "draining": r.draining, "role": r.role,
                      "load": r.load() if r.alive else 0,
-                     "dispatched": r.m_disp.value}
+                     "dispatched": r.m_disp.value,
+                     **r.eng.weights_stats()}
                     for r in self.replicas]}
         if self._admission is not None:
             out["admission"] = self._admission.stats()
@@ -1319,6 +1320,16 @@ def create_router(params, cfg, replicas: int = 2, family: str = "gpt",
         if asked and option in fam.refuses:
             raise UnsupportedOptionError(fam.name, option)
     tele = engine_kw.pop("telemetry_jsonl", None)
+    # round the weights to the compute dtype ONCE, before they are
+    # placed, so that replicas on one device share one rounded tree
+    # (quantization/serving.py round_serving_params; each engine's own
+    # application then finds nothing to do). Not under int8: there the
+    # engines quantize from the leaves as handed, and round the rest.
+    given = params
+    from ..kernels.quant_matmul import resolve_quant
+    if not resolve_quant(engine_kw.get("quant", "auto")):
+        from ..quantization.serving import round_serving_params
+        params = round_serving_params(params, fam.name, cfg)
     placed = [params] * replicas
     if meshes is None:
         meshes, placed = _place_replicas(
@@ -1327,7 +1338,7 @@ def create_router(params, cfg, replicas: int = 2, family: str = "gpt",
                              mesh=None if meshes is None else meshes[i],
                              telemetry_jsonl=(f"{tele}.r{i}" if tele
                                               else None),
-                             **engine_kw)
+                             _given_params=given, **engine_kw)
                for i in range(replicas)]
     return EngineRouter(engines, max_queue=max_queue,
                         queue_policy=queue_policy, concurrent=concurrent,

@@ -193,6 +193,15 @@ SNIPPETS.md [3]'s PartitionSpec layout):
   when tp pays (the decode tick is weight-bandwidth bound; a model
   bigger than one chip forces tp > 1).
 
+Held weights: the engine keeps its params at the COMPUTE dtype. What
+the caller hands in (float32 leaves, as a checkpoint stores them) is
+rounded once at build by quantization/serving.round_serving_params —
+the leaves the family's cached forward would otherwise `astype` inside
+every tick and every prefill; norm leaves, which it reads in float32,
+stay. No knob: the effect follows from the leaves' dtypes against
+cfg.dtype, the logits are bit-identical, and the gauges
+serving.weights_bytes / serving.weights_given_bytes say what it did.
+
 Quantized serving (quant="int8" / env PADDLE_TPU_QUANT / the
 "quant_matmul" registry kernel — the weight-HBM layer, cf. the
 reference PTQ driver's channel_wise_abs_max weight path; OFF by
@@ -827,7 +836,8 @@ class ServingEngine:
                  quant: str = "auto", telemetry: str = "auto",
                  telemetry_jsonl: Optional[str] = None,
                  telemetry_every: int = 32, tracing: bool = False,
-                 multi_tick: int = 0, host_kv_bytes: int = 0):
+                 multi_tick: int = 0, host_kv_bytes: int = 0,
+                 _given_params: Optional[dict] = None):
         self.family = (family_for(family) if isinstance(family, str)
                        else family)
         self.cfg = cfg
@@ -974,12 +984,27 @@ class ServingEngine:
             self._refuse("quant")
         self._serving_specs = self.family.serving_specs
         self._quant_info = None
+        from ..quantization.serving import (quantize_serving_params,
+                                            round_serving_params,
+                                            tree_bytes)
+        given = params if _given_params is None else _given_params
         if self.quant:
-            from ..quantization.serving import quantize_serving_params
             params, qspecs, self._quant_info = quantize_serving_params(
                 params, self.family.name, self._serving_specs)
             if self._serving_specs is not None:
                 self._serving_specs = qspecs
+        # what stays floating point is rounded to the compute dtype
+        # here, once, and not by the forward's `astype` inside every
+        # tick and prefill: the second leaf rewrite, by the leaves'
+        # dtypes and the family's table alone (a no-op on a tree the
+        # router has rounded already). Only the rounded tree is kept.
+        params = round_serving_params(params, self.family.name, cfg)
+        self._weights_info = {
+            "weights_bytes": tree_bytes(params),
+            "weights_given_bytes": tree_bytes(given),
+            "weights_rounded_leaves": sum(
+                1 for n, v in params.items()
+                if n in given and v.dtype != given[n].dtype)}
         self._params = (self._shard_params(params) if mesh is not None
                         else params)
         self._cache_pin = None        # leaf -> NamedSharding under mesh=
@@ -1170,6 +1195,12 @@ class ServingEngine:
         self._m_spec_rate = monitor.gauge("serving.spec_accept_rate")
         self._spec_prop_total = 0
         self._spec_acc_total = 0
+        # what the engine holds after its two leaf rewrites, and what
+        # it was handed (gauges: the rewrites engage once, at build)
+        monitor.gauge("serving.weights_bytes").set(
+            self._weights_info["weights_bytes"])
+        monitor.gauge("serving.weights_given_bytes").set(
+            self._weights_info["weights_given_bytes"])
         # weight-only quant surface (stays 0/unset with quant off):
         # the bytes gauges report THIS engine's weight tree before and
         # after the int8 rewrite (the HBM halving observable); the
@@ -1422,6 +1453,14 @@ class ServingEngine:
             return {"quant": "off"}
         return {"quant": "int8", **self._quant_info}
 
+    def weights_stats(self) -> dict:
+        """Bytes of the params tree this engine holds (`weights_bytes`)
+        against the tree it was handed (`weights_given_bytes`), and how
+        many leaves the build rounded to the compute dtype
+        (`weights_rounded_leaves`; quantization/serving.py
+        round_serving_params)."""
+        return dict(self._weights_info)
+
     def _publish_pool_gauges(self) -> None:
         if not self.paged:
             return
@@ -1483,19 +1522,19 @@ class ServingEngine:
     def memory_ledger(self) -> dict:
         """This engine's `cost_model.serving_memory_ledger` — per-chip
         HBM attribution (weights / quantized pairs / kv pool / decode
-        scratch) from the LIVE configuration. The analytical half that
+        scratch) from the LIVE configuration, with `weights_stats()`
+        under "held". The analytical half that
         `profiler.mem_audit.audit_serving_memory` diffs against the
         compiled decode tick, and the first page of an oom_forensics
         dump."""
         if self.family.refuses:
             # no cost-model dims for this family: what the device holds
-            weights = sum(int(v.nbytes) for v in
-                          jax.tree_util.tree_leaves(self._params))
+            weights = self._weights_info["weights_bytes"]
             kv = _pool_bytes(self._cache)
             return {"weights": weights, "kv_pool_device": kv,
-                    "total": weights + kv}
+                    "total": weights + kv, "held": self.weights_stats()}
         from ..cost_model import jnp_dtype_bytes, serving_memory_ledger
-        return serving_memory_ledger(
+        ledger = serving_memory_ledger(
             self.cfg, family=self.family.name,
             layout="paged" if self.paged else "dense",
             quant="int8" if self._quant_info else "off",
@@ -1507,6 +1546,9 @@ class ServingEngine:
             tp=self.tp,
             host_kv_bytes=(int(self._host_tier.bytes)
                            if self._host_tier is not None else 0))
+        # beside the analytical bytes, what the tree really holds
+        ledger["held"] = self.weights_stats()
+        return ledger
 
     def compiled_memory_stats(self, sampling: bool = False) -> dict:
         """XLA's compiled memory accounting for THIS engine's decode

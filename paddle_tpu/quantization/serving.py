@@ -28,18 +28,27 @@ CHANNEL axis (column-parallel weights carry tp on the output dim, so
 their scales are tp-sharded; row-parallel weights shard the reduction
 dim, so their scales replicate). The head copy flips the vocab-parallel
 embedding spec onto its transposed layout.
+
+The sibling rewrite, `round_serving_params`, is always on and needs no
+knob: whatever stays floating point is rounded to the compute dtype
+once, at build, where the cached forward would round it on every call
+(COMPUTE_LEAVES). Shapes and specs do not change.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .int8 import _Q, quantize_weight, quantize_weight_stacked
 
-__all__ = ["QUANT_LEAVES", "HEAD_LEAF", "quantize_serving_params"]
+__all__ = ["QUANT_LEAVES", "HEAD_LEAF", "COMPUTE_LEAVES",
+           "quantize_serving_params", "round_serving_params",
+           "tree_bytes"]
 
 # family -> the stacked [L, ..., N] matmul leaves that quantize (the
 # attention qkv/proj and MLP in/out weights; biases, norms, embeddings
@@ -55,6 +64,24 @@ QUANT_LEAVES: Dict[str, tuple] = {
 # gather stays fp (and so the head runs the same [K, N] kernel layout
 # as the block matmuls)
 HEAD_LEAF = "wte"
+
+# family -> the leaves its cached forward rounds WHOLE to the compute
+# dtype (`.astype(x.dtype)`) before the first use: the matmul stacks
+# through kernels/quant_matmul.leaf_matmul, their biases, and `wte`, the
+# tied head (its embedding rows are gathered first and rounded after,
+# which commutes). Norm scales and biases are not here: `_ln` /
+# `_rmsnorm` compute on them in float32. Nor is gpt's `wpe`: the forward
+# converts only the rows it slices out, and on the TPU the prefill's
+# embed fusion adds those rows UNROUNDED (XLA folds the slice's
+# f32->bf16->f32 convert pair away), so a table rounded ahead of time
+# serves other logits than the table as handed (GPT-1.3B on a v5e: 87%
+# of a 256-token prefill's logits move, by up to 0.07). A family with no
+# entry (cohere2_moe: bf16 as stored) is left alone.
+COMPUTE_LEAVES: Dict[str, tuple] = {
+    "gpt": QUANT_LEAVES["gpt"] + ("qkv_b", "attn_out_b", "mlp_up_b",
+                                  "mlp_down_b", "wte"),
+    "llama": QUANT_LEAVES["llama"] + ("wte",),
+}
 
 
 def _entry(spec, i: int):
@@ -88,7 +115,7 @@ def quantize_serving_params(params: dict, family: str,
             f"(QUANT_LEAVES covers {sorted(QUANT_LEAVES)}); a custom "
             "family must register its stacked matmul leaves there "
             "before serving with quant=")
-    fp_bytes = sum(np.asarray(v).nbytes for v in params.values())
+    fp_bytes = tree_bytes(params)
     out = dict(params)
     qspecs = dict(specs or {})
     done = []
@@ -123,8 +150,58 @@ def quantize_serving_params(params: dict, family: str,
         qspecs["head_q"] = P(_entry(espec, 1), out_axis)
         qspecs["head_scale"] = P(out_axis)
         head = 1
-    quant_bytes = sum(np.asarray(v).nbytes for v in out.values())
+    quant_bytes = tree_bytes(out)
     info = {"fp_bytes": int(fp_bytes), "quant_bytes": int(quant_bytes),
             "per_layer": len(done), "head": head,
             "quant_leaf_names": tuple(done)}
     return out, qspecs, info
+
+
+def tree_bytes(params: dict) -> int:
+    """Bytes of a params tree, from shapes and dtypes (no transfer)."""
+    return sum(int(v.nbytes) for v in params.values())
+
+
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _round_on_device(leaves: dict, dtype):
+    return {name: v.astype(dtype) for name, v in leaves.items()}
+
+
+def round_serving_params(params: dict, family: str, cfg) -> dict:
+    """Round a serving params tree to the compute dtype ONCE, at build:
+    every COMPUTE_LEAVES[family] leaf whose dtype is wider than
+    `cfg.dtype` becomes `leaf.astype(cfg.dtype)` — the cast the cached
+    forward would otherwise make inside every decode tick and every
+    prefill (on a float32 GPT-1.3B tree: 5.25 GB read and 2.42 GB
+    written a tick before any work). Rounding is element-wise, so it
+    commutes with the forward's row gathers and layer slices and every
+    program computes on the operands it computed on before; the
+    forward's own `astype` calls become no-ops.
+
+    What is done follows from the tree, not from a knob: a leaf already
+    at (or under) the compute width, an int8 pair, a norm leaf and a
+    family with no table pass through as the same objects, and a tree
+    with nothing to round comes back as the SAME dict, so a second
+    application is free. Device leaves are rounded in one jitted call
+    where they live; host (numpy) leaves are rounded on the host, so a
+    tree on its way to `_shard_params` is never staged on one device.
+    After `quantize_serving_params`, not before: the int8 pairs are
+    made from the leaves as handed."""
+    names = COMPUTE_LEAVES.get(family)
+    dtype = getattr(cfg, "dtype", None)
+    if names is None or dtype is None:
+        return params
+    dtype = jnp.dtype(dtype)
+    wide = {n: params[n] for n in names
+            if n in params
+            and jnp.issubdtype(params[n].dtype, jnp.floating)
+            and jnp.dtype(params[n].dtype).itemsize > dtype.itemsize}
+    if not wide:
+        return params
+    on_device = {n: v for n, v in wide.items() if isinstance(v, jax.Array)}
+    out = dict(params)
+    out.update({n: np.asarray(v).astype(dtype) for n, v in wide.items()
+                if n not in on_device})
+    if on_device:
+        out.update(_round_on_device(on_device, dtype))
+    return out

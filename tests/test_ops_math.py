@@ -84,6 +84,19 @@ class TestBinary:
         np.testing.assert_allclose((1.0 / x).numpy(), 1.0 / A, rtol=1e-5)
 
 
+def test_equal_literals_of_different_type_do_not_share_a_closure():
+    """1 == 1.0 == True and they hash alike; the dispatch cache keys a
+    baked-in literal by its type too, or `x + 1` on an int tensor would
+    reuse the closure compiled for `x + 1.0` and come back float."""
+    i = paddle.to_tensor(np.array([3], np.int32))
+    assert (paddle.to_tensor(np.array([3.0], np.float32)) + 1.0).dtype \
+        == paddle.float32
+    out = i + 1
+    assert out.dtype == paddle.int32 and int(out.numpy()[0]) == 4
+    traced = paddle.jit.to_static(lambda t: t + 1)(i)
+    assert traced.dtype == paddle.int32 and int(traced.numpy()[0]) == 4
+
+
 class TestUnary:
     @pytest.mark.parametrize("name,npfn", [
         ("exp", np.exp), ("log", np.log), ("sqrt", np.sqrt),

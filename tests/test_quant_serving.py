@@ -168,9 +168,11 @@ def test_leaf_matmul_routes_by_tree():
     w = rng.randn(8, 12).astype(np.float32)
     x = jnp.asarray(rng.randn(2, 3, 8).astype(np.float32))
     y_fp = qm.leaf_matmul(x, {"w": jnp.asarray(w)}, "w")
+    # a float32 reduction over 8 terms: summation order alone moves a
+    # small sum by an ulp (1.5e-6 relative)
     np.testing.assert_allclose(
         np.asarray(y_fp), np.einsum("btk,kn->btn", np.asarray(x), w),
-        rtol=1e-6)
+        rtol=1e-5)
     w_q, scale = quantize_weight(w, channel_axis=1)
     y_q = qm.leaf_matmul(
         x, {"w_q": jnp.asarray(w_q),
