@@ -305,8 +305,8 @@ def kernel_cases(sizes: Sizes):
         cases.append((name, ce, ce_oracle, ce_args, 2e-2))
 
     # one leaf through the kernel and through models.gpt.apply_adamw, the
-    # jax form the kernel names as its oracle (it is that form unless a
-    # 'fused_update' registry row routes it to the kernel, and none does);
+    # jax form the kernel names as its oracle (it is that form while
+    # pallas_update.FUSED_UPDATE is off, and it is);
     # `v` arrives as a normal draw and a second moment is a square
     def adamw_with(apply):
         def run(p, g, m, v):

@@ -296,12 +296,10 @@ def train_flops_per_token(n_params: int, num_layers: int,
                           hidden_size: int, seq: int) -> float:
     """ONE home for the train-step MFU accounting: 6N matmul FLOPs per
     token (fwd+bwd) plus the attention score/context matmul term.
-    bench.py, the plan3d rung (tools/bench_plan3d.py), the sharded-step
-    ablation rows (tools/ablate_step.py), the sweep adoption's
-    plausibility gate (kernels/registry.py) and the telemetry
-    `train.mfu` gauge all price against THIS formula, so their
-    MFU/evidence rows stay comparable — adjust it here and every
-    consumer moves together."""
+    bench.py, the plan3d rung (tools/bench_plan3d.py) and the telemetry
+    `train.mfu` gauge all price against THIS formula, so their MFU rows
+    stay comparable — adjust it here and every consumer moves
+    together."""
     return 6.0 * n_params + 12.0 * num_layers * hidden_size * seq
 
 

@@ -18,7 +18,7 @@ _current_device = None
 
 def is_tpu() -> bool:
     """True when jax's default backend is a TPU — the one test the kernel
-    gates, the registry's backend class and the compile cache branch on."""
+    gates, the per-platform defaults and the compile cache branch on."""
     return jax.default_backend() == "tpu"
 
 

@@ -27,47 +27,16 @@ Accounting: `serving_memory_ledger` prices the tier as the
 `kv_pool_host` component (host RAM, NOT device HBM — excluded from the
 device total); gauges `serving.kv_host_bytes` /
 `serving.host_spills` / `serving.host_swapins` ride the telemetry
-flush cadence. Kill switch: `PADDLE_TPU_HOST_KV` off values zero the
-cap even when the engine was built with host_kv_bytes > 0.
+flush cadence. The engine's `host_kv_bytes=` argument is the byte cap
+(0 = no tier).
 """
 from __future__ import annotations
 
 import collections
-import os
 
 import numpy as np
 
-__all__ = ["ENV_HOST_KV", "HostKVTier", "resolve_host_kv"]
-
-ENV_HOST_KV = "PADDLE_TPU_HOST_KV"
-
-_OFF_VALUES = frozenset({"0", "off", "false", "no"})
-
-
-def resolve_host_kv(knob: int = 0) -> int:
-    """Resolve the engine's host_kv_bytes knob to an effective byte
-    cap (0 = tier off). The env var kill-switches an explicit cap and
-    can set one for knob-0 engines (an int byte count); unrecognized
-    values fail safe to OFF with a stderr warning."""
-    cap = int(knob or 0)
-    if cap < 0:
-        raise ValueError(f"host_kv_bytes must be >= 0; got {knob}")
-    env = os.environ.get(ENV_HOST_KV, "").strip().lower()
-    if not env:
-        return cap
-    if env in _OFF_VALUES:
-        return 0
-    try:
-        n = int(env)
-    except ValueError:
-        n = -1
-    if n >= 0:
-        return n if cap == 0 else cap
-    import sys
-    print(f"[host_kv] {ENV_HOST_KV}={env!r} is not a byte count or one "
-          f"of {sorted(_OFF_VALUES)}; treating as 'off' (the kill "
-          "switch fails safe)", file=sys.stderr, flush=True)
-    return 0
+__all__ = ["HostKVTier"]
 
 
 class HostKVTier:

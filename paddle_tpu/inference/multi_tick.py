@@ -39,106 +39,28 @@ executables); per-slot PRNG streams fold (request id, gen index) per
 step exactly like the single-tick path, so sampled streams are
 bit-identical; donation and cache pinning are unchanged.
 
-Selection (the kernels/registry.py seam): kernel "multi_tick", impls
-"off" | "scan". `PADDLE_TPU_MULTI_TICK` is the env override AND the
-kill switch — an off value ("0"/"1"/"off"/"false"/"single") flattens
-every engine to single-tick even when built with multi_tick=K, an
-integer >= 2 sets K for knob='auto' engines, and unrecognized values
-fail safe to off with a stderr warning. Default: off (adoption only
-via env > registry — tools/bench_serving.py --multi-tick --adopt is
-the evidence-gated writer).
+Selection: the engine's `multi_tick=` argument (K; 0 / "auto" is 1, the
+single-tick shape).
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
 
 from .spec_decode import SPEC_PAD as MT_PAD   # same sentinel space
 
-__all__ = ["MT_PAD", "ENV_MULTI_TICK", "DEFAULT_MULTI_TICK_K",
-           "multi_tick_impl", "resolve_multi_tick", "multi_tick_scan",
+__all__ = ["MT_PAD", "resolve_multi_tick", "multi_tick_scan",
            "multi_tick_spec_scan"]
-
-ENV_MULTI_TICK = "PADDLE_TPU_MULTI_TICK"
-
-# the K an 'auto' engine gets when the registry (or an un-numbered env
-# on value) enables the scan: deep enough to amortize a ~100 ms
-# dispatch against ~ms ticks, shallow enough that early-exit waste
-# (dead slots riding out the scan) stays small at high occupancy
-DEFAULT_MULTI_TICK_K = 4
-
-_OFF_VALUES = frozenset({"0", "1", "off", "false", "no", "single"})
-_ON_VALUES = frozenset({"on", "true", "yes", "scan"})
-
-
-def _env_value():
-    """Read + classify PADDLE_TPU_MULTI_TICK: '' (unset), 'off',
-    'scan' (enable at the default K), or an int K >= 2. Unrecognized
-    values are OFF with a stderr warning — this env var is the kill
-    switch, and a typo must fail toward the single-tick shape."""
-    env = os.environ.get(ENV_MULTI_TICK, "").strip().lower()
-    if not env:
-        return ""
-    if env in _OFF_VALUES:
-        return "off"
-    if env in _ON_VALUES:
-        return "scan"
-    try:
-        k = int(env)
-    except ValueError:
-        k = 0
-    if k >= 2:
-        return k
-    import sys
-    print(f"[multi_tick] {ENV_MULTI_TICK}={env!r} is not an int >= 2 "
-          f"or one of {sorted(_ON_VALUES)} / {sorted(_OFF_VALUES)}; "
-          "treating as 'off' (the kill switch fails safe)",
-          file=sys.stderr, flush=True)
-    return "off"
-
-
-def multi_tick_impl():
-    """Selector: env PADDLE_TPU_MULTI_TICK > registry winner
-    ('multi_tick', current backend class) > 'off'. Returns 'off',
-    'scan', or an int K from a numbered env value."""
-    env = _env_value()
-    if env:
-        return env
-    from ..kernels import registry
-    win = registry.winner("multi_tick",
-                          backend=registry.backend_class(
-                              jax.default_backend()))
-    return win or "off"
 
 
 def resolve_multi_tick(knob=0) -> int:
-    """Engine-build resolution of the multi_tick knob to the effective
-    ticks-per-dispatch K (1 = the single-tick shape). knob 0/'auto'
-    consults env > registry; an explicit int K >= 1 wins except
-    against the env KILL SWITCH (an off value flattens even an
-    explicit K — the spec_decode.resolve_spec asymmetry,
-    docs/serving.md §Disaggregation)."""
-    if knob in (None, "auto"):
-        knob = 0
-    k = int(knob)
+    """The engine's `multi_tick=` argument as the ticks-per-dispatch K:
+    0 / None / 'auto' is 1 (the single-tick shape), an int K >= 1 is
+    itself, a negative one raises."""
+    k = 0 if knob in (None, "auto") else int(knob)
     if k < 0:
         raise ValueError(f"multi_tick must be >= 0 (0 = auto); got {knob}")
-    env = _env_value()
-    if env == "off":
-        return 1
-    if k >= 1:
-        return k
-    if isinstance(env, int):
-        return env
-    if env == "scan":
-        return DEFAULT_MULTI_TICK_K
-    from ..kernels import registry
-    win = registry.winner("multi_tick",
-                          backend=registry.backend_class(
-                              jax.default_backend()))
-    return DEFAULT_MULTI_TICK_K if win == "scan" else 1
+    return max(k, 1)
 
 
 # ---------------------------------------------------------- scan bodies

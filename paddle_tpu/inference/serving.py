@@ -83,9 +83,8 @@ requirement of Orca/vLLM-class serving stacks):
   Every serving fault dumps a flight-recorder black box
   (profiler/flight_recorder.py).
 
-Paged KV cache (kv_layout="paged", selectable via
-PADDLE_TPU_DECODE_ATTN_IMPL=paged / the kernel registry — the
-capacity layer, cf. vLLM's PagedAttention SOSP '23 and SGLang's
+Paged KV cache (kv_layout="paged"; "auto" is dense — the capacity
+layer, cf. vLLM's PagedAttention SOSP '23 and SGLang's
 RadixAttention):
 
 - **Block pool.** K/V live in fixed-size pages ({"k","v"} buffers of
@@ -124,9 +123,8 @@ RadixAttention):
   that could never fit the configured pool raises the typed
   `PoolExhaustedError` at submit.
 
-Speculative decoding (spec_decode="spec" / env PADDLE_TPU_SPEC_DECODE
-/ the "spec_decode" registry kernel — the single-stream latency layer,
-cf. Leviathan et al. 2023; OFF by default):
+Speculative decoding (spec_decode="spec" — the single-stream latency
+layer, cf. Leviathan et al. 2023; OFF by default):
 
 - **Self-draft propose + one-pass verify, one tick.** Each tick runs
   `gamma` truncated-depth draft steps (the first `draft_layers` layers
@@ -202,8 +200,7 @@ stay. No knob: the effect follows from the leaves' dtypes against
 cfg.dtype, the logits are bit-identical, and the gauges
 serving.weights_bytes / serving.weights_given_bytes say what it did.
 
-Quantized serving (quant="int8" / env PADDLE_TPU_QUANT / the
-"quant_matmul" registry kernel — the weight-HBM layer, cf. the
+Quantized serving (quant="int8" — the weight-HBM layer, cf. the
 reference PTQ driver's channel_wise_abs_max weight path; OFF by
 default):
 
@@ -224,8 +221,8 @@ default):
   so the tick invariants (one host pull, trace ceilings, donation)
   are untouched and dense/paged/spec-draft/tp compose for free. The
   fused dequant-matmul runs as 'xla' (portable, the CPU-tested real
-  path) or 'pallas' (hand-tiled, int8->f32 in registers), selected
-  via env > registry > 'xla'.
+  path) or 'pallas' (hand-tiled, int8->f32 in registers):
+  kernels/quant_matmul.QUANT_MATMUL_IMPL, 'xla'.
 - **Determinism tiers.** Weight-only dequant is deterministic: a
   quantized engine's streams are BIT-IDENTICAL across layouts and
   meshes (dense/paged, spec on/off, tp degrees), and the Pallas and
@@ -233,11 +230,6 @@ default):
   engine, streams carry a measured logit-error budget instead
   (BASELINE.md "Quantized serving"); greedy streams may diverge —
   that is the accuracy/HBM trade, recorded, not hidden.
-- **Kill switch + evidence gate.** PADDLE_TPU_QUANT off-values
-  disable quantization for new engines even when quant="int8"
-  (unrecognized values fail SAFE to off); adoption into the registry
-  goes through tools/bench_serving.py --quant --adopt, which refuses
-  unless weight bytes <= 0.55x fp AND tokens/s >= 0.95x fp.
 
 Observability: serving.* monitor counters/gauges (slot occupancy,
 queue depth, tokens emitted, prefills, decode ticks, plus
@@ -888,9 +880,6 @@ class ServingEngine:
             from jax.sharding import NamedSharding, PartitionSpec
             self._rep_sharding = NamedSharding(mesh, PartitionSpec())
         # ------------------------------------------- speculative decode
-        # knob 'auto' consults env > registry ('spec_decode') > off;
-        # the env's off values kill-switch even an explicit 'spec'
-        # (inference/spec_decode.resolve_spec)
         from .spec_decode import resolve_spec
         self.spec = resolve_spec(spec_decode)
         # whether drafts CAN run: set_spec_drafts (brownout) may flip
@@ -919,11 +908,9 @@ class ServingEngine:
                     "not accept layers= — the truncated-depth self-draft "
                     "needs it (see models/gpt.py gpt_forward_cached)")
         # --------------------------------------------- fused multi-tick
-        # knob 0/'auto' consults env > registry ('multi_tick') > off;
-        # PADDLE_TPU_MULTI_TICK's off values kill-switch even an
-        # explicit K (inference/multi_tick.resolve_multi_tick). K is
-        # BAKED into the decode executable (a lax.scan of length K), so
-        # the jit cache keys of engines with different K never collide.
+        # K is BAKED into the decode executable (a lax.scan of length
+        # K), so the jit cache keys of engines with different K never
+        # collide.
         from .multi_tick import resolve_multi_tick
         self.mt_k = resolve_multi_tick(multi_tick)
         if self.mt_k > 1:
@@ -933,14 +920,10 @@ class ServingEngine:
         self._tick_span = self.mt_k * ((self.spec_gamma + 1)
                                        if self.spec else 1)
         # ------------------------------------------------- cache layout
-        if kv_layout == "auto":
-            from ..kernels.decode_attention import decode_attn_impl
-            kv_layout = ("paged" if decode_attn_impl() == "paged"
-                         else "dense")
-        if kv_layout not in ("dense", "paged"):
+        if kv_layout not in ("auto", "dense", "paged"):
             raise ValueError(f"kv_layout {kv_layout!r} "
                              "(auto|dense|paged)")
-        self.paged = kv_layout == "paged"
+        self.paged = kv_layout == "paged"      # 'auto' is dense
         if self.paged:
             self._refuse("kv_layout='paged'")
         self.page_size = int(page_size)
@@ -969,12 +952,9 @@ class ServingEngine:
         self.max_top_k = int(max_top_k)
         self.bucket_lo = int(bucket_lo)
         # --------------------------------------- weight-only int8 quant
-        # knob 'auto' consults env > registry ('quant_matmul') > off;
-        # PADDLE_TPU_QUANT's off values kill-switch even an explicit
-        # 'int8' (kernels/quant_matmul.resolve_quant). Quantization is
-        # a LEAF REWRITE at build: the fp matmul weights become
-        # <name>_q/<name>_scale pairs (plus the transposed head copy),
-        # the cached forwards pick them up from the tree through
+        # Quantization is a LEAF REWRITE at build: the fp matmul weights
+        # become <name>_q/<name>_scale pairs (plus the transposed head
+        # copy), the cached forwards pick them up from the tree through
         # kernels/quant_matmul.leaf_matmul, and the jitted bodies /
         # tick invariants are untouched — same state tuple, same one
         # pull per tick, same trace ceilings.
@@ -1169,10 +1149,11 @@ class ServingEngine:
         # ------------------------------------------ host-tier KV offload
         # paged + prefix_sharing only: the pool's LRU eviction demotes
         # registered pages to host ndarrays instead of dropping them,
-        # and admission swaps them back (inference/host_kv.py). 0 = off;
-        # PADDLE_TPU_HOST_KV kill-switches an explicit cap.
-        from .host_kv import resolve_host_kv
-        self.host_kv_bytes = resolve_host_kv(host_kv_bytes)
+        # and admission swaps them back (inference/host_kv.py). 0 = off.
+        self.host_kv_bytes = int(host_kv_bytes or 0)
+        if self.host_kv_bytes < 0:
+            raise ValueError(
+                f"host_kv_bytes must be >= 0; got {host_kv_bytes}")
         self._host_tier = None
         self._host_stage: dict = {}    # prefix key -> (dk, dv) prefetch
         if self.paged and self.prefix_sharing and self.host_kv_bytes > 0:

@@ -67,84 +67,29 @@ TARGET-model non-finite logits quarantine (the -1 sentinel), and only
 over rows the slot actually emits. `testing/faults.py draft_nan`
 injects the draft lane; tools/chaos_serving.py asserts the degrade.
 
-Selection (the kernels/registry.py seam, same precedence story as
-decode_attention): kernel "spec_decode", impls "off" | "spec".
-`PADDLE_TPU_SPEC_DECODE` is the env override AND the kill switch —
-an explicit off value ("0"/"off"/"dense"/"false") disables
-speculation even on engines built with spec_decode="spec", so a
-misbehaving deployment can be flattened without a code change.
-Default: off (adoption only via env > sweep-winner > registry —
-tools/bench_serving.py --spec --adopt is the evidence-gated writer).
+Selection: the engine's `spec_decode=` argument ("auto" | "off" |
+"spec"; auto is off). At run time `ServingEngine.set_spec_drafts` (the
+brownout controller's lever) turns the drafts of a spec-built engine off
+and back on.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["SPEC_PAD", "spec_decode_impl", "resolve_spec", "spec_tick"]
-
-ENV_SPEC_DECODE = "PADDLE_TPU_SPEC_DECODE"
+__all__ = ["SPEC_PAD", "resolve_spec", "spec_tick"]
 
 # emission-matrix pad sentinel: -1 is the quarantine verdict, real ids
 # are never negative — -2 marks "no token emitted in this column"
 SPEC_PAD = -2
 
-_OFF_VALUES = frozenset({"0", "off", "dense", "false", "no"})
-_ON_VALUES = frozenset({"1", "spec", "on", "true", "yes"})
-
-
-def _env_value() -> str:
-    """Read + classify PADDLE_TPU_SPEC_DECODE: '' (unset), 'off',
-    or 'spec'. An unrecognized value is treated as OFF with a stderr
-    warning — this env var is the kill switch, and a typo that
-    silently ENABLED speculation would do the exact opposite of what
-    the operator reached for."""
-    env = os.environ.get(ENV_SPEC_DECODE, "").strip().lower()
-    if not env:
-        return ""
-    if env in _ON_VALUES:
-        return "spec"
-    if env not in _OFF_VALUES:
-        import sys
-        print(f"[spec_decode] {ENV_SPEC_DECODE}={env!r} is not one of "
-              f"{sorted(_ON_VALUES)} / {sorted(_OFF_VALUES)}; treating "
-              "as 'off' (the kill switch fails safe)",
-              file=sys.stderr, flush=True)
-    return "off"
-
-
-def spec_decode_impl() -> str:
-    """Selector: env PADDLE_TPU_SPEC_DECODE > registry winner
-    ('spec_decode', current backend class) > 'off'. The env var is
-    re-read per engine build like the Pallas kill switches."""
-    env = _env_value()
-    if env:
-        return env
-    from ..kernels import registry
-    win = registry.winner("spec_decode",
-                          backend=registry.backend_class(
-                              jax.default_backend()))
-    return win or "off"
-
 
 def resolve_spec(knob: str) -> bool:
-    """Engine-build resolution of the spec_decode knob ('auto' | 'off'
-    | 'spec') against the selector. The env KILL SWITCH is absolute: an
-    off value disables speculation even for knob='spec' (the only
-    selector in the repo where env beats an explicit caller choice —
-    that asymmetry is what makes it a kill switch, docs/serving.md).
-    Unrecognized env values count as off (_env_value fails safe)."""
-    if _env_value() == "off":
-        return False
-    if knob == "off":
-        return False
-    if knob == "spec":
-        return True
-    if knob == "auto":
-        return spec_decode_impl() == "spec"
-    raise ValueError(f"spec_decode {knob!r} (auto|off|spec)")
+    """The engine's `spec_decode=` argument ('auto' | 'off' | 'spec') as
+    a bool; anything else raises. 'auto' is off."""
+    if knob not in ("auto", "off", "spec"):
+        raise ValueError(f"spec_decode {knob!r} (auto|off|spec)")
+    return knob == "spec"
 
 
 def _spec_core(params, cache, toks, positions, active, temps, top_ks,

@@ -31,39 +31,38 @@ GQA is native: kc/vc carry KV heads; queries fold their group axis into
 the einsum so repeated KV is never materialized (models/llama.py's
 decode-bandwidth trade).
 
-Implementation selection (the kernels/registry.py seam — env >
-registry winner > default, same precedence as flash_attention._attn_impl):
-- 'dense'  f32 scores AND f32 context accumulation (default: exactly
-  the training forward's numerics, required for the serving engine's
+The attention math is one of two, chosen by DECODE_ATTN_IMPL below
+(`cached_attention(impl=)` overrides it for one call):
+- 'dense'  f32 scores AND f32 context accumulation (exactly the
+  training forward's numerics, required for the serving engine's
   bit-parity guarantee against per-request greedy decode);
 - 'mixed'  QK^T and P·V run in the cache dtype with an f32 softmax —
-  halves decode HBM traffic for bf16 caches; opt in per backend via
-  the registry or PADDLE_TPU_DECODE_ATTN_IMPL;
-- 'paged'  the serving engine's block-pool cache layout (vLLM's
-  PagedAttention, SOSP '23): K/V live in fixed-size pages
-  [P, page_size, KV, hd] shared by every slot, and a per-slot page
-  table [B, max_pages] maps logical cache positions to physical
-  pages. `gather_pages` re-linearizes a slot's view (logical position
-  p lands at view index p, so the attention math — and therefore the
-  token stream — is BIT-IDENTICAL to 'dense'); `write_kv_paged`
-  scatters the step's K/V through the table. The selector only
-  changes the CACHE LAYOUT the serving engine allocates; the
-  attention math of a gathered view is 'dense' (attn_math_impl).
-  Kill switch: PADDLE_TPU_DECODE_ATTN_IMPL=dense.
+  halves decode HBM traffic for bf16 caches.
+
+The cache LAYOUT is not chosen here: it is the engine's `kv_layout=`.
+Under "paged" (vLLM's PagedAttention, SOSP '23) K/V live in fixed-size
+pages [P, page_size, KV, hd] shared by every slot, and a per-slot page
+table [B, max_pages] maps logical cache positions to physical pages.
+`gather_pages` re-linearizes a slot's view (logical position p lands at
+view index p, so the attention math — and therefore the token stream —
+is BIT-IDENTICAL to the dense pool's); `write_kv_paged` scatters the
+step's K/V through the table.
 """
 from __future__ import annotations
 
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["write_kv", "cached_attention", "decode_attn_impl",
-           "gather_pages", "write_kv_paged", "layer_view",
-           "attn_math_impl", "cache_pspecs", "attended_tokens",
-           "kv_view_extent", "ring_positions", "ring_rows",
-           "blocked_attention"]
+__all__ = ["write_kv", "cached_attention", "gather_pages",
+           "write_kv_paged", "layer_view", "cache_pspecs",
+           "attended_tokens", "kv_view_extent", "ring_positions",
+           "ring_rows", "blocked_attention"]
+
+# The decode attention math, 'dense' | 'mixed' (module docstring).
+# ROADMAP S5 decides between them in the GPT serving cells and keeps one.
+DECODE_ATTN_IMPL = "dense"
 
 
 def cache_pspecs(paged: bool, tp_axis: str = "tp"):
@@ -86,28 +85,6 @@ def cache_pspecs(paged: bool, tp_axis: str = "tp"):
     if paged:
         specs["pt"] = P()
     return specs
-
-
-def decode_attn_impl() -> str:
-    """Selector: env PADDLE_TPU_DECODE_ATTN_IMPL > registry winner
-    ('decode_attention', current backend class) > 'dense'. The env var
-    is re-read per trace like the Pallas kill switches."""
-    env = os.environ.get("PADDLE_TPU_DECODE_ATTN_IMPL")
-    if env:
-        return env
-    from . import registry
-    win = registry.winner("decode_attention",
-                          backend=registry.backend_class(
-                              jax.default_backend()))
-    return win or "dense"
-
-
-def attn_math_impl(impl: str | None = None) -> str:
-    """The attention-math flavor for a given selector: 'paged' is a
-    cache LAYOUT — its gathered per-slot view runs the 'dense' f32
-    math (bit-parity with the dense pool is the whole point)."""
-    impl = impl or decode_attn_impl()
-    return "dense" if impl == "paged" else impl
 
 
 def gather_pages(pages, table, layer=None):
@@ -297,10 +274,10 @@ def cached_attention(q, kc, vc, pos, impl: str | None = None,
         return _native_attention(q, kc, vc, pos, window)
     if window is not None:
         raise ValueError("a window mask needs impl='native'")
-    impl = attn_math_impl(impl)
+    impl = impl or DECODE_ATTN_IMPL
     if impl not in ("dense", "mixed"):
         raise ValueError(
-            f"unknown decode_attention impl {impl!r} (dense|mixed|paged)")
+            f"unknown decode_attention impl {impl!r} (dense|mixed)")
     dot_dt = kc.dtype if impl == "mixed" else jnp.float32
     scale = 1.0 / math.sqrt(hd)
 

@@ -135,7 +135,7 @@ def _pallas_enabled() -> bool:
     return use_pallas
 
 
-def _pallas_attn_enabled(seq: int | None = None) -> bool:
+def _pallas_attn_enabled() -> bool:
     """Attention-only gate layered on the global one (CE kernel
     unaffected — it gates through _pallas_enabled directly): the round-4
     ablation measured the XLA attention path faster than the Pallas flash
@@ -145,7 +145,7 @@ def _pallas_attn_enabled(seq: int | None = None) -> bool:
     if os.environ.get("PADDLE_TPU_DISABLE_PALLAS_ATTN", "") in (
             "1", "true", "True"):
         return False
-    if _attn_impl(seq) == "xla":
+    if _attn_impl() == "xla":
         return False
     return _pallas_enabled()
 
@@ -220,8 +220,7 @@ def _tuned_blocks(q, k, causal):
 
 
 def _fwd_with_lse(q, k, v, causal, kv_len=None):
-    if _pallas_attn_enabled(q.shape[1]) \
-            and is_tpu():
+    if _pallas_attn_enabled() and is_tpu():
         from .pallas_attention import mha_fwd
         blocks = _tuned_blocks(q, k, causal)
         if blocks is not None:
@@ -302,18 +301,17 @@ def _flash_mha_fwd(q, k, v, causal, kv_len=None):
     return out, (q, k, v, out, lse)
 
 
-def _pallas_bwd_enabled(seq: int | None = None) -> bool:
+def _pallas_bwd_enabled() -> bool:
     import os
     if os.environ.get("PADDLE_TPU_DISABLE_PALLAS_BWD", "") in ("1", "true",
                                                                "True"):
         return False
-    return _pallas_attn_enabled(seq)
+    return _pallas_attn_enabled()
 
 
 def _flash_mha_bwd(causal, kv_len, res, do):
     q, k, v, out, lse = res
-    if _pallas_bwd_enabled(q.shape[1]) \
-            and is_tpu():
+    if _pallas_bwd_enabled() and is_tpu():
         from .pallas_attention import mha_bwd
         blocks = _tuned_blocks_bwd(q, k, causal)
         if blocks is not None:
@@ -327,80 +325,22 @@ def _flash_mha_bwd(causal, kv_len, res, do):
 _flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
 
 
-_sweep_winner_impl = None     # memoized perf/sweep_winner.json read
-
-
-def impl_from_winner_env(env: dict) -> str:
-    """ONE home for the sweep-spec env -> impl translation: the sweep
-    spells 'xla' as the
-    PADDLE_TPU_DISABLE_PALLAS_ATTN kill switch. '' when the env names no
-    recognizable impl."""
-    impl = env.get("PADDLE_TPU_ATTN_IMPL", "")
-    if not impl and env.get("PADDLE_TPU_DISABLE_PALLAS_ATTN") == "1":
-        impl = "xla"
-    return impl if impl in ("pallas", "jax_flash", "splash", "xla") \
-        else ""
-
-
-def _winner_impl():
-    """Attention impl adopted by the latest hardware sweep
-    (perf/sweep_winner.json, written by kernels.registry.
-    adopt_sweep_winner when tools/sweep_gpt_step.py lands) — the measured
-    winner ships as the TPU default without a code edit. Only consulted
-    on the TPU backend: the CPU
-    suite must keep exercising the documented 'pallas' path (interpret-
-    mode parity coverage would silently vanish otherwise). Memoized for
-    the process lifetime; absent/invalid file -> None."""
-    global _sweep_winner_impl
-    if not is_tpu():
-        return None
-    if _sweep_winner_impl is None:
-        import json
-        import os
-        path = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))), "perf",
-            "sweep_winner.json")
-        env = {}
-        try:
-            with open(path) as f:
-                env = json.load(f).get("env", {})
-        except (OSError, ValueError):
-            pass
-        _sweep_winner_impl = impl_from_winner_env(env)
-    return _sweep_winner_impl or None
-
-
-def _registry_impl(seq: int | None = None):
-    """Evidence-gated registry winner for the current backend class
-    (kernels/registry.py; perf/kernel_registry.json). Seeded so that
-    the TPU backend defaults to 'xla' — the only hardware ablation's
-    winner — and CPU keeps 'pallas' for parity coverage. Exact
-    shape bucket first, then the wildcard row."""
-    from . import registry
-    cls = registry.backend_class(jax.default_backend())
-    bucket = registry.seq_bucket(seq) if seq else "*"
-    return registry.winner("attention", backend=cls, bucket=bucket)
-
-
-def _attn_impl(seq: int | None = None) -> str:
-    """Attention implementation selector (PADDLE_TPU_ATTN_IMPL):
+def _attn_impl() -> str:
+    """Which attention implementation runs:
     - 'pallas'   homegrown kernel + the gates above
     - 'jax_flash' jax.experimental.pallas.ops.tpu.flash_attention — the
       upstream-tuned TPU kernel with its own fwd+bwd Pallas passes
     - 'splash'   jax.experimental splash attention (block-sparse mask
-      pipeline; usually the fastest causal kernel)
+      pipeline)
     - 'xla'      the blockwise lax.scan path (same as the ATTN kill)
-    The ENV VAR is re-read per trace like the kill switches; with it
-    unset, the TPU backend follows the latest measured sweep winner
-    (perf/sweep_winner.json, memoized per process — a sweep landing
-    mid-process applies from the next process), then BOTH backend
-    classes consult the kernel-selection registry
-    (perf/kernel_registry.json, evidence-gated), and only then the
-    hardcoded 'pallas'. `seq` (when the caller knows it) picks the
-    registry's shape bucket."""
-    import os
-    return (os.environ.get("PADDLE_TPU_ATTN_IMPL")
-            or _winner_impl() or _registry_impl(seq) or "pallas")
+    On the TPU 'xla': the one step ablation on a v5e (2026-07-30,
+    GPT-350M B=8 S=1024 bf16) read 399.7 ms/step for it against 427.6+
+    for every Pallas forward, and it is what the train cell measures.
+    Elsewhere 'pallas', so that the CPU suite keeps exercising the
+    homegrown kernel's path (interpret-mode parity coverage would
+    silently vanish if the CPU followed the TPU's choice). ROADMAP S3
+    races the four in the train cell and keeps one."""
+    return "xla" if is_tpu() else "pallas"
 
 
 def _jax_flash_mha(q, k, v, causal):
@@ -442,8 +382,8 @@ def _dispatch_mha(q, k, v, causal):
     # the upstream kernel is still Pallas: the global and attention kill
     # switches outrank the impl selector, preserving the documented
     # global > attention-only > impl layering
-    impl = _attn_impl(q.shape[1])
-    if (impl in ("jax_flash", "splash") and _pallas_attn_enabled(q.shape[1])
+    impl = _attn_impl()
+    if (impl in ("jax_flash", "splash") and _pallas_attn_enabled()
             and is_tpu()):
         fn = _splash_mha if impl == "splash" else _jax_flash_mha
         return fn(q, k, v, causal)

@@ -278,7 +278,7 @@ def ce_fused_train(logits2d, targets, interpret=False):
     the ONE-PASS fused kernel (_ce_fused) already emitted d_logits with
     the loss, so backward is a cotangent scale instead of a second
     kernel re-reading the logits. Select it only where the grad is
-    always taken (registry impl 'pallas_fused'): a primal-only call
+    always taken (models/losses.CE_FUSED_GRAD): a primal-only call
     computes and discards the d_logits half."""
     bt, bv = _tuned_ce_blocks(logits2d)
     loss, _ = _ce_fused(logits2d, targets, block_t=bt, block_v=bv,

@@ -14,11 +14,9 @@ updated in f32, written back in storage dtype — the AMP master-weight
 pattern without materializing a separate master copy) and its numerics
 are rule-for-rule the models.gpt.apply_adamw oracle.
 
-Wired behind gpt.apply_adamw when the registry names 'pallas' for the
-'fused_update' kernel on the TPU backend (evidence-gated adoption —
-kernels/registry.py); PADDLE_TPU_DISABLE_PALLAS (global) and
-PADDLE_TPU_DISABLE_PALLAS_UPDATE (targeted) kill it. The jax-level form
-stays the default and the parity oracle (tests/test_kernels.py).
+Wired behind gpt.apply_adamw by the FUSED_UPDATE constant below (off);
+PADDLE_TPU_DISABLE_PALLAS (global) vetoes it. The jax-level form is the
+default and the parity oracle (tests/test_kernels.py).
 """
 from __future__ import annotations
 
@@ -124,19 +122,15 @@ def fused_apply_adamw(grads, params, opt_state, lr, beta1=0.9,
     return new_params, {"m": new_m, "v": new_v, "step": step}
 
 
+# Whether gpt.apply_adamw runs this kernel instead of its jax form.
+# ROADMAP S7 times it once in the train cell, then flips this or deletes
+# the kernel.
+FUSED_UPDATE = False
+
+
 def fused_update_enabled() -> bool:
-    """The gpt.apply_adamw consult: TPU backend, Pallas alive
-    (global + targeted kill switches), and the registry's evidence-gated
-    'fused_update' winner naming 'pallas'. No entry = jax default."""
-    import os
-    from .flash_attention import _pallas_enabled
-    if not _pallas_enabled():
-        return False
-    if os.environ.get("PADDLE_TPU_DISABLE_PALLAS_UPDATE", "") in (
-            "1", "true", "True"):
-        return False
+    """The gpt.apply_adamw consult: the constant, on the TPU backend,
+    with Pallas alive (the global kill switch)."""
     from ..device import is_tpu
-    if not is_tpu():
-        return False
-    from . import registry
-    return registry.winner("fused_update", backend="tpu") == "pallas"
+    from .flash_attention import _pallas_enabled
+    return FUSED_UPDATE and is_tpu() and _pallas_enabled()

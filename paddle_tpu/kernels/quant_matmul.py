@@ -10,8 +10,8 @@ the weight in HBM — that copy not existing IS the feature (weight HBM
 traffic halves vs bf16, quarters vs f32, which is what a bandwidth-
 bound decode tick actually pays for).
 
-Two implementations, selected through the kernels/registry.py seam
-(kernel "quant_matmul", impls off|xla|pallas):
+Two implementations; which one a site runs is QUANT_MATMUL_IMPL below
+(`quant_matmul(impl=)` overrides it for one call):
 
 - 'xla'    jax dot_general on the fp activations against the int8
            weight upcast IN THE FUSION (XLA keeps the convert fused
@@ -33,23 +33,12 @@ x @ (w_q.astype(f32) * scale) up to one fp rounding per product —
 the parity tests hold the impls bitwise-identical to EACH OTHER and
 allclose to the dequant-first oracle.
 
-Selection and the kill switch (the spec_decode pattern — env beats
-everything, unrecognized values fail SAFE to off):
-
-- env PADDLE_TPU_QUANT: 'off'/'0'/'false'/'no'/'fp'/'dense' disable
-  weight-only quant even for engines built with quant="int8";
-  'xla'/'pallas' enable it AND pin the matmul impl; '1'/'on'/'true'/
-  'yes'/'int8' enable it with the portable 'xla' impl; anything else
-  warns on stderr and counts as OFF (a typo must kill, not enable).
-- registry: winner("quant_matmul") — written only by the evidence-
-  gated sweep (tools/bench_serving.py --quant --adopt, which refuses
-  adoption unless weight bytes <= 0.55x fp AND tokens/s >= 0.95x fp).
-- default: off.
+Whether an engine quantizes at all is its `quant=` argument
+("auto" | "off" | "int8"; auto is off).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -57,85 +46,35 @@ import jax.numpy as jnp
 from ..device import is_tpu
 from .primitives import pad_to as _pad_to, round_up as _round_up
 
-__all__ = ["ENV_QUANT", "quant_impl", "resolve_quant", "matmul_impl",
-           "quant_matmul", "leaf_matmul"]
+__all__ = ["resolve_quant", "matmul_impl", "quant_matmul", "leaf_matmul"]
 
-ENV_QUANT = "PADDLE_TPU_QUANT"
-
-_OFF_VALUES = frozenset({"0", "off", "false", "no", "fp", "dense"})
-_ON_VALUES = frozenset({"1", "on", "true", "yes", "int8"})
 _IMPL_VALUES = frozenset({"xla", "pallas"})
 
-
-def _env_value() -> str:
-    """Read + classify PADDLE_TPU_QUANT: '' (unset), 'off', 'xla' or
-    'pallas'. Unrecognized values are OFF with a stderr warning — this
-    env var is the kill switch, and a typo that silently enabled
-    quantized serving would do the exact opposite of what the operator
-    reached for (the spec_decode fail-safe rule)."""
-    env = os.environ.get(ENV_QUANT, "").strip().lower()
-    if not env:
-        return ""
-    if env in _IMPL_VALUES:
-        return env
-    if env in _ON_VALUES:
-        return "xla"
-    if env not in _OFF_VALUES:
-        import sys
-        print(f"[quant_matmul] {ENV_QUANT}={env!r} is not one of "
-              f"{sorted(_IMPL_VALUES | _ON_VALUES)} / "
-              f"{sorted(_OFF_VALUES)}; treating as 'off' (the kill "
-              "switch fails safe)", file=sys.stderr, flush=True)
-    return "off"
-
-
-def quant_impl() -> str:
-    """Selector: env PADDLE_TPU_QUANT > registry winner
-    ('quant_matmul', current backend class) > 'off'. Re-read per
-    engine build like the other kill switches."""
-    env = _env_value()
-    if env:
-        return env
-    from . import registry
-    win = registry.winner("quant_matmul",
-                          backend=registry.backend_class(
-                              jax.default_backend()))
-    return win or "off"
+# The matmul a quantized engine's sites run, 'xla' | 'pallas' (module
+# docstring). ROADMAP S2 times int8 in the GPT serving cells with each,
+# then flips this or deletes the Pallas kernel.
+QUANT_MATMUL_IMPL = "xla"
 
 
 def resolve_quant(knob: str) -> bool:
-    """Engine-build resolution of the quant knob ('auto' | 'off' |
-    'int8') against the selector. The env kill switch is absolute: an
-    off value disables quantization even for knob='int8' (same
-    asymmetry as PADDLE_TPU_SPEC_DECODE — docs/serving.md)."""
-    if _env_value() == "off":
-        return False
-    if knob == "off":
-        return False
-    if knob == "int8":
-        return True
-    if knob == "auto":
-        return quant_impl() != "off"
-    raise ValueError(f"quant {knob!r} (auto|off|int8)")
+    """The engines' `quant=` argument ('auto' | 'off' | 'int8') as a
+    bool; anything else raises. 'auto' is off."""
+    if knob not in ("auto", "off", "int8"):
+        raise ValueError(f"quant {knob!r} (auto|off|int8)")
+    return knob == "int8"
 
 
 def matmul_impl() -> str:
     """Which implementation a quant_matmul SITE runs: 'pallas' when
-    selected AND the backend is TPU (the compiled kernel targets
-    Mosaic; off-TPU callers get the numerically-identical 'xla' form —
-    interpret-mode coverage lives in the parity tests) AND the global
-    PADDLE_TPU_DISABLE_PALLAS escape hatch is not set (the CLAUDE.md
-    kill-switch convention every Pallas kernel honors), else 'xla'.
-    'off' here still resolves to 'xla': an engine that already
-    quantized its weights at build must keep serving them — the kill
-    switch stops NEW engines from quantizing (resolve_quant), it
-    cannot un-quantize a live tree."""
-    sel = quant_impl()
-    if (sel == "pallas"
-            and is_tpu()
-            and os.environ.get("PADDLE_TPU_DISABLE_PALLAS", "")
-            not in ("1", "true", "True")):
-        return "pallas"
+    QUANT_MATMUL_IMPL says so AND the backend is TPU (the compiled
+    kernel targets Mosaic; off-TPU callers get the numerically-identical
+    'xla' form — interpret-mode coverage lives in the parity tests) AND
+    Pallas is alive (the global PADDLE_TPU_DISABLE_PALLAS escape every
+    Pallas kernel honors), else 'xla'."""
+    if QUANT_MATMUL_IMPL == "pallas" and is_tpu():
+        from .flash_attention import _pallas_enabled
+        if _pallas_enabled():
+            return "pallas"
     return "xla"
 
 
@@ -201,7 +140,7 @@ def quant_matmul(x, w_q, scale, impl: str | None = None,
                  interpret: bool = False):
     """y = x @ dequant(w_q): x [..., K] float, w_q [K, N] int8, scale
     [N] f32 per-output-channel. Returns [..., N] in x.dtype. `impl`
-    overrides the selector (tests); `interpret` runs the Pallas kernel
+    overrides matmul_impl() (tests); `interpret` runs the Pallas kernel
     in interpreter mode (CPU parity tests)."""
     impl = impl or matmul_impl()
     if impl not in _IMPL_VALUES:

@@ -580,16 +580,14 @@ class FacadeModel:
         Speculative decoding passes through the same way:
         `spec_decode` ("auto"|"off"|"spec"), `gamma` (draft length)
         and `draft_layers` (self-draft depth) reach the ServingEngine
-        (inference/spec_decode.py; PADDLE_TPU_SPEC_DECODE is the kill
-        switch) and join the engine cache key — switching gamma or
-        draft depth rebuilds the engine rather than serving a tick
-        compiled for the old knobs.
+        (inference/spec_decode.py) and join the engine cache key —
+        switching gamma or draft depth rebuilds the engine rather than
+        serving a tick compiled for the old knobs.
 
         Quantized serving: `quant` ("auto"|"off"|"int8") selects the
-        weight-only int8 path (inference/serving.py quant=;
-        PADDLE_TPU_QUANT is the kill switch) and joins the engine
-        cache key — a quant engine compiled over the int8 tree is
-        never reused for fp serving or vice versa.
+        weight-only int8 path (inference/serving.py quant=) and joins
+        the engine cache key — a quant engine compiled over the int8
+        tree is never reused for fp serving or vice versa.
 
         Tensor-parallel serving: `mesh` (a jax Mesh with a `tp_axis`
         axis — parallel.mesh.build_mesh({'tp': N})) shards the engine's

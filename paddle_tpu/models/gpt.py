@@ -66,7 +66,7 @@ class GPTConfig:
     # - "all_but_mlp" checkpoints ONLY the dense FFN (nested, inside an
     #   otherwise unremat'd block) — near-no-remat speed at batches
     #   where true no-remat OOMs; recompute = the FFN forward per layer.
-    # All raced on hardware in tools/sweep_gpt_step.py.
+    # All raced on hardware once (2026-07; BASELINE.md "Earlier chip numbers").
     remat_policy: str = "full"
     # lax.scan unroll factor over the layer axis: >1 lets XLA fuse across
     # adjacent blocks at the cost of compile time; raced on hardware, the
@@ -594,10 +594,10 @@ def apply_adamw(grads, params, opt_state, lr, beta1=0.9, beta2=0.95,
     params cast back to their storage dtype). Shared by every flagship
     family's train_step (gpt, llama) so the update rule cannot drift.
 
-    On the TPU backend with an evidence-gated 'fused_update' registry
-    winner the whole update runs through the hand-tiled Pallas kernel
+    With `pallas_update.FUSED_UPDATE` set (it is not) the whole update
+    runs on the TPU through the hand-tiled Pallas kernel
     (kernels/pallas_update.py — one launch per leaf, rule-for-rule these
-    numerics); this jax form stays the default and the parity oracle."""
+    numerics); this jax form is the default and the parity oracle."""
     from ..kernels.pallas_update import fused_update_enabled
     if fused_update_enabled():
         from ..kernels.pallas_update import fused_apply_adamw
@@ -714,8 +714,8 @@ def _cached_attention(x, params_l, layer, kc, vc, pos, cfg, pt=None):
     (the serving engine's slot pool, where every slot advances
     independently). Returns (attn_out, kc, vc) — the same pools with
     the step's rows written at [layer, ...]. The cache write and the
-    masked attention go through the selectable decode-attention seam
-    (kernels/decode_attention.py; registry kernel 'decode_attention');
+    masked attention go through the decode-attention seam
+    (kernels/decode_attention.py);
     the paged path scatters the write through the table and attends a
     gathered per-slot view — bit-identical to the dense layout."""
     B, T, D = x.shape

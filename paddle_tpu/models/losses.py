@@ -12,6 +12,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# Whether the loss runs pallas_ce.ce_fused_train, the one-pass CE+grad
+# kernel, instead of ce_with_logits (fused_softmax_ce says what that
+# costs). ROADMAP S7 times it once in the train cell, then flips this or
+# deletes the kernel.
+CE_FUSED_GRAD = False
+
 
 def _pallas_ce_enabled() -> bool:
     import os
@@ -26,13 +32,7 @@ def _pallas_ce_enabled() -> bool:
             "1", "true", "True"):
         return False
     from ..device import is_tpu
-    if not is_tpu():
-        return False
-    # evidence-gated selection: a registered (and plausibility-gated)
-    # 'jax' winner for the CE kernel routes the loss onto the jax-level
-    # form without a code edit; no entry keeps the Pallas default
-    from ..kernels import registry
-    return registry.winner("ce", backend="tpu") != "jax"
+    return is_tpu()
 
 
 def _ce_rows_over_mesh(ce_fn, logits2d, targets):
@@ -78,14 +78,14 @@ def fused_softmax_ce(logits, targets, valid_mask=None):
     lead = logits.shape[:-1]
     V = logits.shape[-1]
     if _pallas_ce_enabled() and pallas_ce.suitable(logits.shape):
-        # the one-pass CE+grad flavor (backward folded into the forward
-        # launch) rides the SAME enablement gate but only engages when
-        # the registry's evidence-gated winner names it explicitly —
-        # a primal-only caller would pay for the discarded d_logits
-        from ..kernels import registry
-        ce_fn = (pallas_ce.ce_fused_train
-                 if registry.winner("ce", backend="tpu")
-                 == "pallas_fused" else pallas_ce.ce_with_logits)
+        # the one-pass flavour (backward folded into the forward launch)
+        # rides the SAME enablement gate; a primal-only caller would pay
+        # for its discarded d_logits, so it is off unless the constant
+        # says otherwise
+        if CE_FUSED_GRAD:
+            ce_fn = pallas_ce.ce_fused_train
+        else:
+            ce_fn = pallas_ce.ce_with_logits
         per_pos = _ce_rows_over_mesh(
             ce_fn, logits.reshape(-1, V),
             targets.reshape(-1).astype(jnp.int32)).reshape(lead)

@@ -84,26 +84,9 @@ _VARLEN_BLOCK_KV = 512
 
 def _varlen_impl(n_elements: int) -> str:
     """'blockwise' | 'dense' for a packing whose probs buffer would hold
-    n_elements (= H * total_q * total_k). Precedence mirrors the
-    attention selector: env override (PADDLE_TPU_VARLEN_IMPL, the
-    operator's absolute escape hatch), then the evidence-gated kernel
-    registry's winner for this backend class, then the element-count
-    heuristic. A registry 'dense' winner is a PREFERENCE, not a license
-    to OOM: it only applies while the probs buffer stays under the
-    memory guard — a wildcard row measured on a small packing must not
-    force an O(n_elements) materialization at every size."""
-    import os
-    impl = os.environ.get("PADDLE_TPU_VARLEN_IMPL", "")
-    if impl in ("blockwise", "dense"):
-        return impl
-    from ...kernels import registry
-    impl = registry.winner("varlen_attention",
-                           backend=registry.backend_class()) or ""
-    if impl == "dense" and n_elements > _VARLEN_DENSE_MAX:
-        impl = "blockwise"
-    if impl not in ("blockwise", "dense"):
-        impl = "blockwise" if n_elements > _VARLEN_DENSE_MAX else "dense"
-    return impl
+    n_elements (= H * total_q * total_k): dense only while that buffer
+    stays under the memory guard."""
+    return "blockwise" if n_elements > _VARLEN_DENSE_MAX else "dense"
 
 
 def _varlen_segments(cu, total):

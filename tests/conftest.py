@@ -94,8 +94,8 @@ SMOKE_FILES = {
     # distributed (mesh-light representatives)
     "test_collective.py", "test_sharding_stages.py", "test_auto_parallel.py",
     "test_fleet_e2e.py", "test_distributed_tail.py", "test_67b_lowering.py",
-    "test_compression.py", "test_ps_embedding.py", "test_sweep_adoption.py",
-    "test_kernel_registry.py", "test_plan3d.py", "test_plan4d.py",
+    "test_compression.py", "test_ps_embedding.py",
+    "test_kernel_selection.py", "test_plan3d.py", "test_plan4d.py",
     # io / inference / serving
     "test_multiprocess_loader.py", "test_inference.py", "test_int8.py",
     "test_serving.py", "test_serving_robustness.py", "test_paged_kv.py",

@@ -470,10 +470,7 @@ def test_counts_ride_the_one_pull_onto_the_spans(setup):
     ("mesh", {"mesh": "tp"}),
 ])
 def test_each_refused_engine_option_raises_its_typed_error(setup, option,
-                                                           kw, monkeypatch):
-    for var in ("PADDLE_TPU_SPEC_DECODE", "PADDLE_TPU_MULTI_TICK",
-                "PADDLE_TPU_QUANT", "PADDLE_TPU_HOST_KV"):
-        monkeypatch.delenv(var, raising=False)
+                                                           kw):
     cfg, params = setup
     if "mesh" in kw:
         from paddle_tpu.parallel.mesh import build_mesh

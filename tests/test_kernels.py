@@ -297,19 +297,18 @@ class TestPallasFusedCE:
             np.asarray(jax.grad(f_r)(lb)).astype(np.float32),
             rtol=0.1, atol=0.05)
 
-    def test_registry_selects_fused_impl(self, monkeypatch):
+    def test_constant_selects_fused_impl(self, monkeypatch):
         """losses.fused_softmax_ce routes onto ce_fused_train ONLY when
-        the registry's 'ce' winner names 'pallas_fused'."""
+        losses.CE_FUSED_GRAD is set."""
         from paddle_tpu.models import losses
-        from paddle_tpu.kernels import pallas_ce, registry
+        from paddle_tpu.kernels import pallas_ce
         logits, tgt = self._data(T=24, V=600, seed=17)
         logits3 = logits.reshape(2, 12, 600)
         tgt3 = tgt.reshape(2, 12)
         jax_val = float(losses.fused_softmax_ce(logits3, tgt3))
 
         monkeypatch.setattr(losses, "_pallas_ce_enabled", lambda: True)
-        monkeypatch.setattr(registry, "winner",
-                            lambda *a, **k: "pallas_fused")
+        monkeypatch.setattr(losses, "CE_FUSED_GRAD", True)
         seen = []
         real = pallas_ce.ce_fused_train
 
@@ -384,16 +383,14 @@ class TestPallasFusedUpdate:
                                        rtol=1e-6, atol=1e-7)
 
     def test_off_by_default_and_kill_switch(self, monkeypatch):
-        """No registry entry -> apply_adamw stays on the jax path; the
-        targeted and global kill switches both veto a registry win."""
-        from paddle_tpu.kernels import pallas_update, registry
+        """apply_adamw stays on the jax path, on the TPU too, while
+        FUSED_UPDATE is off; the global kill switch vetoes it when on."""
+        from paddle_tpu.kernels import pallas_update
         assert not pallas_update.fused_update_enabled()
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(registry, "winner", lambda *a, **k: "pallas")
-        assert pallas_update.fused_update_enabled()
-        monkeypatch.setenv("PADDLE_TPU_DISABLE_PALLAS_UPDATE", "1")
         assert not pallas_update.fused_update_enabled()
-        monkeypatch.delenv("PADDLE_TPU_DISABLE_PALLAS_UPDATE")
+        monkeypatch.setattr(pallas_update, "FUSED_UPDATE", True)
+        assert pallas_update.fused_update_enabled()
         monkeypatch.setenv("PADDLE_TPU_DISABLE_PALLAS", "1")
         assert not pallas_update.fused_update_enabled()
 
@@ -445,7 +442,7 @@ class TestKillSwitchGates:
         q = jnp.zeros((1, 8, 2, 4), jnp.float32)
         fa._dispatch_mha(q, q, q, True)
         assert calls == ["own"]          # default impl
-        monkeypatch.setenv("PADDLE_TPU_ATTN_IMPL", "jax_flash")
+        monkeypatch.setattr(fa, "_attn_impl", lambda: "jax_flash")
         fa._dispatch_mha(q, q, q, True)
         # CPU backend: upstream TPU kernel must NOT be selected
         expected = "jax" if jax.default_backend() == "tpu" else "own"
@@ -453,8 +450,8 @@ class TestKillSwitchGates:
 
 
 class TestUpstreamImpls:
-    """PADDLE_TPU_ATTN_IMPL backends (upstream jax.experimental kernels)
-    against the dense oracle, interpret mode on CPU."""
+    """The upstream jax.experimental attention kernels against the
+    dense oracle, interpret mode on CPU."""
 
     @pytest.mark.parametrize("causal", [True, False])
     def test_splash_matches_dense(self, causal):

@@ -18,8 +18,7 @@ The load-bearing guarantees:
   workload, zero recompiles after warmup;
 - K joins the facade engine cache key (switching K rebuilds, same K
   reuses);
-- env precedence: PADDLE_TPU_MULTI_TICK off-values kill an explicit
-  knob, an int value turns knob-0 engines on, garbage fails safe off;
+- selection: the multi_tick= argument alone (0 / "auto" is K=1);
 - host tier: prefix hits BEYOND the device pool's capacity come back
   from host RAM (swap-in, zero re-prefill of those pages) with
   bit-identical streams, and the memory ledger prices the tier as
@@ -37,7 +36,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.inference.serving import ServingEngine
 from paddle_tpu.inference import multi_tick as mt
-from paddle_tpu.inference.host_kv import HostKVTier, resolve_host_kv
+from paddle_tpu.inference.host_kv import HostKVTier
 from paddle_tpu.models.gpt import GPTConfig, init_gpt_params
 from paddle_tpu.profiler import monitor
 
@@ -74,49 +73,25 @@ def _ticks():
 # ------------------------------------------------------------ selection
 @pytest.mark.smoke
 class TestResolve:
-    def test_default_off(self, monkeypatch):
-        monkeypatch.delenv(mt.ENV_MULTI_TICK, raising=False)
+    def test_default_off(self):
         assert mt.resolve_multi_tick(0) == 1
+        assert mt.resolve_multi_tick("auto") == 1
 
-    def test_explicit_knob(self, monkeypatch):
-        monkeypatch.delenv(mt.ENV_MULTI_TICK, raising=False)
+    def test_explicit_knob(self):
         assert mt.resolve_multi_tick(4) == 4
         assert mt.resolve_multi_tick(1) == 1
 
-    def test_env_kill_switch_beats_knob(self, monkeypatch):
-        for v in ("0", "off", "false", "no", "single", "1"):
-            monkeypatch.setenv(mt.ENV_MULTI_TICK, v)
-            assert mt.resolve_multi_tick(8) == 1
-
-    def test_env_int_enables(self, monkeypatch):
-        monkeypatch.setenv(mt.ENV_MULTI_TICK, "6")
-        assert mt.resolve_multi_tick(0) == 6
-        # explicit engine knob still wins in the ON direction
-        assert mt.resolve_multi_tick(3) == 3
-
-    def test_env_scan_uses_default(self, monkeypatch):
-        monkeypatch.setenv(mt.ENV_MULTI_TICK, "scan")
-        assert mt.resolve_multi_tick(0) == mt.DEFAULT_MULTI_TICK_K
-
-    def test_garbage_fails_safe_off(self, monkeypatch, capsys):
-        monkeypatch.setenv(mt.ENV_MULTI_TICK, "turbo")
-        assert mt.resolve_multi_tick(0) == 1
-        assert "treating as 'off'" in capsys.readouterr().err
-
-    def test_negative_raises(self, monkeypatch):
-        monkeypatch.delenv(mt.ENV_MULTI_TICK, raising=False)
+    def test_negative_raises(self):
         with pytest.raises(ValueError):
             mt.resolve_multi_tick(-2)
 
-    def test_host_kv_resolve(self, monkeypatch):
-        monkeypatch.delenv("PADDLE_TPU_HOST_KV", raising=False)
-        assert resolve_host_kv(1 << 20) == 1 << 20
-        monkeypatch.setenv("PADDLE_TPU_HOST_KV", "off")
-        assert resolve_host_kv(1 << 20) == 0
-        monkeypatch.setenv("PADDLE_TPU_HOST_KV", str(1 << 16))
-        assert resolve_host_kv(0) == 1 << 16
+    def test_host_kv_resolve(self, gpt_setup):
+        cfg, params = gpt_setup
+        assert _eng(params, cfg, host_kv_bytes=1 << 20).host_kv_bytes \
+            == 1 << 20
+        assert _eng(params, cfg).host_kv_bytes == 0
         with pytest.raises(ValueError):
-            resolve_host_kv(-1)
+            _eng(params, cfg, host_kv_bytes=-1)
 
 
 # ------------------------------------------------------ stream parity

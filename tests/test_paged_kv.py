@@ -140,12 +140,6 @@ class TestPagedKernels:
         assert np.asarray(out[1:]).sum() == 0.0
         assert np.asarray(out[0]).sum() != 0.0
 
-    def test_paged_impl_selector(self, monkeypatch):
-        from paddle_tpu.kernels import decode_attention as da
-        monkeypatch.setenv("PADDLE_TPU_DECODE_ATTN_IMPL", "paged")
-        assert da.decode_attn_impl() == "paged"
-        assert da.attn_math_impl() == "dense"     # layout, not math
-
 
 # --------------------------------------------------------------------------
 # bit-parity vs the dense pool
@@ -179,14 +173,6 @@ class TestPagedParity:
             prompts, 6, temperature=0.8, top_k=5)
         for x, y in zip(a, b):
             np.testing.assert_array_equal(x, y)
-
-    def test_env_selects_paged_layout(self, gpt_setup, monkeypatch):
-        cfg, params = gpt_setup
-        monkeypatch.setenv("PADDLE_TPU_DECODE_ATTN_IMPL", "paged")
-        eng = _dense(params, cfg)         # kv_layout defaults to auto
-        assert eng.paged
-        monkeypatch.setenv("PADDLE_TPU_DECODE_ATTN_IMPL", "dense")
-        assert not _dense(params, cfg).paged  # the kill switch
 
 
 # --------------------------------------------------------------------------

@@ -12,8 +12,8 @@ The load-bearing guarantees:
   dense/paged, spec on/off, tp-sharded/unsharded, gpt and llama/GQA —
   (weight-only dequant is deterministic), while quant-vs-fp logits
   carry a measured error budget;
-- selection precedence + the PADDLE_TPU_QUANT kill switch fail SAFE
-  (unrecognized values disable, never enable);
+- selection: the quant= argument alone ("auto" is off), and the
+  matmul constant takes its Pallas form on the TPU only;
 - the engine invariants survive quantization: trace-count ceilings,
   one host pull per tick, cache-key distinctness of facade quant=.
 """
@@ -25,7 +25,6 @@ from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.inference.serving import ServingEngine
 from paddle_tpu.kernels import quant_matmul as qm
-from paddle_tpu.kernels import registry
 from paddle_tpu.models.gpt import GPTConfig, init_gpt_params
 from paddle_tpu.models import llama as llama_mod
 from paddle_tpu.quantization.int8 import (quantize_weight,
@@ -182,49 +181,25 @@ def test_leaf_matmul_routes_by_tree():
 
 
 # --------------------------------------------------------------------------
-# selection precedence + kill switch
+# selection: the quant= argument, and the matmul constant
 # --------------------------------------------------------------------------
-def test_env_kill_switch_fails_safe(monkeypatch, capsys):
-    monkeypatch.setenv(qm.ENV_QUANT, "pallsa")        # typo
-    assert qm.quant_impl() == "off"
-    assert qm.resolve_quant("int8") is False          # typo KILLS
-    assert "fails safe" in capsys.readouterr().err
-    monkeypatch.setenv(qm.ENV_QUANT, "off")
-    assert qm.resolve_quant("int8") is False
-    monkeypatch.setenv(qm.ENV_QUANT, "xla")
-    assert qm.resolve_quant("off") is False           # knob off wins
-    assert qm.resolve_quant("auto") is True
-    monkeypatch.delenv(qm.ENV_QUANT)
+def test_resolve_validates():
+    assert qm.resolve_quant("int8") is True
+    assert qm.resolve_quant("off") is False
+    assert qm.resolve_quant("auto") is False
     with pytest.raises(ValueError):
         qm.resolve_quant("fp8")
 
 
-def test_env_on_values_and_impl_selection(monkeypatch):
-    monkeypatch.setenv(qm.ENV_QUANT, "1")
-    assert qm.quant_impl() == "xla"
-    assert qm.resolve_quant("auto") is True
-    monkeypatch.setenv(qm.ENV_QUANT, "pallas")
-    assert qm.quant_impl() == "pallas"
+def test_matmul_impl_follows_the_constant_on_tpu_only(monkeypatch):
+    assert qm.matmul_impl() == "xla"
+    monkeypatch.setattr(qm, "QUANT_MATMUL_IMPL", "pallas")
     # off-TPU the matmul site degrades to the identical xla form
     assert qm.matmul_impl() == "xla"
-
-
-def test_registry_default_off_and_adoption_path(monkeypatch, tmp_path):
-    monkeypatch.delenv(qm.ENV_QUANT, raising=False)
-    path = str(tmp_path / "reg.json")
-    monkeypatch.setattr(registry, "REGISTRY_PATH", path)
-    registry._reset()
-    assert qm.quant_impl() == "off"                  # empty registry
-    assert qm.resolve_quant("auto") is False
-    assert registry.adopt("quant_matmul", "xla", 5.0,
-                          bytes_moved=1e8, path=path) is None
-    registry._reset()
-    assert qm.quant_impl() == "xla"                  # adopted winner
-    assert qm.resolve_quant("auto") is True
-    # an illegal impl name never validates
-    assert registry.adopt("quant_matmul", "int4", 5.0,
-                          bytes_moved=1e8, path=path) is not None
-    registry._reset()
+    monkeypatch.setattr(qm, "is_tpu", lambda: True)
+    assert qm.matmul_impl() == "pallas"
+    monkeypatch.setenv("PADDLE_TPU_DISABLE_PALLAS", "1")
+    assert qm.matmul_impl() == "xla"
 
 
 # --------------------------------------------------------------------------
@@ -352,14 +327,6 @@ def test_quant_off_engine_has_no_quant_leaves(gpt_setup):
     eng = _engine(params, cfg, "gpt")             # default auto -> off
     assert eng.quant is False
     assert eng.quant_stats() == {"quant": "off"}
-    assert not any(k.endswith("_q") for k in eng._params)
-
-
-def test_env_kill_switch_blocks_engine_quant(monkeypatch, gpt_setup):
-    cfg, params = gpt_setup
-    monkeypatch.setenv(qm.ENV_QUANT, "off")
-    eng = _engine(params, cfg, "gpt", quant="int8")
-    assert eng.quant is False
     assert not any(k.endswith("_q") for k in eng._params)
 
 

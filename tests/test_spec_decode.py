@@ -18,13 +18,10 @@ The load-bearing guarantees:
   reproduce the non-spec engine's sampled streams exactly;
 - draft-NaN degrades to non-spec decode for the slot (never
   quarantines the target stream);
-- selection: off by default, env > registry precedence, and the
-  PADDLE_TPU_SPEC_DECODE kill switch beats even an explicit
-  spec_decode="spec" engine knob;
+- selection: off by default, on by the spec_decode= argument alone;
 - facade/hapi passthrough: spec knobs reach the engine and its cache
   key (switching gamma/draft depth rebuilds).
 """
-import json
 import os
 
 import numpy as np
@@ -321,71 +318,16 @@ class TestSpecInvariants:
 
 
 # --------------------------------------------------------------------------
-# selection: env > registry > default-off; the kill switch
+# selection: the spec_decode= argument alone; 'auto' is off
 # --------------------------------------------------------------------------
 class TestSelection:
     def test_default_off(self, gpt_setup):
         cfg, params = gpt_setup
         assert not _eng(params, cfg, num_slots=1).spec
 
-    def test_env_enables_auto(self, gpt_setup, monkeypatch):
-        cfg, params = gpt_setup
-        monkeypatch.setenv(sd.ENV_SPEC_DECODE, "spec")
-        assert _eng(params, cfg, num_slots=1).spec
-
-    def test_kill_switch_beats_explicit_spec(self, gpt_setup,
-                                             monkeypatch):
-        cfg, params = gpt_setup
-        monkeypatch.setenv(sd.ENV_SPEC_DECODE, "off")
-        assert not _eng(params, cfg, num_slots=1,
-                        spec_decode="spec").spec
-
-    def test_registry_winner_adopts(self, tmp_path, monkeypatch):
-        """A policy row for 'spec_decode' turns 'auto' on — the
-        env > sweep/registry > default precedence, like every other
-        selectable kernel."""
-        from paddle_tpu.kernels import registry
-        path = str(tmp_path / "reg.json")
-        with open(path, "w") as f:
-            json.dump({"entries": {
-                f"spec_decode::{registry.backend_class()}::*": {
-                    "impl": "spec", "kind": "policy",
-                    "reason": "test adoption"}}}, f)
-        monkeypatch.setattr(registry, "REGISTRY_PATH", path)
-        registry._reset()
-        try:
-            assert sd.spec_decode_impl() == "spec"
-            assert sd.resolve_spec("auto")
-            monkeypatch.setenv(sd.ENV_SPEC_DECODE, "off")
-            assert not sd.resolve_spec("auto")     # env beats registry
-        finally:
-            registry._reset()
-
-    def test_registry_rejects_unknown_impl(self):
-        from paddle_tpu.kernels import registry
-        assert registry._entry_problem(
-            "spec_decode::cpu::*",
-            {"impl": "warp", "kind": "policy", "reason": "x"})
-
     def test_resolve_validates(self):
         with pytest.raises(ValueError):
             sd.resolve_spec("sometimes")
-
-    def test_unknown_env_value_fails_safe_off(self, monkeypatch,
-                                              capsys):
-        """A TYPO in the kill switch must kill, not silently enable:
-        any unrecognized PADDLE_TPU_SPEC_DECODE value counts as off
-        (with a stderr warning), even against an explicit
-        spec_decode='spec' engine knob."""
-        monkeypatch.setenv(sd.ENV_SPEC_DECODE, "disable")
-        assert sd.spec_decode_impl() == "off"
-        assert not sd.resolve_spec("spec")
-        assert not sd.resolve_spec("auto")
-        assert sd.ENV_SPEC_DECODE in capsys.readouterr().err
-        monkeypatch.setenv(sd.ENV_SPEC_DECODE, "spec")
-        assert sd.spec_decode_impl() == "spec"
-        assert sd.resolve_spec("spec")
-        assert not sd.resolve_spec("off")      # caller off still wins
 
 
 # --------------------------------------------------------------------------

@@ -58,9 +58,7 @@ Self-draft depth defaults to the FULL stack (draft == target,
 acceptance 1.0): bench params are random-init, so a truncated draft
 has no learned signal and the full-depth ceiling is what isolates the
 ENGINE mechanics; --sweep additionally races truncated depths and
-reports their acceptance. --adopt writes the evidence-gated registry
-row ("spec_decode" -> "spec") only when the measured speedup clears
-1.5x and the per-tick timing passes the roofline gate.
+reports their acceptance.
 
 The default workload is the BASELINE.md "Serving" row: 16 requests,
 prompt lengths uniform in [8, 96], 32 generated tokens each, GPT
@@ -447,30 +445,6 @@ def spec_main(args):
         # in the table)
         doc["stream_mismatches"] = mismatches
 
-    if args.adopt:
-        from paddle_tpu.kernels import registry
-        ok = (mismatches == 0
-              and doc["speedup_vs_nonspec"] >= 1.5
-              and doc["recompiles_after_warmup"] == [0, 0])
-        if not ok:
-            doc["adopt"] = "refused: speedup/parity/recompile gate failed"
-        else:
-            # evidence: per-tick ms + the weight bytes a spec tick
-            # streams (target pass over gamma+1 positions + gamma
-            # truncated draft passes) — the roofline gate re-checks
-            pbytes = sum(np.asarray(v).nbytes for v in params.values())
-            per_tick_ms = spec_s * 1e3 / max(spec_ticks, 1)
-            bytes_moved = pbytes * (1.0 + args.gamma * kd / args.layers)
-            problem = registry.adopt(
-                "spec_decode", "spec", per_tick_ms,
-                bytes_moved=bytes_moved,
-                source=(f"bench_serving --spec: {doc['speedup_vs_nonspec']}x "
-                        f"single-stream GREEDY vs non-spec "
-                        f"(gamma={args.gamma}, K={kd}, "
-                        f"accept={doc['acceptance_rate']}; sampled-only "
-                        "workloads were not measured — they pay the draft "
-                        "with acceptance forced to 0)"))
-            doc["adopt"] = problem or "adopted"
     print(json.dumps(doc), flush=True)
     return 0 if mismatches == 0 else 1
 
@@ -483,14 +457,9 @@ def quant_main(args):
     prefill-shaped probe through both param trees, and the intra-quant
     determinism check (quant dense vs quant paged must be
     BIT-IDENTICAL — weight-only dequant is deterministic; only the
-    quant-vs-fp comparison carries an error budget). --adopt writes
-    the evidence-gated registry row ("quant_matmul" -> the measured
-    impl) and refuses unless weight bytes <= 0.55x fp AND tokens/s
-    >= 0.95x fp with zero recompiles and exact intra-quant parity.
-    One JSON line."""
+    quant-vs-fp comparison carries an error budget). One JSON line."""
     from paddle_tpu.models.decode import next_pow2
     from paddle_tpu.inference.serving import ServingEngine
-    from paddle_tpu.profiler import monitor
 
     gen = args.gen
     max_len = args.max_len or next_pow2(args.prompt_hi + gen)
@@ -507,12 +476,6 @@ def quant_main(args):
         outs = eng.generate(prompts, gen)
         return time.perf_counter() - t0, outs
 
-    def ticks():
-        return monitor.counter("serving.decode_ticks").value
-
-    # quant="off" EXPLICITLY: after a successful --adopt the registry
-    # winner would make the default "auto" quantize this baseline too,
-    # and the A/B would silently compare quant vs quant forever after
     base = ServingEngine(params, cfg, family=args.family,
                          num_slots=args.slots, max_len=max_len,
                          quant="off")
@@ -524,9 +487,7 @@ def quant_main(args):
                         quant="int8")
     run(eng)                                         # warm
     traces_warm = eng.trace_counts()
-    k0 = ticks()
     q_s, q_outs = run(eng)
-    q_ticks = ticks() - k0
     traces_after = eng.trace_counts()
 
     # intra-quant determinism: the paged engine over the SAME int8
@@ -578,29 +539,6 @@ def quant_main(args):
         "stream_mismatches": mismatches,     # quant dense vs paged
     }
 
-    if args.adopt:
-        from paddle_tpu.kernels import registry
-        from paddle_tpu.kernels.quant_matmul import matmul_impl
-        ok = (mismatches == 0
-              and bytes_ratio <= 0.55
-              and doc["tokens_ratio_vs_fp"] >= 0.95
-              and recompiles == [0, 0])
-        if not ok:
-            doc["adopt"] = ("refused: bytes/<=0.55x, tokens/s>=0.95x, "
-                            "parity or recompile gate failed")
-        else:
-            # evidence: per-tick ms + the int8 weight bytes a decode
-            # tick streams — the roofline gate re-checks plausibility
-            per_tick_ms = q_s * 1e3 / max(q_ticks, 1)
-            problem = registry.adopt(
-                "quant_matmul", matmul_impl(), per_tick_ms,
-                bytes_moved=float(st["quant_bytes"]),
-                source=(f"bench_serving --quant: weight bytes "
-                        f"{doc['weight_bytes_ratio']}x fp, tokens/s "
-                        f"{doc['tokens_ratio_vs_fp']}x fp, logit "
-                        f"max-abs-err {doc['logit_max_abs_err']} "
-                        f"(|logit| max {doc['logit_max_abs']})"))
-            doc["adopt"] = problem or "adopted"
     print(json.dumps(doc), flush=True)
     return 0 if mismatches == 0 else 1
 
@@ -1073,9 +1011,7 @@ def multi_tick_main(args):
     so the host pays one dispatch + one pull per K tokens
     (serving.decode_ticks counts DISPATCHES — the tokens/dispatch
     ratio printed here is the one-pull-per-K-tokens assertion). One
-    JSON line; --adopt writes the evidence-gated registry row
-    (kernels/registry.py "multi_tick": parity + >=1.5x single-stream
-    + zero recompiles)."""
+    JSON line."""
     from paddle_tpu.models.decode import next_pow2
     from paddle_tpu.inference.serving import ServingEngine
     from paddle_tpu.profiler import monitor
@@ -1172,27 +1108,6 @@ def multi_tick_main(args):
             traces_after[1] - traces_warm[1]],
         "stream_mismatches": mismatches,
     }
-    if args.adopt:
-        from paddle_tpu.kernels import registry
-        ok = (mismatches == 0
-              and doc["speedup_vs_single_tick"] >= 1.5
-              and doc["recompiles_after_warmup"] == [0, 0]
-              and mt_ticks <= expected_dispatches)
-        if not ok:
-            doc["adopt"] = "refused: speedup/parity/recompile gate failed"
-        else:
-            pbytes = sum(np.asarray(v).nbytes for v in params.values())
-            per_dispatch_ms = mt_s * 1e3 / max(mt_ticks, 1)
-            problem = registry.adopt(
-                "multi_tick", "scan", per_dispatch_ms,
-                bytes_moved=pbytes * K,
-                source=(f"bench_serving --multi-tick {K}: "
-                        f"{doc['speedup_vs_single_tick']}x single-stream "
-                        f"vs single-tick ({tokens_per_dispatch:.1f} "
-                        f"tok/dispatch measured, K={K}; dispatch-bound "
-                        "rungs only — at step-sized device work the scan "
-                        "amortizes nothing)"))
-            doc["adopt"] = problem or "adopted"
     print(json.dumps(doc), flush=True)
     return 0 if mismatches == 0 else 1
 
@@ -1334,10 +1249,6 @@ def main():
                          "the acceptance ceiling on random-init params)")
     ap.add_argument("--sweep", action="store_true",
                     help="--spec: acceptance vs gamma/draft-depth table")
-    ap.add_argument("--adopt", action="store_true",
-                    help="--spec/--quant: write the evidence-gated "
-                         "registry row (spec: speedup >= 1.5x; quant: "
-                         "weight bytes <= 0.55x AND tokens/s >= 0.95x)")
     ap.add_argument("--quant", action="store_true",
                     help="weight-only int8 A/B: fp vs quant engine, "
                          "weight bytes + tokens/s + logit error budget")

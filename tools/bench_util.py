@@ -1,18 +1,47 @@
-"""Shared timing helpers for the measurement tools (ablate_step,
-autotune_kernels, bench_int8). One copy of the forcing rule: dispatch is
-asynchronous, so a timed span ends in `jax.block_until_ready`."""
+"""Shared timing helpers for the measurement tools (autotune_kernels,
+bench_int8). One copy of the forcing rule: dispatch is asynchronous, so
+a timed span ends in `jax.block_until_ready`."""
 from __future__ import annotations
 
 import time
 
 import jax
 
-# The roofline plausibility gate moved into the package
-# (paddle_tpu/kernels/registry.py) so the kernel-selection registry's
-# adoption path and the tools share ONE rule; re-exported here for the
-# existing tool callers.
-from paddle_tpu.kernels.registry import (  # noqa: F401
-    FLOOR_GBS, FLOOR_TFLOPS, gate_ms, plausible_ms)
+# Roofline anchors for the plausibility gate: the peaks-table row
+# (paddle_tpu.device.CHIP_PEAKS) of the one chip these tools have timed
+# kernels on. The gate prices that chip, not the device it runs on.
+GATE_CHIP = "TPU v5 lite"
+# Below these effective rates a kernel-sized timing is measuring the
+# host, not the chip — a sweep once persisted CE rows at 3.4-7.9 s for a
+# ~15 ms kernel, which this floor rejects.
+FLOOR_TFLOPS = 0.5
+FLOOR_GBS = 20.0
+
+
+def plausible_ms(flops: float = 0.0, bytes_moved: float = 0.0):
+    """Physical window (lo_ms, hi_ms) for ONE application of a kernel of
+    known arithmetic/memory volume. lo = half the roofline time (nothing
+    runs 2x faster than the roofline); hi = the time implied by the
+    FLOOR_* effective rates (anything slower is a measurement artifact,
+    not a slow kernel)."""
+    from paddle_tpu.device import chip_peaks
+    peaks = chip_peaks(GATE_CHIP)
+    lo_s = max(flops / peaks.flops, bytes_moved / peaks.hbm_bw) / 2.0
+    hi_s = max(flops / (FLOOR_TFLOPS * 1e12),
+               bytes_moved / (FLOOR_GBS * 1e9), 1e-6)
+    return lo_s * 1e3, hi_s * 1e3
+
+
+def gate_ms(ms: float, flops: float = 0.0, bytes_moved: float = 0.0):
+    """None if `ms` is physically plausible for the given volumes, else a
+    short reason string for the record."""
+    lo, hi = plausible_ms(flops, bytes_moved)
+    if ms < lo:
+        return f"implausibly fast: {ms:.3f} ms < {lo:.3f} ms (2x roofline)"
+    if ms > hi:
+        return (f"implausibly slow: {ms:.3f} ms > {hi:.1f} ms "
+                "(sub-floor effective rate; likely host-bound)")
+    return None
 
 
 def force(out):

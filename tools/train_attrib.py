@@ -45,9 +45,9 @@ if REPO not in sys.path:
 
 # a CPU-only tool unless --tpu is given: it pins 8 virtual CPU devices
 # (the mesh the plans need) before jax initializes, and a CPU run's
-# milliseconds are not device numbers. Script-mode only:
-# importers (tools/ablate_step.py's train_attrib variant, tests) own
-# their backend and must not have it re-pinned at import time.
+# milliseconds are not device numbers. Script-mode only: importers
+# (tests) own their backend and must not have it re-pinned at import
+# time.
 from paddle_tpu.device import pin_cpu            # noqa: E402
 if __name__ == "__main__" and "--tpu" not in sys.argv:
     pin_cpu(8)
