@@ -57,6 +57,7 @@ class Sizes:
     ce_shape: Tuple[int, int]                             # [T, V]
     adamw_leaf: Tuple[int, ...]
     qmm_shapes: Tuple[Tuple[int, int, int], ...]          # [M, K, N]
+    decode_pool: Tuple[int, int, int, int, int]           # [L, B, S, KV, hd]
 
 
 # the published widths: GPT-350M (24L x 1024d x 16 heads, hd 64) trains at
@@ -70,6 +71,7 @@ REAL = Sizes(
     ce_shape=(8 * 1024, 50304),
     adamw_leaf=(24, 1024, 4096),            # mlp_up_w, the largest leaf
     qmm_shapes=((256, 2048, 8192), (256, 8192, 2048)),
+    decode_pool=(4, 8, 1024, 16, 128),      # four layers of the serve pool
 )
 
 _TINY_GPT = dict(vocab_size=640, hidden_size=128, num_layers=2, num_heads=2,
@@ -85,6 +87,7 @@ TINY = Sizes(
     ce_shape=(256, 640),
     adamw_leaf=(2, 128, 512),
     qmm_shapes=((16, 256, 512), (16, 512, 256)),
+    decode_pool=(2, 4, 256, 8, 128),
 )
 
 # bf16 compute rounds to 8 bits of mantissa; losses of two layouts of one
@@ -327,6 +330,27 @@ def kernel_cases(sizes: Sizes):
                       quant_matmul._xla_quant_matmul,
                       (S((M, K), bf16), S((K, N), i8), S((N,), f32)),
                       2e-2))
+
+    # the decode attention's live blocks against the masked einsum over
+    # the whole layer; the int32 draw (0 .. hd - 1) spread over the pool
+    from paddle_tpu.kernels import decode_attention as da
+    L, B, P, KV, hd = sizes.decode_pool
+
+    def spread(draw):
+        return draw * 37 % P
+
+    def decode(kc, vc, q, draw):
+        return da.length_aware_attention(
+            q, kc, vc, jnp.int32(L - 1),
+            da.work_list(spread(draw), None, B, P))
+
+    def decode_oracle(kc, vc, q, draw):
+        return da.cached_attention(q, kc[L - 1], vc[L - 1], spread(draw),
+                                   impl="dense")
+
+    cases.append(("decode_live_blocks", decode, decode_oracle,
+                  (S(sizes.decode_pool, bf16),) * 2
+                  + (S((B, 1, KV, hd), bf16), S((B,), i32)), 1e-5))
     return cases
 
 

@@ -46,6 +46,7 @@ profiler/mem_audit diffs against XLA's `compiled.memory_analysis()`.
 """
 from __future__ import annotations
 
+import math
 
 class CostModel:
     """Reference CostModel shape: profile_measure(program) → cost dict."""
@@ -125,7 +126,8 @@ def serving_tick_ledger(cfg, family: str = "gpt",
                         num_slots: Optional[float] = None,
                         max_len: int = 0, page_size: int = 16,
                         max_pages: int = 0,
-                        dtype_bytes: int = 4) -> dict:
+                        dtype_bytes: int = 4,
+                        length_aware: bool = False) -> dict:
     """Per-phase FLOPs/bytes for ONE serving decode tick.
 
     The tick is FIXED-SHAPE: every one of the engine's `num_slots`
@@ -138,7 +140,12 @@ def serving_tick_ledger(cfg, family: str = "gpt",
     `attended` cache tokens (kernels/decode_attention.attended_tokens)
     — as the `*_useful`/`*_ideal` columns whose gap is the occupancy/
     masked-waste overhead an operator can act on. `num_slots` defaults
-    to `active` (a fully-occupied tick). Phases:
+    to `active` (a fully-occupied tick). `length_aware` says the tick
+    being priced takes the dense pool's length-aware attention kernel
+    (kernels/decode_attention.length_aware — a TPU's plain tick): its
+    attention then runs over the ACTIVE rows alone, each over its mean
+    context rounded up to whole blocks (`kv_view_extent(context=)`),
+    and the masked-waste gap shrinks to that rounding. Phases:
 
     - matmuls:  the stacked block matmuls — FLOPs scale with rows
       computed this tick; BYTES are the weight read (per device pass
@@ -175,6 +182,12 @@ def serving_tick_ledger(cfg, family: str = "gpt",
     view = kv_view_extent(layout == "paged", max_len, max_pages,
                           page_size)
     rows = float(num_slots) if num_slots else float(active)
+    # the rows whose cache the attention reads, and how far
+    kv_rows = rows
+    if length_aware and layout == "dense" and not spec:
+        kv_rows = float(active)
+        view = kv_view_extent(False, max_len, context=math.ceil(
+            attended / max(active, 1e-9)))
 
     T = (gamma + 1) if spec else 1            # verify-pass tokens/slot
     dL = int(draft_layers or max(1, L // 2)) if spec else 0
@@ -205,13 +218,13 @@ def serving_tick_ledger(cfg, family: str = "gpt",
     # useful S = the mask-admitted tokens of active rows.
     layer_passes = T + gamma * (dL / max(L, 1))
     attention = {
-        "flops": 4.0 * D * L * view * rows * layer_passes,
+        "flops": 4.0 * D * L * view * kv_rows * layer_passes,
         "bytes": 0.0,
         "flops_useful": 4.0 * D * L * attended * layer_passes,
     }
     # cache read: k+v over the full view per row per layer per pass
     # (drafts read their dL-layer slice of the same pool)
-    kv_bytes_pass = 2.0 * view * KV * hd * dtype_bytes * rows
+    kv_bytes_pass = 2.0 * view * KV * hd * dtype_bytes * kv_rows
     kv_gather = {
         "flops": 0.0,
         "bytes": kv_bytes_pass * (L + dL * n_draft_passes),

@@ -625,6 +625,20 @@ def _sample(lg, temps, top_ks, keys, max_top_k: int):
     return jnp.where(temps <= 0.0, greedy, sampled).astype(jnp.int32)
 
 
+def _traced_on(fwd, mesh):
+    """`fwd` with `mesh` ambient (parallel.mesh.use_mesh) while it is
+    traced: how a tensor-parallel engine's single-token forwards tell
+    the decode attention that their pool is sharded — GSPMD cannot
+    partition its Pallas kernel, so it keeps the einsum there."""
+    from ..parallel.mesh import use_mesh
+
+    @functools.wraps(fwd)
+    def on_mesh(*args, **kw):
+        with use_mesh(mesh):
+            return fwd(*args, **kw)
+    return on_mesh
+
+
 def _pin_cache(cache, pin):
     """Pin the returned cache leaves to their input NamedShardings
     (tensor-parallel serving, `mesh=`): GSPMD would usually propagate
@@ -647,7 +661,7 @@ def _pin_cache(cache, pin):
 #   (cur_tok, positions, active, temps, top_ks, req_ids, gen_idx)
 def _decode_tick(params, cache, state, base_key, poison, *, fwd, cfg,
                  max_top_k, sampling, guard, oor_pos=None,
-                 cache_pin=None, tele=False, counting=False):
+                 cache_pin=None, tele=False):
     """THE mixed step: all N slots advance one token. Each slot's
     current token is written at its own position; sampling runs in-jit;
     inactive slots compute too (fixed shape) but their output is masked
@@ -666,9 +680,11 @@ def _decode_tick(params, cache, state, base_key, poison, *, fwd, cfg,
     `tele` (static, baked per engine) additionally returns the
     TICK_FIELDS int32 row (profiler/serving_telemetry) computed from
     values the tick already holds — it rides the same host pull as
-    the token array and never touches the stream math. `counting`
-    (static, ModelFamily.counts) hands the forward the active mask as
-    `live=`: its counts leave idle slots out."""
+    the token array and never touches the stream math. The forward
+    is handed the active mask as `live=`: a family's counts leave idle
+    slots out, and the dense pool's decode attention reads nothing for
+    them (kernels/decode_attention.py) — no output of a live row
+    depends on it."""
     toks, positions, active, temps, top_ks, req_ids, gen_idx = state
     # under the paged layout the pool is SHARED across rows, so an
     # inactive row (mid-chunked-prefill, its table already mapping
@@ -679,7 +695,7 @@ def _decode_tick(params, cache, state, base_key, poison, *, fwd, cfg,
     fpos = (positions if oor_pos is None
             else jnp.where(active, positions, oor_pos))
     logits, cache = fwd(params, toks[:, None], cache, fpos, cfg,
-                        **({"live": active[:, None]} if counting else {}))
+                        live=active[:, None])
     lg = logits[:, 0].astype(jnp.float32)
     if guard:
         lg = lg * poison[:, None]
@@ -1297,11 +1313,14 @@ class ServingEngine:
             return cached
         run_cfg = self._run_cfg
         _oor = (self.max_pages * self.page_size if self.paged else None)
+        fwd = self.family.forward_cached
+        if self.tp > 1:
+            fwd = _traced_on(fwd, self.mesh)
         if self.mt_k > 1 and spec:
             from .multi_tick import multi_tick_spec_scan
             fn = jax.jit(
                 functools.partial(multi_tick_spec_scan,
-                                  fwd=self.family.forward_cached,
+                                  fwd=fwd,
                                   cfg=run_cfg, max_top_k=self.max_top_k,
                                   guard=self.guardrails,
                                   gamma=self.spec_gamma,
@@ -1316,7 +1335,7 @@ class ServingEngine:
             from .multi_tick import multi_tick_scan
             fn = jax.jit(
                 functools.partial(multi_tick_scan,
-                                  fwd=self.family.forward_cached,
+                                  fwd=fwd,
                                   cfg=run_cfg, max_top_k=self.max_top_k,
                                   guard=self.guardrails,
                                   k_ticks=self.mt_k,
@@ -1329,7 +1348,7 @@ class ServingEngine:
             from .spec_decode import spec_tick
             fn = jax.jit(
                 functools.partial(spec_tick,
-                                  fwd=self.family.forward_cached,
+                                  fwd=fwd,
                                   cfg=run_cfg, max_top_k=self.max_top_k,
                                   guard=self.guardrails,
                                   gamma=self.spec_gamma,
@@ -1341,12 +1360,11 @@ class ServingEngine:
         else:
             fn = jax.jit(
                 functools.partial(_decode_tick,
-                                  fwd=self.family.forward_cached,
+                                  fwd=fwd,
                                   cfg=run_cfg, max_top_k=self.max_top_k,
                                   guard=self.guardrails, oor_pos=_oor,
                                   cache_pin=self._cache_pin,
-                                  tele=self._tick_tele,
-                                  counting=bool(self.family.counts)),
+                                  tele=self._tick_tele),
                 donate_argnums=(1, 2), static_argnames=("sampling",))
         self._decode_variants[bool(spec)] = fn
         return fn
@@ -2101,7 +2119,8 @@ class ServingEngine:
                 poison_slot = None        # injected at most once
                 with RecordEvent("serving.decode_tick",
                                  active=int(self._active.sum()),
-                                 slots=self.num_slots) as dev:
+                                 slots=self.num_slots,
+                                 **self._kv_read_counts()) as dev:
                     if self.spec:
                         dpoison = self._poison_ones
                         if draft_slot is not None:
@@ -2198,6 +2217,39 @@ class ServingEngine:
             # download, and the device state stays clean unless an
             # eviction dirties it
             self._emit_token(i, req, tok, events, tick_now)
+
+    def _kv_read_counts(self) -> dict:
+        """The `serving.decode_tick` span's two KV counts for a family
+        with the one uniform pool: `kv_positions_pool`, the positions
+        its layers hold (layers x slots x the view a row attends), and
+        `kv_positions_read`, those the tick's attention may touch —
+        where the plain tick's forward takes the length-aware kernel
+        (`decode_attention.length_aware`) each live row's blocks, from
+        the mirrors of the positions and the mask the tick is handed;
+        on the einsum path (paged, a `tp` mesh, off a TPU, and the spec and
+        multi-tick bodies, whose verify pass and later steps this does
+        not follow) the whole pool. A family that counts its own pools
+        (`ModelFamily.counts`) reports those instead."""
+        if self.family.counts:
+            return {}
+        from ..kernels.decode_attention import kv_positions_read
+        view = (self.max_pages * self.page_size if self.paged
+                else self.max_len)
+        layers = self._cache["k"].shape[0]
+        return {"kv_positions_read": layers * kv_positions_read(
+                    self._positions, self._active, view,
+                    self.length_aware_tick()),
+                "kv_positions_pool": layers * self.num_slots * view}
+
+    def length_aware_tick(self) -> bool:
+        """Whether this engine's decode tick is the plain one over the
+        dense pool whose attention takes the length-aware kernel
+        (`decode_attention.length_aware`): what the span's counts and
+        tools/serving_attrib.py price the tick's KV read by."""
+        from ..kernels.decode_attention import length_aware
+        return (not self.paged and self.tp == 1 and not self.spec
+                and self.mt_k == 1 and not self.family.counts
+                and length_aware(1, self._cache["k"]))
 
     def _upload_dirty(self) -> None:
         """Rebuild whatever the host mirrors dirtied since the last tick

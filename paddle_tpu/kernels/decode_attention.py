@@ -6,8 +6,10 @@ Reference analog: the FusedMultiTransformer decode attention
 masked single-step branch) reached via
 incubate/nn/layer/fused_transformer.py:1022. TPU-native collapse: at
 T=1 the attention is a bandwidth-bound matvec over the cache — flash
-tiling buys nothing — so the implementations here are dense masked
-einsums; what stays selectable is the precision trade.
+tiling buys nothing, what it reads is the whole cost — so the forms
+here are dense masked einsums and, for the serving pool's single-token
+step, a kernel that reads only what is live; what stays selectable is
+the precision trade.
 
 One implementation serves BOTH cache-position shapes:
 - scalar `pos` — the whole batch sits at one position (whole-batch
@@ -23,13 +25,25 @@ One implementation serves BOTH cache-position shapes:
 The cached forwards (models/gpt.py, models/llama.py) hold the cache as
 ONE stacked pool [L, ...] per k/v and carry it whole through their
 layer scan: `write_kv` / `write_kv_paged` with `layer=` write only the
-step's new rows at [layer, ...], in place, and `layer_view` reads the
-layer back for the attention — the pool is never sliced into per-layer
-buffers nor restacked (a 3.2 GB pool moved ~5x a tick that way).
+step's new rows at [layer, ...], in place, and the attention reads the
+layer back out of the same pool (`layer_view`, or the kernel's own
+[layer, row, block] addressing) — the pool is never sliced into
+per-layer buffers nor restacked (a 3.2 GB pool moved ~5x a tick that
+way).
 
 GQA is native: kc/vc carry KV heads; queries fold their group axis into
 the einsum so repeated KV is never materialized (models/llama.py's
 decode-bandwidth trade).
+
+Handed the STACKED pool, the layer and a plan
+(`cached_attention(layer=, plan=)`, the cached forwards' form over the
+dense pool), a single-token step on a TPU does not read the layer's
+whole [B, S] view: `length_aware` says when `live_block_plan` makes the
+plan and the Pallas kernel below runs, which fetches, per slot, only
+the blocks of DECODE_BLOCK positions that slot has written — the
+einsum reads every position of every slot and masks afterwards, and in
+a serving pool most of them are dead. Same arithmetic class as 'dense';
+only the order of summation differs.
 
 The attention math is one of two, chosen by DECODE_ATTN_IMPL below
 (`cached_attention(impl=)` overrides it for one call):
@@ -54,15 +68,29 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from ..utils.compile_cache import bytecode_cache
+from .primitives import NEG_INF, cdiv, round_up
+
+with bytecode_cache():      # every cached forward imports this module
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["write_kv", "cached_attention", "gather_pages",
            "write_kv_paged", "layer_view", "cache_pspecs",
            "attended_tokens", "kv_view_extent", "ring_positions",
-           "ring_rows", "blocked_attention"]
+           "ring_rows", "blocked_attention", "DECODE_BLOCK",
+           "kv_positions_read", "length_aware", "length_aware_attention",
+           "live_block_plan", "work_list"]
 
 # The decode attention math, 'dense' | 'mixed' (module docstring).
 # ROADMAP S5 decides between them in the GPT serving cells and keeps one.
 DECODE_ATTN_IMPL = "dense"
+
+# Positions a block of the length-aware kernel holds: what one DMA
+# fetches, and the grain a slot's read is rounded up to.
+DECODE_BLOCK = 128
 
 
 def cache_pspecs(paged: bool, tp_axis: str = "tp"):
@@ -211,14 +239,23 @@ def attended_tokens(positions, active):
 
 
 def kv_view_extent(paged: bool, max_len: int, max_pages: int = 0,
-                   page_size: int = 0) -> int:
+                   page_size: int = 0, context: int | None = None) -> int:
     """Host-side: the per-row cache positions one decode-attention call
-    actually READS — the dense pool attends its whole [*, max_len]
-    row under the mask, and the paged gather materializes the full
+    actually READS. The paged gather materializes the full
     [*, max_pages * page_size] table view (unmapped entries hit the
-    scratch page but their bytes still move). The cost-model's
-    KV-gather phase prices against this, not against live tokens."""
-    return max_pages * page_size if paged else max_len
+    scratch page but their bytes still move). The dense pool under the
+    masked einsum — off a TPU, the verify pass, a `tp` mesh — attends
+    its whole [*, max_len] row; under the length-aware kernel (a
+    single-token step on a TPU, `length_aware`) a live row of `context`
+    positions reads them rounded up to whole blocks of DECODE_BLOCK,
+    and an idle row nothing: hand `context` where that kernel runs. The
+    cost-model's KV-gather phase prices against this, not against live
+    tokens."""
+    if paged:
+        return max_pages * page_size
+    if context is None:
+        return max_len
+    return min(max_len, round_up(int(context), DECODE_BLOCK))
 
 
 def _query_positions(pos, B, T):
@@ -250,11 +287,19 @@ def ring_rows(true_len, ring: int):
 
 
 def cached_attention(q, kc, vc, pos, impl: str | None = None,
-                     window: int | None = None):
+                     window: int | None = None, layer=None, plan=None):
     """Masked attention of q [B, T, H, hd] against the cache kc/vc
     [B, S, KV, hd]; query t of row b sits at absolute position
     `pos[b] + t` (pos scalar or [B]) and sees cache slots <= that
     position. Returns ctx [B, T, H, hd] float32 (callers cast).
+
+    With `layer` (a traced index) kc/vc are the STACKED pools
+    [L, B, S, KV, hd] as the cached forwards carry them. With a `plan`
+    (`live_block_plan`, made once a forward where `length_aware`
+    holds) a Pallas kernel reads each row's live blocks straight out of
+    [layer, row] and nothing else — a row that is no request reads
+    nothing and returns zeros; without one this is `layer_view` and the
+    einsum.
 
     Slots above the row's own position are masked to -inf before the
     softmax, so stale cache contents (a freed slot's previous request,
@@ -268,6 +313,10 @@ def cached_attention(q, kc, vc, pos, impl: str | None = None,
     with float32 accumulation and a float32 softmax (impl 'native'): a
     float32 copy of a 16k-position layer would not fit beside it."""
     B, T, H, hd = q.shape
+    if plan is not None:
+        return length_aware_attention(q, kc, vc, layer, plan)
+    if layer is not None:
+        kc, vc = layer_view(kc, layer), layer_view(vc, layer)
     S, KV = kc.shape[1], kc.shape[2]
     G = H // KV
     if impl == "native":
@@ -293,6 +342,178 @@ def cached_attention(q, kc, vc, pos, impl: str | None = None,
     ctx = jnp.einsum("bkgts,bskd->btkgd", p.astype(dot_dt)
                      if impl == "mixed" else p, vc.astype(dot_dt))
     return ctx.reshape(B, T, H, hd).astype(jnp.float32)
+
+
+def length_aware(T: int, pool) -> bool:
+    """Whether a call on the stacked dense pool [L, B, S, KV, hd] takes
+    the length-aware kernel — by what the call can observe, no option:
+    one query token a row (the verify pass and prefill keep the
+    einsum), a TPU, no ambient mesh of several devices (GSPMD cannot
+    partition a Mosaic kernel; a tensor-parallel engine traces its
+    forwards under `parallel.mesh.use_mesh`), and a pool the kernel's
+    tiles fit: S a whole number of blocks, whole sublanes of KV heads
+    and whole lanes of hd."""
+    from ..device import is_tpu
+    from ..parallel.mesh import get_mesh
+    mesh = get_mesh()
+    S, KV, hd = pool.shape[2:]
+    return (T == 1 and is_tpu() and (mesh is None or mesh.size == 1)
+            and S % DECODE_BLOCK == 0 and KV % 8 == 0 and hd % 128 == 0)
+
+
+def kv_positions_read(positions, active, max_len: int,
+                      live_blocks: bool) -> int:
+    """Host-side: the cache positions ONE layer's decode attention may
+    touch this tick, from the lengths the kernel is handed — per live
+    row its `positions + 1` rounded up to whole blocks, nothing for an
+    idle row (`live_blocks`: the kernel's work list); on the einsum
+    path every position of every row, so the share of the pool reads
+    100% where the kernel does not run."""
+    if not live_blocks:
+        return int(np.size(positions)) * int(max_len)
+    n = np.where(active, np.minimum(np.asarray(positions) + 1, max_len), 0)
+    return int(cdiv(n, DECODE_BLOCK).sum()) * DECODE_BLOCK
+
+
+def work_list(pos, live, B: int, S: int):
+    """The length-aware kernel's scalar operands, from the rows'
+    positions (scalar or [B]) and `live` [B] (None: every row) ->
+    (n [B] positions row b may see — `pos + 1`, 0 for a row that is no
+    request —, slot and block [B * S // DECODE_BLOCK] of work item t,
+    total [1] items). Row b's blocks 0 .. cdiv(n[b], block) - 1, row by
+    row; entries past `total` are never read."""
+    nb = S // DECODE_BLOCK
+    n = jnp.minimum(jnp.broadcast_to(pos, (B,)).astype(jnp.int32) + 1, S)
+    if live is not None:
+        n = jnp.where(live, n, 0)
+    blocks = cdiv(n, DECODE_BLOCK)
+    ends = jnp.cumsum(blocks)
+    t = jnp.arange(B * nb, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.sum(t[:, None] >= ends[None, :], axis=1), B - 1
+                       ).astype(jnp.int32)
+    blk = jnp.clip(t - (ends - blocks)[slot], 0, nb - 1).astype(jnp.int32)
+    return n, slot, blk, ends[-1:].astype(jnp.int32)
+
+
+def live_block_plan(T: int, pool, pos, live=None):
+    """What a cached forward hands `cached_attention(plan=)`, made ONCE
+    ahead of its layer scan (inside it XLA would redo the small index
+    arithmetic every layer): the kernel's work list where the step's
+    attention is the length-aware kernel (`length_aware`, and the
+    `dense` math it computes is the one selected), else None — the
+    einsum. `live` [B, T] is the forwards' mask of real tokens."""
+    if DECODE_ATTN_IMPL != "dense" or not length_aware(T, pool):
+        return None
+    return work_list(pos, None if live is None else live[:, 0],
+                     pool.shape[1], pool.shape[2])
+
+
+def _decode_kernel(layer_ref, len_ref, slot_ref, blk_ref, total_ref,
+                   q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
+                   m_ref, l_ref, acc_ref):
+    """One pass over the work list (slot_ref[t], blk_ref[t]), t <
+    total: the (row, block) pairs that hold a live position, row by
+    row. Block t+1 is in flight from the pool in HBM while block t is
+    computed (two buffers); a row's running max, sum and context sit in
+    scratch from its first block to its last, which writes the row
+    out. Rows with nothing live keep the zeros written first."""
+    layer, total = layer_ref[0], total_ref[0]
+    G, block = q_ref.shape[1], kbuf.shape[1]
+
+    def fetch(t, buf):
+        at = (layer, slot_ref[t], pl.ds(blk_ref[t] * block, block))
+        return (pltpu.make_async_copy(k_hbm.at[at], kbuf.at[buf],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[at], vbuf.at[buf],
+                                      sem.at[1, buf]))
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(total > 0)
+    def _():
+        for c in fetch(0, 0):
+            c.start()
+
+    def step(t, _):
+        buf = t % 2
+        b, j = slot_ref[t], blk_ref[t]
+        n = len_ref[b]
+
+        @pl.when(t + 1 < total)
+        def _():
+            for c in fetch(t + 1, 1 - buf):
+                c.start()
+
+        for c in fetch(t, buf):
+            c.wait()
+
+        @pl.when(j == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        kf = kbuf[buf].astype(jnp.float32)              # [block, KV, hd]
+        vf = vbuf[buf].astype(jnp.float32)
+        seen = (j * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, kf.shape[1], 1), 0)) < n
+        # what lies past the row's length is another request's, or
+        # nothing yet: it must not reach the sums even as 0 * nan
+        vf = jnp.where(seen, vf, 0.0)
+        for g in range(G):
+            s = jnp.sum(kf * q_ref[b, g][None], axis=-1, keepdims=True)
+            s = jnp.where(seen, s, NEG_INF)             # [block, KV, 1]
+            m_prev = m_ref[g]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new[None])
+            shrink = jnp.exp(m_prev - m_new)            # [KV, 1]
+            l_ref[g] = l_ref[g] * shrink + jnp.sum(p, axis=0)
+            acc_ref[g] = acc_ref[g] * shrink + jnp.sum(p * vf, axis=0)
+            m_ref[g] = m_new
+
+        @pl.when((j + 1) * block >= n)
+        def _():
+            o_ref[b] = acc_ref[...] / l_ref[...]
+
+    jax.lax.fori_loop(0, total, step, None)
+
+
+def length_aware_attention(q, kc, vc, layer, plan, interpret: bool = False):
+    """Single-token attention of q [B, 1, H, hd] against layer `layer`
+    of the stacked pools kc/vc [L, B, S, KV, hd], reading per row only
+    the blocks `plan` (`work_list`) lists for it -> ctx [B, 1, H, hd]
+    float32. The arithmetic of 'dense': the cache's K and V widened to
+    float32 in VMEM, float32 scores, softmax statistics, probabilities
+    and context — in blocks with a running softmax, so only the order
+    of summation differs. The pools stay where they are (HBM; `layer`
+    and the plan are scalar-prefetch operands, the kernel addresses
+    [layer, row, block] itself): handed `layer_view` XLA would first
+    copy the layer out of the scan's carry."""
+    B, _, H, hd = q.shape
+    KV = kc.shape[3]
+    G = H // KV
+    qf = (q.reshape(B, KV, G, hd).astype(jnp.float32)
+          * jnp.float32(1.0 / math.sqrt(hd))).swapaxes(1, 2)
+    whole = pl.BlockSpec((B, G, KV, hd), lambda i, *_: (0, 0, 0, 0))
+    ctx = pl.pallas_call(
+        _decode_kernel,
+        out_shape=jax.ShapeDtypeStruct((B, G, KV, hd), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(1,),
+            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((2, DECODE_BLOCK, KV, hd), kc.dtype),
+                pltpu.VMEM((2, DECODE_BLOCK, KV, hd), vc.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((G, KV, 1), jnp.float32),
+                pltpu.VMEM((G, KV, 1), jnp.float32),
+                pltpu.VMEM((G, KV, hd), jnp.float32)]),
+        name="decode_attention_live_blocks",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), *plan, qf, kc, vc)
+    return ctx.swapaxes(1, 2).reshape(B, 1, H, hd)
 
 
 def _native_attention(q, kc, vc, pos, window):

@@ -694,9 +694,9 @@ GPT3_CONFIGS = {
 # layer writes only the step's new rows at [layer, ...] in place and reads
 # its own rows back for the attention, so no layer's cache is sliced out or
 # restacked. Prefill writes the prompt's k/v while running the causal
-# forward, decode steps are single-token dense attention over the cache (a
-# bandwidth-bound matvec — flash tiling buys nothing at T=1, and dense
-# masking keeps kv_len dynamic under jit).
+# forward, decode steps are single-token attention over the cache (a
+# bandwidth-bound matvec — flash tiling buys nothing at T=1; masking, or
+# on a TPU the per-row live length, keeps kv_len dynamic under jit).
 # --------------------------------------------------------------------------
 def init_kv_cache(cfg: GPTConfig, batch: int, max_len: int):
     """→ {"k","v": [L, B, max_len, H, hd]} in the activation dtype."""
@@ -705,7 +705,8 @@ def init_kv_cache(cfg: GPTConfig, batch: int, max_len: int):
             "v": jnp.zeros(shape, cfg.dtype)}
 
 
-def _cached_attention(x, params_l, layer, kc, vc, pos, cfg, pt=None):
+def _cached_attention(x, params_l, layer, kc, vc, pos, cfg, pt=None,
+                      plan=None):
     """Block `layer`'s attention with cache update. x [B,T,D]; kc/vc
     the STACKED pools [L,B,max_len,H,hd] (dense) or [L,P,page_size,H,hd]
     pages with the per-slot page table `pt` [B,max_pages] (the serving
@@ -717,7 +718,10 @@ def _cached_attention(x, params_l, layer, kc, vc, pos, cfg, pt=None):
     masked attention go through the decode-attention seam
     (kernels/decode_attention.py);
     the paged path scatters the write through the table and attends a
-    gathered per-slot view — bit-identical to the dense layout."""
+    gathered per-slot view — bit-identical to the dense layout. The
+    dense path hands the seam the pools whole with `layer` and the
+    forward's `plan` (`live_block_plan`): where there is one, the
+    attention reads only each row's live blocks."""
     B, T, D = x.shape
     H, hd = cfg.num_heads, cfg.head_dim
     from ..kernels.quant_matmul import leaf_matmul
@@ -738,8 +742,11 @@ def _cached_attention(x, params_l, layer, kc, vc, pos, cfg, pt=None):
             kc = write_kv_paged(kc, pt, k, pos, layer)
             vc = write_kv_paged(vc, pt, v, pos, layer)
     with jax.named_scope("decode_attention"):
-        ctx = cached_attention(q, layer_view(kc, layer, pt),
-                               layer_view(vc, layer, pt), pos)
+        if pt is None:
+            ctx = cached_attention(q, kc, vc, pos, layer=layer, plan=plan)
+        else:
+            ctx = cached_attention(q, layer_view(kc, layer, pt),
+                                   layer_view(vc, layer, pt), pos)
     ctx = ctx.reshape(B, T, D).astype(x.dtype)
     out = leaf_matmul(ctx, params_l, "attn_out_w")
     if params_l.get("attn_out_b") is not None:
@@ -748,7 +755,7 @@ def _cached_attention(x, params_l, layer, kc, vc, pos, cfg, pt=None):
 
 
 def gpt_forward_cached(params, tokens, cache, pos, cfg: GPTConfig,
-                       layers: Optional[int] = None):
+                       layers: Optional[int] = None, live=None):
     """Forward `tokens` [B,T] against a cache holding `pos` tokens.
     → (logits [B,T,V], updated cache). Works for prefill (pos=0, T=prompt)
     and decode (T=1), for dense and MoE configs (reference: the inference
@@ -772,7 +779,12 @@ def gpt_forward_cached(params, tokens, cache, pos, cfg: GPTConfig,
     "pt": [B, max_pages]} — the page table rides the cache dict and is
     returned unchanged; the per-layer write/attend goes through the
     paged seam (kernels/decode_attention.py) and is bit-identical to
-    the dense layout."""
+    the dense layout.
+
+    `live` [B, T] bool marks the rows that are requests (the serving
+    tick's active mask): nothing here computes differently for it — it
+    only tells the dense pool's decode attention which rows have
+    nothing to read."""
     B, T = tokens.shape
     pt = cache.get("pt")
     with jax.named_scope("embed"):
@@ -803,7 +815,10 @@ def gpt_forward_cached(params, tokens, cache, pos, cfg: GPTConfig,
     if layers is not None:
         stacked = {k: v[:layers] for k, v in stacked.items()}
         n_layers = int(layers)
+    from ..kernels.decode_attention import live_block_plan
     from ..kernels.quant_matmul import leaf_matmul, quant_matmul
+    plan = None if pt is not None else live_block_plan(
+        T, cache["k"], pos, live)
 
     def scan_fn(carry, layer_in):
         h, kc, vc = carry
@@ -812,7 +827,7 @@ def gpt_forward_cached(params, tokens, cache, pos, cfg: GPTConfig,
             a_in = _ln(h, params_l["ln1_scale"], params_l["ln1_bias"],
                        cfg.layer_norm_eps)
             a, kc, vc = _cached_attention(a_in, params_l, layer, kc, vc,
-                                          pos, cfg, pt=pt)
+                                          pos, cfg, pt=pt, plan=plan)
         h = h + a
         with jax.named_scope("mlp"):
             m_in = _ln(h, params_l["ln2_scale"], params_l["ln2_bias"],

@@ -242,7 +242,7 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int):
 
 
 def llama_forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
-                         layers: Optional[int] = None):
+                         layers: Optional[int] = None, live=None):
     """Forward tokens [B,T] against a cache holding `pos` tokens ->
     (logits [B,T,V], updated cache). Prefill (pos=0) and decode (T=1)
     share the graph; RoPE is applied at the absolute positions. `pos`
@@ -258,7 +258,8 @@ def llama_forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
     layouts: dense {"k","v": [L, B, max_len, KV, hd]} or the serving
     engine's paged pool {"k","v": [L, P, page_size, KV, hd], "pt":
     [B, max_pages]} — same contract as models/gpt.py, bit-identical
-    across layouts."""
+    across layouts. `live` [B, T] as in models/gpt.py: which rows are
+    requests, for the dense pool's decode attention alone."""
     B, T = tokens.shape
     pt = cache.get("pt")
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -292,8 +293,11 @@ def llama_forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
         stacked = {k: v[:layers] for k, v in stacked.items()}
         n_layers = int(layers)
     from ..kernels.decode_attention import (cached_attention, layer_view,
-                                            write_kv, write_kv_paged)
+                                            live_block_plan, write_kv,
+                                            write_kv_paged)
     from ..kernels.quant_matmul import leaf_matmul, quant_matmul
+    plan = None if pt is not None else live_block_plan(
+        T, cache["k"], pos, live)
 
     def scan_fn(carry, layer_in):
         x, kc, vc = carry
@@ -310,8 +314,11 @@ def llama_forward_cached(params, tokens, cache, pos, cfg: LlamaConfig,
         else:
             kc = write_kv_paged(kc, pt, k, pos, layer)
             vc = write_kv_paged(vc, pt, v, pos, layer)
-        ctx = cached_attention(q, layer_view(kc, layer, pt),
-                               layer_view(vc, layer, pt), pos)
+        if pt is None:
+            ctx = cached_attention(q, kc, vc, pos, layer=layer, plan=plan)
+        else:
+            ctx = cached_attention(q, layer_view(kc, layer, pt),
+                                   layer_view(vc, layer, pt), pos)
         ctx = ctx.reshape(B, T, H * hd).astype(x.dtype)
         x = x + leaf_matmul(ctx, lp, "o_w")
         h = _rmsnorm(x, lp["ffn_norm"], cfg.rms_eps)

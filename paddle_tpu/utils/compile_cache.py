@@ -20,13 +20,38 @@ program's own choice is withdrawn again once the backend is known.
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import sys
 
-__all__ = ["xla_cache_dir", "seed_cache_env", "sync_compile_cache_for"]
+__all__ = ["xla_cache_dir", "seed_cache_env", "sync_compile_cache_for",
+           "bytecode_cache"]
 
-_CHECKOUT_CACHE = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), "perf", "xla_cache")
+_PERF = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "perf")
+_CHECKOUT_CACHE = os.path.join(_PERF, "xla_cache")
+
+
+@contextlib.contextmanager
+def bytecode_cache():
+    """Imports made inside read and write their bytecode under
+    `perf/pycache` of the checkout (ignored by git), whatever the
+    interpreter was told about bytecode; the two settings are put back
+    on the way out. For ONE import the serving path cannot do without
+    and cannot make smaller: `jax.experimental.pallas` is some 140
+    modules (Mosaic for GPUs among them), and an installation that
+    ships no `.pyc` and runs with PYTHONDONTWRITEBYTECODE compiles
+    their source in every process — 0.8 of the import's 1.35 s on the
+    chip's host, inside every engine's set-up (PERF.md section 6,
+    PR 34). Like the XLA cache beside it, only a checkout's first
+    process pays."""
+    prior = sys.pycache_prefix, sys.dont_write_bytecode
+    sys.pycache_prefix = os.path.join(_PERF, "pycache")
+    sys.dont_write_bytecode = False
+    try:
+        yield
+    finally:
+        sys.pycache_prefix, sys.dont_write_bytecode = prior
 
 
 def xla_cache_dir() -> str:

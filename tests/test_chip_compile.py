@@ -37,7 +37,8 @@ SIZES = chip_smoke.REAL
 # chip_smoke.kernel_cases(SIZES) is checked against this list in the test
 KERNELS = ["flash_fwd_hd64", "flash_bwd_hd64", "flash_fwd_hd128",
            "flash_bwd_hd128", "jax_flash", "splash", "ce", "ce_fused",
-           "fused_adamw", "quant_matmul_k2048", "quant_matmul_k8192"]
+           "fused_adamw", "quant_matmul_k2048", "quant_matmul_k8192",
+           "decode_live_blocks"]
 
 
 @pytest.fixture(scope="module")
@@ -162,7 +163,8 @@ def _lower_decode_tick(topo, tp, slots, max_len, page_size=0):
     chips the way ServingEngine(mesh=) places it, over the dense pool or
     — with `page_size` — the paged one. -> (compiled, pool shape, the
     parameter tree's shapes)."""
-    from paddle_tpu.inference.serving import _decode_tick, family_for
+    from paddle_tpu.inference.serving import (_decode_tick, _traced_on,
+                                              family_for)
     from paddle_tpu.kernels.decode_attention import cache_pspecs
     from paddle_tpu.models.gpt import init_gpt_params
     from paddle_tpu.parallel.mesh import sharding_for
@@ -203,8 +205,11 @@ def _lower_decode_tick(topo, tp, slots, max_len, page_size=0):
     state = tuple(rep_of((n,), dt) for dt in (
         jnp.int32, jnp.int32, jnp.bool_, jnp.float32, jnp.int32,
         jnp.int32, jnp.int32))
+    # a tensor-parallel engine traces its forwards with its mesh ambient
+    fwd = _traced_on(fam.forward_cached, mesh) if tp > 1 \
+        else fam.forward_cached
     tick = jax.jit(
-        functools.partial(_decode_tick, fwd=fam.forward_cached, cfg=cfg,
+        functools.partial(_decode_tick, fwd=fwd, cfg=cfg,
                           max_top_k=0, guard=True, oor_pos=oor_pos,
                           cache_pin=pin, tele=True),
         donate_argnums=(1, 2), static_argnames=("sampling",))
@@ -251,7 +256,27 @@ def test_gpt_1p3b_dense_decode_tick_moves_no_pool(topo, as_tpu):
     assert [ln.strip()[:160] for ln in compiled.as_text().splitlines()
             if " convert(" in ln
             and any(f"= {w}" in ln for w in weights)] == []
-    assert ma.temp_size_in_bytes < 0.5e9
+    assert ma.temp_size_in_bytes < 2e6
+    assert _device_bytes(compiled) < HBM_BYTES
+    # the attention is the length-aware kernel on the pool as carried:
+    # nothing slices or copies a layer (134 MB) out of it first, and the
+    # masked score fusion over all 1024 positions is gone
+    text = compiled.as_text()
+    assert "decode_attention_live_blocks" in text
+    layer = "bf16[" + ",".join(map(str, (1,) + pool[1:])) + "]"
+    assert [ln.strip()[:160] for ln in text.splitlines()
+            if f"= {layer}" in ln or f"= {layer.replace('[1,', '[')}" in ln
+            ] == []
+    assert "f32[16,1024,16]" not in text
+
+
+def test_gpt_1p3b_dense_decode_tick_keeps_the_einsum_under_tp(topo, as_tpu):
+    """Sharded four ways the way ServingEngine(mesh=) places it, the same
+    tick holds no Mosaic kernel (GSPMD cannot partition one) and still
+    moves no pool."""
+    compiled, pool, _ = _lower_decode_tick(topo, 4, slots=16, max_len=1024)
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert _pool_movers(compiled, pool, 4) == []
     assert _device_bytes(compiled) < HBM_BYTES
 
 

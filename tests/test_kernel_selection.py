@@ -113,6 +113,13 @@ SITES = {
     "decode_attention": (
         lambda m: (da.DECODE_ATTN_IMPL, _engine(m).paged),
         {"tpu": ("dense", False), "cpu": ("dense", False)}),
+    # the benchmark's serving pool, one query token a row
+    "decode_attention_kernel": (
+        lambda m: (da.length_aware(1, jax.ShapeDtypeStruct(
+            (24, 16, 1024, 16, 128), jnp.bfloat16)),
+                   da.length_aware(5, jax.ShapeDtypeStruct(
+                       (24, 16, 1024, 16, 128), jnp.bfloat16))),
+        {"tpu": (True, False), "cpu": (False, False)}),
     "quant_matmul": (lambda m: (qm.resolve_quant("auto"),
                                 qm.matmul_impl()),
                      {"tpu": (False, "xla"), "cpu": (False, "xla")}),

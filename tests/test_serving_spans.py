@@ -146,6 +146,28 @@ def test_one_step_is_one_tree_of_spans_with_counts(gpt_setup, kind):
     assert all(len(r.tokens) == GEN for r in reqs)
 
 
+@pytest.mark.parametrize("kind", list(ENGINES))
+def test_on_the_einsum_path_the_kv_read_is_the_whole_pool(gpt_setup, kind):
+    """`kv_positions_read` / `kv_positions_pool` on every decode tick
+    (docs/observability.md): off a TPU — and paged, speculative or
+    multi-tick anywhere — the attention is the masked einsum over every
+    position of every row, so the two are equal and
+    `kv_read_share_of_pool.serve` reads 100%. The kernel's block
+    arithmetic is pinned in tests/test_decode_attention_kernel.py."""
+    cfg, _ = gpt_setup
+    router = _router(gpt_setup, kind)
+    eng = router.replicas[0].eng
+    assert not eng.length_aware_tick()
+    clear_profiler_spans()
+    _serve(router, _prompts())
+    ticks = [s.counts for s in get_profiler_spans()
+             if s.name == "serving.decode_tick"]
+    view = eng.max_pages * eng.page_size if kind == "paged" else MAX_LEN
+    assert ticks and all(
+        c["kv_positions_read"] == c["kv_positions_pool"]
+        == cfg.num_layers * SLOTS * view for c in ticks)
+
+
 def test_span_durations_feed_the_telemetry_records(gpt_setup):
     """`ServingTelemetry`'s dur_ms is the span's own duration: no second
     clock pair beside the RecordEvent."""

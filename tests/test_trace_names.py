@@ -108,6 +108,16 @@ def _quant():
         jnp.zeros((128,), jnp.float32))
 
 
+def _decode_live_blocks():
+    from paddle_tpu.kernels import decode_attention as da
+    pool = jnp.zeros((2, 2, da.DECODE_BLOCK, 8, 128), jnp.bfloat16)
+    q = jnp.zeros((2, 1, 8, 128), jnp.bfloat16)
+    return jax.make_jaxpr(lambda q, pool: da.length_aware_attention(
+        q, pool, pool, jnp.int32(1),
+        da.work_list(jnp.int32(5), None, 2, da.DECODE_BLOCK),
+        interpret=True))(q, pool)
+
+
 KERNELS = {
     "flash_fwd": _flash_fwd,
     "flash_bwd_dq": _flash_bwd,
@@ -117,6 +127,7 @@ KERNELS = {
     "ce_bwd": functools.partial(_ce, "ce_bwd"),
     "adamw_update": _adamw,
     "quant_matmul": _quant,
+    "decode_attention_live_blocks": _decode_live_blocks,
 }
 
 

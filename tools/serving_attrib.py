@@ -100,7 +100,8 @@ def measure_layout(name, params, cfg, prompts, gen, max_len,
         active=mean_active, attended=mean_attended,
         num_slots=eng.num_slots,     # the tick computes EVERY row
         max_len=eng.max_len, page_size=eng.page_size,
-        max_pages=getattr(eng, "max_pages", 0))
+        max_pages=getattr(eng, "max_pages", 0),
+        length_aware=eng.length_aware_tick())
     roof = roofline_attribution(ledger, peak_flops=peak_flops,
                                 hbm_bw=hbm_bw)
     measured_ms = _pct(dur, 50)
