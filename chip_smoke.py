@@ -14,6 +14,9 @@ random, from --seed):
   4. serve    GPT-1.3B ServingEngine behind create_router(replicas=1):
               dense vs paged vs speculative streams, a reference decode,
               trace ceilings, a weight-only int8 engine
+  5. serve_recurrent  a tiny model of the family that keeps a recurrent
+              state beside its K/V (jamba): one slot admitted, decoded
+              and admitted again reads nothing of its last occupant
 
 `--chips 4` runs the four-chip phase instead (and nothing else): the
 sharded train plans against the one-chip step, a tp=4 engine against the
@@ -637,6 +640,48 @@ def phase_serve(run: Run, sizes: Sizes) -> dict:
             "new_tokens": sizes.new_tokens, "variants": report}
 
 
+def phase_serve_recurrent(run: Run, sizes: Sizes) -> dict:
+    """The family that keeps a recurrent state beside its keys and values
+    (models/jamba.py), tiny: ONE slot admits a request, decodes it, and is
+    admitted again. The second occupant has to decode exactly what it
+    decodes in an engine whose slot nobody used — the same program on the
+    same inputs but for the rows the first occupant left, which admission
+    must have replaced whole."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.router import create_router
+    from paddle_tpu.models.jamba import JambaConfig, init_jamba_params
+    cfg = JambaConfig(vocab_size=640, hidden_size=128, num_layers=4,
+                      num_heads=2, num_kv_heads=1, head_dim=64,
+                      ffn_hidden=256, max_seq_len=128, attn_layer_period=2,
+                      attn_layer_offset=1, mamba_dt_rank=8,
+                      dtype=jnp.float32, param_dtype=jnp.float32)
+    params = init_jamba_params(cfg, run.key(5))
+    long_one, short_one = _prompts(run, cfg, (70, 9))
+
+    def serve(prompts):
+        router = create_router(params, cfg, replicas=1, family="jamba",
+                               num_slots=1, max_len=128)
+        reqs = _serve(router, prompts, sizes.new_tokens,
+                      first=len(prompts))
+        eng = router.replicas[0].eng
+        pools = {k: int(v.nbytes) for k, v in eng._cache.items()
+                 if k != "stats"}
+        router.close()
+        return [list(r.tokens) for r in reqs], pools
+
+    (_, reused), pools = serve([long_one, short_one])
+    (fresh,), _ = serve([short_one])
+    check(reused == fresh,
+          f"a re-admitted slot decoded {reused}, a fresh one {fresh}: "
+          "something of the last occupant was read")
+    check(all(0 <= t < cfg.vocab_size for t in reused),
+          "a token outside the vocabulary")
+    return {"model": f"{cfg.num_layers}Lx{cfg.hidden_size}d "
+                     f"{'/'.join(t[0] for t in cfg.layer_types)}",
+            "new_tokens": sizes.new_tokens, "pool_bytes": pools,
+            "readmitted_equals_fresh": True}
+
+
 # --------------------------------------------------------- phase: four chips
 def _device_bytes(devices):
     return [int((d.memory_stats() or {}).get("bytes_in_use", 0))
@@ -763,11 +808,12 @@ def four_serve(run: Run, sizes: Sizes) -> dict:
 
 # ----------------------------------------------------------------------- run
 def run_one_chip(run: Run, sizes: Sizes) -> None:
-    """Phases 1-4. A phase that fails raises, and nothing runs after it."""
+    """Phases 1-5. A phase that fails raises, and nothing runs after it."""
     run.phase("surface", phase_surface)
     run.phase("kernels", phase_kernels, sizes)
     run.phase("train", phase_train, sizes)
     run.phase("serve", phase_serve, sizes)
+    run.phase("serve_recurrent", phase_serve_recurrent, sizes)
 
 
 def run_four_chips(run: Run, sizes: Sizes) -> None:

@@ -337,14 +337,21 @@ class ModelFamily:
     that accepts per-row positions (slot-indexed writes) and a cache
     factory. Any family that implements the contract plugs in here.
 
-    The cache is a dict of POOLS BY LAYER KIND, each
-    [layers of that kind, slots, positions, KV, hd] with the slot on
-    axis 1: the uniform families have one kind, `{"k", "v"}` over
-    `max_len` positions; a family with window layers adds a ring pool
-    (`{"k_win", "v_win"}` over `window` positions). The engine
-    allocates, donates and counts the dict whole; only the family reads
-    a pool. (The paged layout and the snapshot paths still assume the
-    uniform `{"k", "v"}` and are among what such a family `refuses`.)
+    The cache is a dict of POOLS BY KIND OF STATE, each
+    [layers of that kind, slots, ...] with the slot on axis 1. Keys and
+    values are held by position, [layers, slots, positions, KV, hd]: the
+    uniform families have one such kind, `{"k", "v"}` over `max_len`
+    positions; a family with window layers adds a ring pool
+    (`{"k_win", "v_win"}` over `window` positions). A recurrent kind has
+    NO position axis (jamba's `"ssm"` [layers, slots, d_state, d_inner]
+    float32 and `"conv"` [layers, slots, d_conv - 1, d_inner]): a step
+    overwrites a slot's rows, so such a family's forward must leave a
+    padded position and an idle row's state alone (it is told which
+    tokens are real, `live=`), and its `prefill` replaces a slot's rows
+    whole at admission. The engine allocates, donates and counts the
+    dict whole; only the family reads a pool. (The paged layout and the
+    snapshot paths still assume the uniform `{"k", "v"}` and are among
+    what such a family `refuses`.)
 
     `serving_specs` is the family's module-level tensor-parallel spec
     table (leaf name -> PartitionSpec over the serving mesh's 'tp' axis
@@ -393,9 +400,16 @@ def _cohere2_moe_family() -> ModelFamily:
                        counts=m.span_counts, refuses=REFUSABLE)
 
 
+def _jamba_family() -> ModelFamily:
+    from ..models import jamba as m
+    return ModelFamily("jamba", m.jamba_forward_cached, m.init_cache, None,
+                       prefill=m.prefill_into_slot, counts=m.span_counts,
+                       refuses=REFUSABLE)
+
+
 # name -> factory; a family's module is imported when it is asked for
 _FAMILIES = {"gpt": _gpt_family, "llama": _llama_family,
-             "cohere2_moe": _cohere2_moe_family}
+             "cohere2_moe": _cohere2_moe_family, "jamba": _jamba_family}
 
 
 def family_for(name: str) -> ModelFamily:
@@ -406,7 +420,8 @@ def family_for(name: str) -> ModelFamily:
 
 
 def _pool_bytes(cache) -> int:
-    """Device bytes of the K/V pools of a cache dict (every kind)."""
+    """Device bytes of the pools of a cache dict, every kind of state
+    (keys and values by position, recurrent rows)."""
     return sum(int(v.nbytes) for k, v in cache.items()
                if k not in ("pt", "stats"))
 

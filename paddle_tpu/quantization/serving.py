@@ -76,11 +76,18 @@ HEAD_LEAF = "wte"
 # f32->bf16->f32 convert pair away), so a table rounded ahead of time
 # serves other logits than the table as handed (GPT-1.3B on a v5e: 87%
 # of a 256-token prefill's logits move, by up to 0.07). A family with no
-# entry (cohere2_moe: bf16 as stored) is left alone.
+# entry (cohere2_moe: bf16 as stored) is left alone. jamba's are the
+# matmul stacks of both mixers and the MLP, the convolution and the
+# embedding: stored in bf16 they pass through; `a_log`, `d`, `dt_b` and
+# the norm scales are float32 operands of float32 arithmetic and are not
+# here.
 COMPUTE_LEAVES: Dict[str, tuple] = {
     "gpt": QUANT_LEAVES["gpt"] + ("qkv_b", "attn_out_b", "mlp_up_b",
                                   "mlp_down_b", "wte"),
     "llama": QUANT_LEAVES["llama"] + ("wte",),
+    "jamba": ("in_w", "conv_w", "conv_b", "x_w", "dt_w", "out_w",
+              "q_w", "k_w", "v_w", "o_w", "gate_w", "up_w", "down_w",
+              "wte"),
 }
 
 

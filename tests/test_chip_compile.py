@@ -296,3 +296,52 @@ def test_gpt_1p3b_paged_decode_tick_compiles(tp, topo, as_tpu):
         whole = sum(int(np.prod(v.shape)) * v.dtype.itemsize
                     for v in shapes.values())
         assert compiled.memory_analysis().argument_size_in_bytes < whole
+
+
+def test_jamba2_3b_decode_tick_moves_no_pool_and_no_weight_stack(topo,
+                                                                 as_tpu):
+    """The jamba2 cell's decode tick (128 slots x 8192, AI21-Jamba2-3B at
+    its published widths): the scan over periods carries four pools — K
+    and V by position, the recurrent state and the convolution rows — and
+    writes each layer's rows in place, and a layer's weights are read
+    straight out of the whole stacks. As the scan's xs each period's
+    2.9 GB of weights were copied out before its body ran (2.97 GB of
+    temporaries)."""
+    from paddle_tpu.inference.serving import _decode_tick, family_for
+    from paddle_tpu.models import jamba
+    cfg = jamba.JambaConfig()
+    assert cfg.layers_of(jamba.MAMBA) == 26 and cfg.period[7] == "attention"
+    one = SingleDeviceSharding(topo.devices[0])
+    slots, max_len = 128, 8192
+    params = _on(one, jax.eval_shape(
+        lambda: jamba.init_jamba_params(cfg, jax.random.PRNGKey(0))))
+    cache = _on(one, jax.eval_shape(
+        lambda: jamba.init_cache(cfg, slots, max_len)))
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    state = tuple(S((slots,), dt) for dt in (
+        jnp.int32, jnp.int32, jnp.bool_, jnp.float32, jnp.int32,
+        jnp.int32, jnp.int32))
+    tick = jax.jit(
+        functools.partial(_decode_tick, fwd=family_for("jamba").forward_cached,
+                          cfg=cfg, max_top_k=0, guard=True, oor_pos=None,
+                          cache_pin=None, tele=True),
+        donate_argnums=(1, 2), static_argnames=("sampling",))
+    compiled = tick.lower(params, cache, state, S((2,), jnp.uint32),
+                          S((slots,), jnp.float32), sampling=False).compile()
+    ma = compiled.memory_analysis()
+    pools = {k: v for k, v in cache.items() if k != "stats"}
+    assert pools["ssm"].shape == (26, 128, 16, 5120) \
+        and pools["ssm"].dtype == jnp.float32
+    assert pools["k"].shape == (2, 128, 8192, 1, 128)
+    held = sum(int(np.prod(v.shape)) * v.dtype.itemsize
+               for v in pools.values())
+    assert ma.alias_size_in_bytes >= held           # updated in place
+    text = compiled.as_text()
+    for v in pools.values():
+        shape = f"{'f32' if v.dtype == jnp.float32 else 'bf16'}[" \
+            + ",".join(map(str, v.shape)) + "]"
+        assert [ln.strip()[:160] for ln in text.splitlines()
+                if f"= {shape}" in ln
+                and (" copy(" in ln or "AllocateBuffer" in ln)] == []
+    assert ma.temp_size_in_bytes < 100e6
+    assert 8.2e9 < _device_bytes(compiled) < 8.6e9 < HBM_BYTES

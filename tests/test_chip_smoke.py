@@ -37,7 +37,8 @@ def test_rehearsal_runs_every_one_chip_phase():
     assert not run.on_chip
     chip_smoke.run_one_chip(run, chip_smoke.TINY)
     phases = _phases(lines)
-    assert list(phases) == ["surface", "kernels", "train", "serve"]
+    assert list(phases) == ["surface", "kernels", "train", "serve",
+                            "serve_recurrent"]
     assert all(r["ok"] for r in phases.values())
     assert phases["surface"]["loader_workers"] == "processes"
     assert set(phases["kernels"]["kernels"]) >= {
@@ -52,6 +53,10 @@ def test_rehearsal_runs_every_one_chip_phase():
     assert variants["paged"]["vs_dense"]["exact"] == 6
     assert variants["paged_spec"]["vs_paged"]["exact"] == 6
     assert variants["reference_decode"]["exact"] == 1
+    recurrent = phases["serve_recurrent"]
+    assert recurrent["readmitted_equals_fresh"] is True
+    assert recurrent["model"] == "4Lx128d m/a/m/a"
+    assert set(recurrent["pool_bytes"]) == {"k", "v", "ssm", "conv"}
 
 
 def test_rehearsal_runs_the_four_chip_phase_on_virtual_devices():
