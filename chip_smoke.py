@@ -272,6 +272,24 @@ def kernel_cases(sizes: Sizes):
         cases.append((f"flash_bwd_hd{shp[-1]}", flash_bwd, flash_bwd_oracle,
                       qkv + (S(shp, bf16),), 6e-2))
 
+    # the training attention's tiled pair, forward and backward, against
+    # the autodiff of the dense softmax
+    def dense_grads(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: fa._dense_reference(
+                q, k, v, True).astype(f32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    def flash_tiled(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: pallas_attention.tiled_mha(
+                q, k, v, True).astype(f32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    for shp in sizes.flash_shapes:
+        cases.append((f"flash_tiled_hd{shp[-1]}", flash_tiled, dense_grads,
+                      (S(shp, bf16),) * 3, 6e-2))
+
     # the upstream wrappers, at the training attention shape
     shp = sizes.flash_shapes[0]
     qkv = (S(shp, bf16),) * 3
@@ -282,15 +300,9 @@ def kernel_cases(sizes: Sizes):
                 lambda q, k, v: impl(q, k, v, True).astype(f32).sum(),
                 argnums=(0, 1, 2))(q, k, v)
 
-        def upstream_oracle(q, k, v):
-            return jax.value_and_grad(
-                lambda q, k, v: fa._dense_reference(
-                    q, k, v, True).astype(f32).sum(),
-                argnums=(0, 1, 2))(q, k, v)
-
         # the summed output is a large number: compare the gradients and
         # the sum relative to its size
-        cases.append((name, upstream, upstream_oracle, qkv, 6e-2))
+        cases.append((name, upstream, dense_grads, qkv, 6e-2))
 
     T, V = sizes.ce_shape
     ce_args = (S((T, V), bf16), S((T,), i32), S((T,), f32))

@@ -4,16 +4,19 @@ Reference analog: the external flashattn CUDA lib wired via
 cmake/external/flashattn.cmake + phi flash_attn kernels
 (/root/reference/paddle/phi/kernels/gpu/flash_attn_kernel.cu).
 
-Two forward paths behind one entry:
-- Pallas hand-tiled kernel (pallas_attention.mha_fwd) when the backend is TPU;
-- a blockwise online-softmax lax.scan path that XLA fuses, used on CPU and as
-  the safety net.
+Two paths behind one entry:
+- on a TPU, the tiled Pallas pair (pallas_attention.tiled_mha: forward and
+  one fused backward kernel, scores never in HBM) for the calls
+  `_tiled_engages` admits — whole-sequence self-attention, no mesh of
+  several devices;
+- a blockwise online-softmax lax.scan path that XLA fuses, for every other
+  call (the CPU, kv_len, ragged lengths, a multi-device mesh).
 
-Both return the softmax log-normalizer (lse), and the backward is the
-standard flash-attention recompute pass written at the jax level (scan over
-kv blocks, f32): p is rebuilt from lse, so no O(S²) tensor is ever saved.
-Wired via jax.custom_vjp, so the eager tape, jit.to_static and grad
-transforms all pick up the memory-efficient backward.
+Both keep the softmax log-normalizer (lse); the blockwise path's backward
+is the standard flash-attention recompute pass written at the jax level
+(scan over kv blocks, f32): p is rebuilt from lse, so no O(S²) tensor is
+ever saved. Wired via jax.custom_vjp, so the eager tape, jit.to_static and
+grad transforms all pick up the memory-efficient backward.
 """
 from __future__ import annotations
 
@@ -136,16 +139,15 @@ def _pallas_enabled() -> bool:
 
 
 def _pallas_attn_enabled() -> bool:
-    """Attention-only gate layered on the global one (CE kernel
-    unaffected — it gates through _pallas_enabled directly): the round-4
-    ablation measured the XLA attention path faster than the Pallas flash
-    forward at S=1024, so benches race the two per-shape via
-    PADDLE_TPU_DISABLE_PALLAS_ATTN."""
+    """Gate of the 128x128 kernels (mha_fwd / mha_bwd), layered on the
+    global one (CE kernel unaffected — it gates through _pallas_enabled
+    directly): shut where `_attn_impl` names another implementation, or
+    by PADDLE_TPU_DISABLE_PALLAS_ATTN."""
     import os
     if os.environ.get("PADDLE_TPU_DISABLE_PALLAS_ATTN", "") in (
             "1", "true", "True"):
         return False
-    if _attn_impl() == "xla":
+    if _attn_impl() in ("xla", "tiled"):
         return False
     return _pallas_enabled()
 
@@ -327,20 +329,35 @@ _flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
 
 def _attn_impl() -> str:
     """Which attention implementation runs:
-    - 'pallas'   homegrown kernel + the gates above
+    - 'tiled'    pallas_attention.tiled_mha where the call engages it
+      (`_tiled_engages`), the blockwise scan for every other call
+    - 'pallas'   the 128x128 homegrown kernels + the gates above
     - 'jax_flash' jax.experimental.pallas.ops.tpu.flash_attention — the
-      upstream-tuned TPU kernel with its own fwd+bwd Pallas passes
+      upstream TPU kernel with its own fwd+bwd Pallas passes
     - 'splash'   jax.experimental splash attention (block-sparse mask
       pipeline)
     - 'xla'      the blockwise lax.scan path (same as the ATTN kill)
-    On the TPU 'xla': the one step ablation on a v5e (2026-07-30,
-    GPT-350M B=8 S=1024 bf16) read 399.7 ms/step for it against 427.6+
-    for every Pallas forward, and it is what the train cell measures.
+    On the TPU 'tiled'. One chip command on a v5e (PR 36, the train
+    cell's attention: B=8 S=1024 H=16 D=64 bf16 causal, [B,S,H,D] in and
+    out, 16 calls chained in one jit), ms forward / ms forward+backward:
+      tiled, tile 512 (this rule's)          0.516 / 1.415
+      tiled, tile 256                        0.772 / 1.601
+      tiled, tile 128                        1.105 / 2.709
+      splash, blocks 512, fused backward     0.558 / 1.879
+      splash, blocks 512, dq + dkv kernels   0.555 / 2.387
+      splash, blocks 1024 (compute 512), f.  0.565 / 1.963
+      splash, blocks 512 (compute 256), f.   0.676 / 2.045
+      jax_flash, every block 512             0.499 / 3.555
+      jax_flash, every block 1024            0.511 / 3.538
+      xla (the blockwise scan)               3.165 / 6.979
+      pallas (128x128 tiles, as it was)      3.654 / 11.562
+    and the train step 414.3 -> 212.6 ms with it (docs/kernel_selection.md).
     Elsewhere 'pallas', so that the CPU suite keeps exercising the
-    homegrown kernel's path (interpret-mode parity coverage would
-    silently vanish if the CPU followed the TPU's choice). ROADMAP S3
-    races the four in the train cell and keeps one."""
-    return "xla" if is_tpu() else "pallas"
+    128x128 kernels' path (interpret-mode parity coverage would silently
+    vanish if the CPU followed the TPU's choice); the tiled kernels'
+    parity cases pass `interpret=True` themselves. ROADMAP D2 deletes the
+    arms that lost."""
+    return "tiled" if is_tpu() else "pallas"
 
 
 def _jax_flash_mha(q, k, v, causal):
@@ -378,11 +395,35 @@ def _splash_mha(q, k, v, causal, interpret=False):
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)
 
 
+def _tiled_engages(q, k, v) -> bool:
+    """Whether a call takes the tiled kernels (pallas_attention.tiled_mha)
+    — by what the call shows, no option: a TPU, no ambient mesh of
+    several devices (GSPMD cannot partition a Mosaic kernel), a
+    self-attention over whole sequences (q, k and v of one shape and
+    dtype), whole 128-lane groups of heads, and a length and head size
+    `tiled_tile` has a tile for. Every other call keeps the blockwise
+    scan and its jax-level backward — as do context_parallel's `kv_len`
+    calls, which enter at `_flash_mha` and never get here."""
+    from ..parallel.mesh import get_mesh
+    from .pallas_attention import LANES, tiled_tile
+    mesh = get_mesh()
+    _, S, H, D = q.shape
+    return (is_tpu() and _pallas_enabled()
+            and (mesh is None or mesh.size == 1)
+            and q.shape == k.shape == v.shape
+            and q.dtype == k.dtype == v.dtype
+            and (H * D) % LANES == 0
+            and tiled_tile(S, D, q.dtype) is not None)
+
+
 def _dispatch_mha(q, k, v, causal):
     # the upstream kernel is still Pallas: the global and attention kill
     # switches outrank the impl selector, preserving the documented
     # global > attention-only > impl layering
     impl = _attn_impl()
+    if impl == "tiled" and _tiled_engages(q, k, v):
+        from .pallas_attention import tiled_mha
+        return tiled_mha(q, k, v, causal)
     if (impl in ("jax_flash", "splash") and _pallas_attn_enabled()
             and is_tpu()):
         fn = _splash_mha if impl == "splash" else _jax_flash_mha

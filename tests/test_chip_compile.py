@@ -36,7 +36,8 @@ SIZES = chip_smoke.REAL
 # static names (a parametrize argument must not need the topology):
 # chip_smoke.kernel_cases(SIZES) is checked against this list in the test
 KERNELS = ["flash_fwd_hd64", "flash_bwd_hd64", "flash_fwd_hd128",
-           "flash_bwd_hd128", "jax_flash", "splash", "ce", "ce_fused",
+           "flash_bwd_hd128", "flash_tiled_hd64", "flash_tiled_hd128",
+           "jax_flash", "splash", "ce", "ce_fused",
            "fused_adamw", "quant_matmul_k2048", "quant_matmul_k8192",
            "decode_live_blocks"]
 
@@ -127,8 +128,14 @@ def test_gpt_350m_train_step_compiles_on_one_chip(topo, as_tpu):
     cfg = chip_smoke._gpt_cfg(SIZES.train_model)
     plan = plan_train(cfg, 1, SIZES.train_batch)
     compiled = _lower_train(plan, list(topo.devices[:1]))
-    # the loss head is the Pallas CE pair (forward and backward)
-    assert compiled.as_text().count("tpu_custom_call") >= 2
+    # the loss head is the Pallas CE pair (forward and backward), and the
+    # attention the tiled pair: no score block of the blockwise scan
+    # ([batch, heads, queries, a block of 512 keys]) is left in HBM
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 4
+    for kernel in ("flash_tiled_fwd", "flash_tiled_bwd"):
+        assert kernel in text, kernel
+    assert "f32[8,16,1024,512]" not in text
     assert _device_bytes(compiled) < HBM_BYTES
     assert not any(_collectives(compiled).values())
 

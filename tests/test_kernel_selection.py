@@ -100,7 +100,13 @@ def _ce_flavour():
 # docs/kernel_selection.md is this table in prose
 SITES = {
     "attention": (lambda m: fa._attn_impl(),
-                  {"tpu": "xla", "cpu": "pallas"}),
+                  {"tpu": "tiled", "cpu": "pallas"}),
+    # the train cell's attention, a ragged length, a 32-wide head
+    "attention_tiled_kernels": (
+        lambda m: tuple(fa._tiled_engages(*(jax.ShapeDtypeStruct(
+            shp, jnp.bfloat16),) * 3) for shp in (
+                (8, 1024, 16, 64), (8, 197, 16, 64), (8, 1024, 16, 32))),
+        {"tpu": (True, False, False), "cpu": (False, False, False)}),
     "ce": (lambda m: (losses._pallas_ce_enabled(), _ce_flavour()),
            {"tpu": (True, "ce_with_logits"),
             "cpu": (False, "ce_with_logits")}),
@@ -149,11 +155,12 @@ class TestAttentionSelection:
         assert fa._attn_impl() == "pallas"
         assert fa._pallas_attn_enabled()
 
-    def test_tpu_default_is_xla(self, monkeypatch):
-        """What the train cell measures: blockwise XLA attention, the
-        Pallas forward and backward gates both closed."""
+    def test_tpu_default_is_tiled(self, monkeypatch):
+        """What the train cell measures: the tiled kernels where the call
+        engages them (`_tiled_engages`), else blockwise XLA attention —
+        the 128x128 kernels' forward and backward gates both closed."""
         monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
-        assert fa._attn_impl() == "xla"
+        assert fa._attn_impl() == "tiled"
         assert not fa._pallas_attn_enabled()
         assert not fa._pallas_bwd_enabled()
 

@@ -156,6 +156,119 @@ class TestFlashBackward:
         assert not np.allclose(qt.grad.numpy(), 0)
 
 
+def _as_tpu(monkeypatch):
+    """Steer the gates to their TPU branch on this CPU, and run the
+    tiled kernels they pick in the TPU interpreter."""
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.kernels import pallas_attention as pa
+    calls = []
+
+    def interpreted(q, k, v, causal=False):
+        calls.append(q.shape)
+        return tiled(q, k, v, causal, True)
+
+    tiled = pa.tiled_mha
+    monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pa, "tiled_mha", interpreted)
+    return calls
+
+
+class TestTiledFlash:
+    """The training attention's tiled kernels (pallas_attention.tiled_mha)
+    in interpret mode against the dense reference and its autodiff."""
+
+    # (S, H, D, dtype, causal): the train cell's head shape, a 128-wide
+    # head, the encoders' S=512, and tile edges of 128 / 256 / 512
+    CASES = [(1024, 2, 64, "bfloat16", True), (1024, 2, 64, "bfloat16", False),
+             (1024, 2, 64, "float32", True), (1024, 2, 64, "float32", False),
+             (512, 1, 128, "float32", True), (512, 1, 128, "bfloat16", False),
+             (512, 2, 64, "float32", False), (512, 4, 64, "bfloat16", False),
+             (768, 2, 64, "float32", True), (384, 2, 128, "float32", True)]
+
+    @pytest.mark.parametrize("S,H,D,dtype,causal", CASES)
+    def test_forward_and_backward_match_dense(self, S, H, D, dtype, causal):
+        from paddle_tpu.kernels.pallas_attention import tiled_mha
+        q, k, v = (x.astype(dtype) for x in _rand_qkv(B=1, S=S, H=H, D=D))
+        do = _rand_qkv(B=1, S=S, H=H, D=D, seed=1)[0].astype(dtype)
+        f32 = jnp.float32
+
+        def run(fn):
+            out, vjp = jax.vjp(fn, q, k, v)
+            return (out,) + vjp(do)
+
+        got = run(lambda q, k, v: tiled_mha(q, k, v, causal, True))
+        want = run(lambda q, k, v: _dense_reference(q, k, v, causal))
+        tol = dict(rtol=1e-3, atol=1e-4) if dtype == "float32" \
+            else dict(rtol=3e-2, atol=3e-2)
+        for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(a.astype(f32)),
+                                       np.asarray(b.astype(f32)),
+                                       err_msg=name, **tol)
+
+    # every attention shape the repo's models make: (S, D) of the GPT
+    # ladder, llama, BERT / ERNIE-ViL text, ViT (197 patches: ragged)
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("S,D,tile", [
+        (1024, 64, 512), (2048, 64, 512), (2048, 128, 512), (4096, 128, 512),
+        (512, 64, 512), (768, 64, 256), (384, 64, 128), (128, 64, 128),
+        (197, 64, None), (64, 64, None), (1000, 128, None),
+        (1024, 32, None), (1024, 256, None)])
+    def test_tile_rule(self, S, D, tile, dtype):
+        from paddle_tpu.kernels import pallas_attention as pa
+        assert pa.tiled_tile(S, D, dtype) == tile
+        if tile is not None:
+            assert S % tile == 0 and tile % pa.LANES == 0
+            assert pa.tiled_vmem_bytes(
+                S, tile, jnp.dtype(dtype).itemsize) <= pa.TILED_VMEM_BUDGET
+
+    def test_tile_rule_refuses_what_does_not_fit_vmem(self):
+        from paddle_tpu.kernels import pallas_attention as pa
+        assert pa.tiled_tile(16384, 128, "float32") is None
+        assert pa.tiled_tile(1024, 64, "float16") is None
+
+    def test_engaged_call_runs_the_kernels(self, monkeypatch):
+        from paddle_tpu.kernels import flash_attention as fa
+        calls = _as_tpu(monkeypatch)
+        q, k, v = _rand_qkv(B=1, S=256, H=2, D=64)
+        assert fa._tiled_engages(q, k, v)
+        got = jax.grad(lambda q: jnp.sum(
+            fa.flash_attention_fn(q, k, v, causal=True) ** 2))(q)
+        want = jax.grad(lambda q: jnp.sum(
+            _dense_reference(q, k, v, True) ** 2))(q)
+        assert calls == [q.shape]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-3, atol=1e-4)
+
+    @pytest.mark.parametrize("why", ["ragged_S", "cross_attention",
+                                     "odd_head_group", "mesh", "kv_len",
+                                     "pallas_off"])
+    def test_refused_call_keeps_the_blockwise_path(self, why, monkeypatch):
+        from paddle_tpu.kernels import flash_attention as fa
+        from paddle_tpu.parallel.mesh import build_mesh, use_mesh
+        import contextlib
+        calls = _as_tpu(monkeypatch)
+        shape = dict(ragged_S=dict(S=200), cross_attention=dict(Skv=512),
+                     odd_head_group=dict(H=3)).get(why, {})
+        q, k, v = _rand_qkv(**{**dict(B=1, S=256, H=2, D=64), **shape})
+        causal = why != "cross_attention"
+        kv_len = 100 if why == "kv_len" else None
+        if why == "pallas_off":
+            monkeypatch.setenv("PADDLE_TPU_DISABLE_PALLAS", "1")
+        ambient = use_mesh(build_mesh({"dp": 2}, jax.devices()[:2])) \
+            if why == "mesh" else contextlib.nullcontext()
+        with ambient:
+            # context_parallel's kv_len calls enter at _flash_mha, past
+            # the predicate (which would admit this shape)
+            assert fa._tiled_engages(q, k, v) == (why == "kv_len")
+            got = fa._flash_mha(q, k, v, causal, kv_len) if kv_len \
+                else fa.flash_attention_fn(q, k, v, causal=causal)
+        assert calls == []
+        np.testing.assert_allclose(
+            np.asarray(got),
+            np.asarray(_dense_reference(q, k, v, causal, kv_len)),
+            rtol=1e-4, atol=1e-5)
+
+
 class TestPallasCrossEntropy:
     """Fused softmax-CE kernel (kernels/pallas_ce.py) vs the jax oracle,
     interpret mode."""

@@ -78,6 +78,13 @@ def _flash_bwd():
                                interpret=True))(q, lse)
 
 
+def _flash_tiled():
+    from paddle_tpu.kernels.pallas_attention import tiled_mha
+    q = jnp.zeros((1, 128, 2, 64), jnp.float32)
+    return jax.make_jaxpr(jax.grad(
+        lambda q: tiled_mha(q, q, q, True, True).sum()))(q)
+
+
 def _ce(which):
     from paddle_tpu.kernels import pallas_ce
     x = jnp.zeros((128, 512), jnp.float32)
@@ -122,6 +129,8 @@ KERNELS = {
     "flash_fwd": _flash_fwd,
     "flash_bwd_dq": _flash_bwd,
     "flash_bwd_dkv": _flash_bwd,
+    "flash_tiled_fwd": _flash_tiled,
+    "flash_tiled_bwd": _flash_tiled,
     "ce_fused": functools.partial(_ce, "ce_fused"),
     "ce_fwd": functools.partial(_ce, "ce_fwd"),
     "ce_bwd": functools.partial(_ce, "ce_bwd"),
