@@ -342,7 +342,11 @@ class ModelFamily:
     values are held by position, [layers, slots, positions, KV, hd]: the
     uniform families have one such kind, `{"k", "v"}` over `max_len`
     positions; a family with window layers adds a ring pool
-    (`{"k_win", "v_win"}` over `window` positions). A recurrent kind has
+    (`{"k_win", "v_win"}` over `window` positions). A LATENT kind is held
+    by position too but has no head axis (joyai_llm_flash's `"ckv"`
+    [layers, slots, positions, kv_lora_rank] and `"kpe"` [layers, slots,
+    positions, qk_rope_head_dim]: what every head's key and value are
+    made from, and the one rotated key they share). A recurrent kind has
     NO position axis (jamba's `"ssm"` [layers, slots, d_state, d_inner]
     float32 and `"conv"` [layers, slots, d_conv - 1, d_inner]): a step
     overwrites a slot's rows, so such a family's forward must leave a
@@ -407,9 +411,17 @@ def _jamba_family() -> ModelFamily:
                        refuses=REFUSABLE)
 
 
+def _joyai_llm_flash_family() -> ModelFamily:
+    from ..models import joyai_llm_flash as m
+    return ModelFamily("joyai_llm_flash", m.joyai_llm_flash_forward_cached,
+                       m.init_cache, None, prefill=m.prefill_into_slot,
+                       counts=m.span_counts, refuses=REFUSABLE)
+
+
 # name -> factory; a family's module is imported when it is asked for
 _FAMILIES = {"gpt": _gpt_family, "llama": _llama_family,
-             "cohere2_moe": _cohere2_moe_family, "jamba": _jamba_family}
+             "cohere2_moe": _cohere2_moe_family, "jamba": _jamba_family,
+             "joyai_llm_flash": _joyai_llm_flash_family}
 
 
 def family_for(name: str) -> ModelFamily:

@@ -161,7 +161,8 @@ def write_kv_paged(pages, table, k, pos, layer=None):
 
 def write_kv(kc, k, pos, layer=None, ring: bool = False):
     """Write the step's k (or v) [B, T, KV, hd] into the cache
-    [B, S, KV, hd] at position(s) `pos` — scalar (one
+    [B, S, KV, hd] (or, a latent's rows, [B, T, W] into [B, S, W]: held
+    by position with no head axis) at position(s) `pos` — scalar (one
     dynamic_update_slice) or [B] per-row (each slot writes at its own
     offset, the serving engine's in-place slot write; T == 1 clamps a
     position past the end onto the last slot, as a
@@ -196,11 +197,11 @@ def write_kv(kc, k, pos, layer=None, ring: bool = False):
             k, mode="promise_in_bounds")
     if jnp.ndim(pos) == 0:
         return jax.lax.dynamic_update_slice(
-            kc, k[(None,) * len(at)], at + (0, pos, 0, 0))
+            kc, k[(None,) * len(at)], at + (0, pos) + (0,) * (k.ndim - 2))
     B, T = k.shape[:2]
     rows = jnp.arange(B, dtype=jnp.int32)
     if T == 1:
-        p = jnp.clip(pos, 0, kc.shape[-3] - 1)
+        p = jnp.clip(pos, 0, kc.shape[len(at) + 1] - 1)
         return kc.at[at + (rows, p)].set(k[:, 0],
                                          mode="promise_in_bounds")
     qpos = _query_positions(pos, B, T)                 # [B, T]
@@ -544,16 +545,19 @@ _MASKED = -1e30     # finite: a block a row sees nothing of leaves no nan
 def blocked_attention(q, k, v, window: int | None = None,
                       block: int = 512, q_offset=0):
     """Causal attention of a prompt's queries against the prompt's keys,
-    in blocks: k/v [B, T, KV, hd] at positions 0..T-1, q [B, Tq, H, hd]
+    in blocks: k [B, T, KV, hd] and v [B, T, KV, vd] at positions
+    0..T-1 (vd = hd everywhere but under latent attention, whose
+    decompressed keys are wider than its values), q [B, Tq, H, hd]
     at positions q_offset.. (a traced multiple of the block; the whole
-    prompt by default) -> ctx [B, Tq, H, hd] in q's dtype. Query i sees
+    prompt by default) -> ctx [B, Tq, H, vd] in q's dtype; the scores
+    are scaled by the q/k width. Query i sees
     key j iff j <= i and, with `window`, i - j < window. A block of query rows walks the key
     blocks it can see with a running softmax (float32 max, sum and
     accumulator; operands in their own dtype), so the scores of a 16k
     prompt never exist at once, and key blocks wholly ahead of the
     rows or wholly outside the window are never touched."""
     B, Tq, H, hd = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    T, KV, vd = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
     bs = min(block, Tq)
     if Tq % bs or T % bs:
@@ -594,10 +598,10 @@ def blocked_attention(q, k, v, window: int | None = None,
             jnp.maximum(i * bs - (window - 1), 0) // bs
         init = (jnp.full((B, KV, G, bs), _MASKED, jnp.float32),
                 jnp.zeros((B, KV, G, bs), jnp.float32),
-                jnp.zeros((B, KV, G, bs, hd), jnp.float32))
+                jnp.zeros((B, KV, G, bs, vd), jnp.float32))
         _, l, acc = jax.lax.fori_loop(first, i + 1, keys_of, init)
-        ctx = (acc / l[..., None]).astype(q.dtype)    # B,KV,G,bs,hd
-        return jnp.transpose(ctx, (0, 3, 1, 2, 4))    # B,bs,KV,G,hd
+        ctx = (acc / l[..., None]).astype(q.dtype)    # B,KV,G,bs,vd
+        return jnp.transpose(ctx, (0, 3, 1, 2, 4))    # B,bs,KV,G,vd
 
     out = jax.lax.map(rows_of, jnp.arange(nb, dtype=jnp.int32))
-    return jnp.moveaxis(out, 0, 1).reshape(B, Tq, H, hd)
+    return jnp.moveaxis(out, 0, 1).reshape(B, Tq, H, vd)

@@ -95,16 +95,27 @@ def topk_gating(probs, k: int, capacity: int, normalize: bool = None):
     return dispatch, combine, aux_loss
 
 
-def sigmoid_topk(router_logits, k: int, normalize: bool = True):
+def sigmoid_topk(router_logits, k: int, normalize: bool = True, bias=None,
+                 scale: float = 1.0):
     """Sigmoid selection over ALL published experts: router_logits
     [T, E] -> (choice [T, k] int32, weight [T, k] float32). The scores
     are sigmoids, the k largest are chosen, and with `normalize` the
     chosen scores are divided by their sum. Float32 throughout: a
-    near-tie for the k-th place must fall the way the reference's does."""
+    near-tie for the k-th place must fall the way the reference's does.
+    `bias` [E] (a `noaux_tc` router's `e_score_correction_bias`) is added
+    to the scores for the CHOICE alone: the weights are the chosen
+    experts' own scores. `scale` (`routed_scaling_factor`) multiplies the
+    weights last."""
     scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
-    weight, choice = jax.lax.top_k(scores, k)
+    if bias is None:
+        weight, choice = jax.lax.top_k(scores, k)
+    else:
+        _, choice = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        weight = jnp.take_along_axis(scores, choice, axis=-1)
     if normalize:
         weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weight = weight * scale
     return choice.astype(jnp.int32), weight
 
 

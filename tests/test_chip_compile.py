@@ -352,3 +352,68 @@ def test_jamba2_3b_decode_tick_moves_no_pool_and_no_weight_stack(topo,
                 and (" copy(" in ln or "AllocateBuffer" in ln)] == []
     assert ma.temp_size_in_bytes < 100e6
     assert 8.2e9 < _device_bytes(compiled) < 8.6e9 < HBM_BYTES
+
+
+def test_joyai_llm_flash_tick_and_longest_prompt_fit_and_move_no_pool(
+        topo, as_tpu):
+    """The joyai-llm-flash cell's two extreme bodies (32 slots x 16,384,
+    13 of 40 layers, 16 of 256 experts held, every width as published):
+    the decode tick — absorbed attention over the two latent pools, which
+    ride both layer scans' carry and are written in place (reading an idle
+    row back to keep it made the compiler re-lay the whole `kpe` pool
+    out, 1.7 GB of temporaries) — and the 16,384 prompt bucket, whose
+    decompressed attention runs in blocks. What
+    `sizing.compile_bytes` of the configuration file states."""
+    from paddle_tpu.inference.serving import (_decode_tick, _prefill_slot,
+                                              family_for)
+    from paddle_tpu.models import joyai_llm_flash as m
+    cfg = m.JoyaiLlmFlashConfig(num_layers=13, experts_held=16)
+    fam = family_for("joyai_llm_flash")
+    one = SingleDeviceSharding(topo.devices[0])
+    slots, max_len = 32, 16384
+    params = _on(one, jax.eval_shape(lambda: m.init_joyai_llm_flash_params(
+        cfg, jax.random.PRNGKey(0), mtp=False)))
+    assert sum(int(np.prod(v.shape)) for v in params.values()) \
+        == 1_885_031_424
+    cache = _on(one, jax.eval_shape(
+        lambda: m.init_cache(cfg, slots, max_len)))
+    pools = {k: v for k, v in cache.items() if k != "stats"}
+    assert pools["ckv"].shape == (13, 32, 16384, 512)
+    assert pools["kpe"].shape == (13, 32, 16384, 64)
+    held = sum(int(np.prod(v.shape)) * v.dtype.itemsize
+               for v in pools.values())
+    assert held == 32 * 16384 * 14_976
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    state = tuple(S((slots,), dt) for dt in (
+        jnp.int32, jnp.int32, jnp.bool_, jnp.float32, jnp.int32,
+        jnp.int32, jnp.int32))
+    tick = jax.jit(
+        functools.partial(_decode_tick, fwd=fam.forward_cached, cfg=cfg,
+                          max_top_k=0, guard=True, oor_pos=None,
+                          cache_pin=None, tele=True),
+        donate_argnums=(1, 2), static_argnames=("sampling",))
+    compiled = tick.lower(params, cache, state, S((2,), jnp.uint32),
+                          S((slots,), jnp.float32), sampling=False).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= held           # updated in place
+    text = compiled.as_text()
+    for v in pools.values():
+        shape = "bf16[" + ",".join(map(str, v.shape)) + "]"
+        assert [ln.strip()[:160] for ln in text.splitlines()
+                if f"= {shape}" in ln
+                and (" copy(" in ln or "AllocateBuffer" in ln)] == []
+    assert "tpu_custom_call" not in text            # plain jnp: the einsum
+    assert ma.temp_size_in_bytes < 100e6
+    assert 11.6e9 < _device_bytes(compiled) < 11.8e9 < HBM_BYTES
+    prefill = jax.jit(
+        functools.partial(_prefill_slot, fwd=fam.forward_cached,
+                          init_cache=fam.init_cache, cfg=cfg, max_top_k=0,
+                          guard=True, cache_pin=None,
+                          family_prefill=fam.prefill),
+        donate_argnums=(1,), static_argnames=("sampling",))
+    compiled = prefill.lower(
+        params, cache, S((1, max_len), jnp.int32), S((), jnp.int32),
+        S((), jnp.int32), S((1,), jnp.float32), S((1,), jnp.int32),
+        S((1,), jnp.int32), S((2,), jnp.uint32), sampling=False).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+    assert 12.8e9 < _device_bytes(compiled) < 13.5e9 < 15.75e9
