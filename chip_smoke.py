@@ -61,6 +61,7 @@ class Sizes:
     adamw_leaf: Tuple[int, ...]
     qmm_shapes: Tuple[Tuple[int, int, int], ...]          # [M, K, N]
     decode_pool: Tuple[int, int, int, int, int]           # [L, B, S, KV, hd]
+    latent_pool: Tuple[int, int, int, int, int, int]      # [L, B, S, C, R, H]
 
 
 # the published widths: GPT-350M (24L x 1024d x 16 heads, hd 64) trains at
@@ -75,6 +76,8 @@ REAL = Sizes(
     adamw_leaf=(24, 1024, 4096),            # mlp_up_w, the largest leaf
     qmm_shapes=((256, 2048, 8192), (256, 8192, 2048)),
     decode_pool=(4, 8, 1024, 16, 128),      # four layers of the serve pool
+    # two layers of JoyAI-LLM-Flash's latent pools at a quarter the length
+    latent_pool=(2, 8, 4096, 512, 64, 32),
 )
 
 _TINY_GPT = dict(vocab_size=640, hidden_size=128, num_layers=2, num_heads=2,
@@ -91,6 +94,7 @@ TINY = Sizes(
     adamw_leaf=(2, 128, 512),
     qmm_shapes=((16, 256, 512), (16, 512, 256)),
     decode_pool=(2, 4, 256, 8, 128),
+    latent_pool=(2, 4, 2048, 128, 64, 8),
 )
 
 # bf16 compute rounds to 8 bits of mantissa; losses of two layouts of one
@@ -366,6 +370,31 @@ def kernel_cases(sizes: Sizes):
     cases.append(("decode_live_blocks", decode, decode_oracle,
                   (S(sizes.decode_pool, bf16),) * 2
                   + (S((B, 1, KV, hd), bf16), S((B,), i32)), 1e-5))
+
+    # the absorbed latent attention's live blocks against the family's
+    # two masked einsums over the whole layer (the probabilities are
+    # rounded to bf16 after they are normalised there, before it here:
+    # 2^-9 of an output); own names: the closures above bind theirs late
+    from paddle_tpu.kernels import latent_attention as la
+    from paddle_tpu.models import joyai_llm_flash as joyai
+    LL, LB, LP, C, R, H = sizes.latent_pool
+    latent_cfg = joyai.JoyaiLlmFlashConfig(kv_lora_rank=C,
+                                           qk_rope_head_dim=R)
+
+    def absorbed(ckv, kpe, q_lat, q_pe, draw):
+        return la.absorbed_attention_live_blocks(
+            q_lat, q_pe, ckv, kpe, jnp.int32(LL - 1),
+            da.work_list(draw * 37 % LP, None, LB, LP, la.LATENT_BLOCK),
+            latent_cfg.qk_head_dim)
+
+    def absorbed_oracle(ckv, kpe, q_lat, q_pe, draw):
+        return joyai._masked_einsums(q_lat, q_pe, ckv[LL - 1], kpe[LL - 1],
+                                     draw * 37 % LP, latent_cfg)
+
+    cases.append(("mla_live_blocks", absorbed, absorbed_oracle,
+                  (S((LL, LB, LP, C), bf16), S((LL, LB, LP, R), bf16),
+                   S((LB, H, C), bf16), S((LB, H, R), bf16),
+                   S((LB,), i32)), 1e-2))
     return cases
 
 

@@ -376,18 +376,18 @@ def kv_positions_read(positions, active, max_len: int,
     return int(cdiv(n, DECODE_BLOCK).sum()) * DECODE_BLOCK
 
 
-def work_list(pos, live, B: int, S: int):
-    """The length-aware kernel's scalar operands, from the rows'
-    positions (scalar or [B]) and `live` [B] (None: every row) ->
-    (n [B] positions row b may see — `pos + 1`, 0 for a row that is no
-    request —, slot and block [B * S // DECODE_BLOCK] of work item t,
-    total [1] items). Row b's blocks 0 .. cdiv(n[b], block) - 1, row by
+def work_list(pos, live, B: int, S: int, block: int = DECODE_BLOCK):
+    """A live-block kernel's scalar operands, from the rows' positions
+    (scalar or [B]) and `live` [B] (None: every row) -> (n [B] positions
+    row b may see — `pos + 1`, 0 for a row that is no request —, slot
+    and block [B * S // block] of work item t, total [1] items). Row
+    b's blocks of `block` positions 0 .. cdiv(n[b], block) - 1, row by
     row; entries past `total` are never read."""
-    nb = S // DECODE_BLOCK
+    nb = S // block
     n = jnp.minimum(jnp.broadcast_to(pos, (B,)).astype(jnp.int32) + 1, S)
     if live is not None:
         n = jnp.where(live, n, 0)
-    blocks = cdiv(n, DECODE_BLOCK)
+    blocks = cdiv(n, block)
     ends = jnp.cumsum(blocks)
     t = jnp.arange(B * nb, dtype=jnp.int32)
     slot = jnp.minimum(jnp.sum(t[:, None] >= ends[None, :], axis=1), B - 1

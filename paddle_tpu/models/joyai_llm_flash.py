@@ -26,7 +26,11 @@ the bucketed prefill into a slot. What differs from the other families:
   The tick (T = 1) ABSORBS: `W_kvb`'s key half goes into the query
   (`q_lat[h] = q_nope[h] W_kvb^K[h]`), its value half onto the output, and
   32 heads attend over the latent pool itself — no per-head key or value
-  of a cached position ever exists;
+  of a cached position ever exists. On a TPU that attention is the Pallas
+  kernel of kernels/latent_attention.py over each live row's blocks, every
+  `ckv` block read once for scores and output; elsewhere two masked
+  einsums over the whole layer (`absorbed_engages` says which, by what
+  the call shows);
 - the layer scan is ONE scan per kind of layer (the leading dense layers,
   then the expert layers), both with the two pools in the carry, every
   layer's leaves read straight out of the whole stacks inside the body;
@@ -59,6 +63,9 @@ import jax.numpy as jnp
 
 from ..kernels.decode_attention import (_query_positions, blocked_attention,
                                         layer_view, write_kv)
+from ..kernels.latent_attention import (LATENT_BLOCK,
+                                        absorbed_attention_live_blocks,
+                                        live_latent_plan)
 from ..parallel.moe import dropless_experts, sigmoid_topk
 from .llama import _apply_rope
 
@@ -74,7 +81,8 @@ MTP = "mtp_"
 # what one forward counts, in the order of the cache's "stats" leaf
 # (int32): live (token, choice) pairs on held experts summed over the
 # expert layers, the busiest held expert's pairs (largest over layers);
-# latent positions ONE layer's tick attention read and its pool holds
+# latent positions ONE layer's tick attention may touch (the kernel's
+# live blocks; every position under the einsums) and its pool holds
 # (`span_counts` multiplies by the layers and the bytes)
 COUNTS = ("expert_tokens", "expert_max_load", "kv_read_layer",
           "kv_pool_layer")
@@ -237,9 +245,10 @@ def init_cache(cfg: JoyaiLlmFlashConfig, batch: int, max_len: int):
 def span_counts(cfg: JoyaiLlmFlashConfig, stats) -> Dict[str, int]:
     """A pulled "stats" row as the counts the engine sets on its spans
     (ModelFamily.counts), in Python integers: the held experts' live pairs
-    and the busiest's; for a tick, the latent positions its attention read
-    over those the pools hold (the same while the einsum reads every
-    position of every slot) and the bytes behind the former."""
+    and the busiest's; for a tick, the latent positions its attention may
+    touch — each live row's whole blocks under the kernel, every position
+    of every slot under the einsums — over those the pools hold, and the
+    bytes behind the former."""
     tokens, busiest, read, pool = (int(v) for v in stats)
     counts = {"expert_tokens": tokens, "expert_max_load": busiest}
     if pool:
@@ -307,27 +316,46 @@ def _decompressed(lp, q_nope, q_pe, c_kv, k_pe, cfg: JoyaiLlmFlashConfig):
     return blocked_attention(q, k, v, block=ATTENTION_BLOCK)
 
 
-def _absorbed(lp, q_nope, q_pe, ckv, kpe, pos, cfg: JoyaiLlmFlashConfig):
-    """The tick's path: one query a row, q_nope / q_pe [B, H, .], against
-    ONE layer's latent rows ckv [B, S, 512] / kpe [B, S, 64], of which row
-    b sees positions 0 .. pos[b]. `W_kvb`'s key half is absorbed into the
-    query and its value half applied to the output, so the H heads attend
-    the latent itself (one shared 576-wide key) -> ctx [B, H, v_head_dim]
-    float32."""
+def _masked_einsums(q_lat, q_pe, ckv, kpe, pos, cfg: JoyaiLlmFlashConfig):
+    """Absorbed attention over ONE layer's whole latent rows ckv [B, S, C]
+    / kpe [B, S, R] under the position mask: scores from both parts of
+    the query, a float32 softmax over the positions 0 .. pos[b], the
+    probabilities' sum over the latent -> o_lat [B, H, C] float32. Reads
+    every position of every slot, `ckv` twice."""
     f32 = jnp.float32
-    H, C = cfg.num_heads, cfg.kv_lora_rank
-    k_b = lp["k_b_w"].reshape(C, H, cfg.qk_nope_head_dim)
-    v_b = lp["v_b_w"].reshape(C, H, cfg.v_head_dim)
-    q_lat = jnp.einsum("bhn,chn->bhc", q_nope, k_b,
-                       preferred_element_type=f32).astype(ckv.dtype)
     s = jnp.einsum("bhc,bsc->bhs", q_lat, ckv, preferred_element_type=f32) \
         + jnp.einsum("bhr,bsr->bhs", q_pe.astype(kpe.dtype), kpe,
                      preferred_element_type=f32)
     s = s / math.sqrt(cfg.qk_head_dim)
     seen = jnp.arange(ckv.shape[1], dtype=jnp.int32)[None, :] <= pos[:, None]
     p = jax.nn.softmax(jnp.where(seen[:, None, :], s, -jnp.inf), axis=-1)
-    o_lat = jnp.einsum("bhs,bsc->bhc", p.astype(ckv.dtype), ckv,
-                       preferred_element_type=f32)
+    return jnp.einsum("bhs,bsc->bhc", p.astype(ckv.dtype), ckv,
+                      preferred_element_type=f32)
+
+
+def _absorbed(lp, q_nope, q_pe, ckv, kpe, at, pos, plan,
+              cfg: JoyaiLlmFlashConfig):
+    """The tick's path: one query a row, q_nope / q_pe [B, H, .], against
+    layer `at` of the latent pools ckv [L, B, S, 512] / kpe [L, B, S, 64],
+    of which row b sees positions 0 .. pos[b]. `W_kvb`'s key half is
+    absorbed into the query and its value half applied to the output, so
+    the H heads attend the latent itself (one shared 576-wide key) -> ctx
+    [B, H, v_head_dim] float32. With a `plan` (`live_latent_plan`: a
+    single-token step on a TPU) the attention between the two absorptions
+    is the Pallas kernel over each row's live blocks, every block read
+    once; without one, `_masked_einsums` over the whole layer."""
+    f32 = jnp.float32
+    H, C = cfg.num_heads, cfg.kv_lora_rank
+    k_b = lp["k_b_w"].reshape(C, H, cfg.qk_nope_head_dim)
+    v_b = lp["v_b_w"].reshape(C, H, cfg.v_head_dim)
+    q_lat = jnp.einsum("bhn,chn->bhc", q_nope, k_b,
+                       preferred_element_type=f32).astype(ckv.dtype)
+    if plan is not None:
+        o_lat = absorbed_attention_live_blocks(q_lat, q_pe, ckv, kpe, at,
+                                               plan, cfg.qk_head_dim)
+    else:
+        o_lat = _masked_einsums(q_lat, q_pe, layer_view(ckv, at),
+                                layer_view(kpe, at), pos, cfg)
     return jnp.einsum("bhc,chv->bhv", o_lat.astype(ckv.dtype), v_b,
                       preferred_element_type=f32)
 
@@ -347,12 +375,12 @@ def _write_latent(pool, rows, pos, at, live):
         rows[:, 0].astype(pool.dtype), mode="drop")
 
 
-def _attention(lp, u, ckv, kpe, at, pos, cos, sin, live,
+def _attention(lp, u, ckv, kpe, at, pos, cos, sin, live, plan,
                cfg: JoyaiLlmFlashConfig):
     """Latent attention on u [B, T, D]: the step's latent goes into layer
     `at` of the pools, then the queries attend — a prompt (T > 1) its own
     decompressed positions, the tick (T = 1) the pool's latent, absorbed
-    -> (out [B, T, D] float32, the pools)."""
+    (`plan`: `_absorbed`) -> (out [B, T, D] float32, the pools)."""
     B, T, _ = u.shape
     q_nope, q_pe, c_kv, k_pe = _project(lp, u, cos, sin, cfg)
     ckv = _write_latent(ckv, c_kv, pos, at, live)
@@ -362,9 +390,8 @@ def _attention(lp, u, ckv, kpe, at, pos, cos, sin, live,
             ctx = _decompressed(lp, q_nope, q_pe, c_kv, k_pe, cfg)
     else:
         with jax.named_scope("mla_absorbed"):
-            ctx = _absorbed(lp, q_nope[:, 0], q_pe[:, 0],
-                            layer_view(ckv, at), layer_view(kpe, at),
-                            jnp.broadcast_to(pos, (B,)), cfg)[:, None]
+            ctx = _absorbed(lp, q_nope[:, 0], q_pe[:, 0], ckv, kpe, at,
+                            jnp.broadcast_to(pos, (B,)), plan, cfg)[:, None]
     out = jnp.einsum("bth,hd->btd", ctx.astype(u.dtype).reshape(B, T, -1),
                      lp["o_w"], preferred_element_type=jnp.float32)
     return out, ckv, kpe
@@ -405,16 +432,17 @@ def _residual(x, out):
 
 
 def _block(tree, at, ffn, x, ckv, kpe, cache_at, pos, cos, sin, live,
-           cfg: JoyaiLlmFlashConfig):
+           cfg: JoyaiLlmFlashConfig, plan=None):
     """One pre-norm block on x [B, T, D]: attention from the leaves at
-    index `at` of `tree`'s stacks into layer `cache_at` of the pools, then
-    `ffn(rows [R, D], live [R]) -> ([R, D] float32, extra)` on chunks of
-    `prefill_chunk` tokens -> (x, ckv, kpe, the chunks' extras)."""
+    index `at` of `tree`'s stacks into layer `cache_at` of the pools (the
+    tick's over `plan`, `_absorbed`), then `ffn(rows [R, D], live [R]) ->
+    ([R, D] float32, extra)` on chunks of `prefill_chunk` tokens ->
+    (x, ckv, kpe, the chunks' extras)."""
     B, T, D = x.shape
     lp = {k: _at_layer(tree[k], at) for k in _ATTENTION}
     u = _rms_norm(x, lp["norm_attn"], cfg.rms_norm_eps).astype(x.dtype)
     out, ckv, kpe = _attention(lp, u, ckv, kpe, cache_at, pos, cos, sin,
-                               live, cfg)
+                               live, plan, cfg)
     x = _residual(x, out)
     u = _rms_norm(x, lp["norm_ffn"], cfg.rms_norm_eps).astype(x.dtype)
     chunk = min(cfg.prefill_chunk, T)
@@ -459,6 +487,9 @@ def _hidden(params, tokens, cache, pos, cfg: JoyaiLlmFlashConfig, live=None):
                             cfg.rope_theta)
     x = jnp.take(params["wte"], tokens, axis=0).astype(cfg.dtype)
     n_dense = cfg.dense_layers
+    # once, ahead of both scans: inside them XLA would redo the small
+    # index arithmetic every layer
+    plan = live_latent_plan(T, cache["ckv"], pos, live)
 
     def dense_layer(carry, i):
         x, ckv, kpe = carry
@@ -468,7 +499,7 @@ def _hidden(params, tokens, cache, pos, cfg: JoyaiLlmFlashConfig, live=None):
                 return _mlp(rows, *(_at_layer(params[n], i) for n in
                                     ("gate_w", "up_w", "down_w"))), None
         x, ckv, kpe, _ = _block(params, i, ffn, x, ckv, kpe, i, pos, cos,
-                                sin, live, cfg)
+                                sin, live, cfg, plan)
         return (x, ckv, kpe), None
 
     def expert_layer(carry, j):
@@ -476,7 +507,7 @@ def _hidden(params, tokens, cache, pos, cfg: JoyaiLlmFlashConfig, live=None):
         x, ckv, kpe, loads = _block(
             params, n_dense + j, lambda rows, lv: _experts(params, j, rows,
                                                            lv, cfg),
-            x, ckv, kpe, n_dense + j, pos, cos, sin, live, cfg)
+            x, ckv, kpe, n_dense + j, pos, cos, sin, live, cfg, plan)
         load = sum(loads)
         return (x, ckv, kpe, on_held + load.sum(),
                 jnp.maximum(busiest, load.max())), None
@@ -487,9 +518,11 @@ def _hidden(params, tokens, cache, pos, cfg: JoyaiLlmFlashConfig, live=None):
     x, ckv, kpe, on_held, busiest = _scan_layers(
         expert_layer, carry + (zero, zero), cfg.expert_layers)
     x = _rms_norm(x, params["norm_f"], cfg.rms_norm_eps).astype(cfg.dtype)
-    # the tick's einsum reads every position of every slot, whatever is live
+    # the tick's einsums read every position of every slot, whatever is
+    # live; the kernel the blocks its work list names
     held = B * ckv.shape[2] if T == 1 else 0
-    stats = jnp.stack([on_held, busiest, jnp.int32(held), jnp.int32(held)])
+    read = held if plan is None else plan[3][0] * LATENT_BLOCK
+    stats = jnp.stack([on_held, busiest, jnp.int32(read), jnp.int32(held)])
     return x, {"ckv": ckv, "kpe": kpe, "stats": stats}
 
 

@@ -15,6 +15,7 @@ un-normalised latent each move logits by 1e-2 and more at these sizes.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -28,6 +29,7 @@ from paddle_tpu.inference.serving import (REFUSABLE, ServingEngine,
                                           UnsupportedOptionError,
                                           family_for)
 from paddle_tpu.kernels import decode_attention
+from paddle_tpu.kernels import latent_attention as la
 from paddle_tpu.models import joyai_llm_flash as m
 from paddle_tpu.parallel import moe
 from paddle_tpu.profiler import clear_profiler_spans, get_profiler_spans
@@ -218,7 +220,8 @@ def test_absorbed_equals_decompressed_on_the_same_cache(setup):
     kpe = jnp.asarray(rng.standard_normal((B, S, dr)), jnp.float32)
     pos = jnp.asarray([0, 7, 23], jnp.int32)
     lp = {k: params[k][2] for k in ("k_b_w", "v_b_w")}
-    got = np.asarray(m._absorbed(lp, q_nope, q_pe, ckv, kpe, pos, cfg))
+    got = np.asarray(m._absorbed(lp, q_nope, q_pe, ckv[None], kpe[None], 0,
+                                 pos, None, cfg))
     hi = jax.lax.Precision.HIGHEST
     k_nope = jnp.einsum("bsc,ch->bsh", ckv, lp["k_b_w"],
                         precision=hi).reshape(B, S, H, dn)
@@ -652,11 +655,50 @@ def test_counts_ride_the_one_pull_onto_the_spans(setup):
         c = s.counts
         assert c["expert_tokens"] <= c["active"] * k * expert_layers
         assert c["expert_max_load"] <= c["active"]
-        # the einsum reads every position of every slot: the honest 100%
+        # the einsums read every position of every slot: the honest 100%
+        # (where the kernel runs, `kv_read_layer` is its work list's
+        # blocks: tests/test_mla_absorbed_kernel.py)
         assert c["kv_positions_read"] == c["kv_positions_pool"] \
             == cfg.num_layers * 2 * 64
         assert c["latent_bytes"] == c["kv_positions_read"] * 20 * 4
     router.close()
+
+
+def _as_tpu(monkeypatch):
+    """The tick as it decides on the chip, its kernel in the interpreter
+    (a test steers what `is_tpu()` answers; the program has no option)."""
+    import paddle_tpu.device as device
+    monkeypatch.setattr(device, "is_tpu", lambda: True)
+    monkeypatch.setattr(m, "absorbed_attention_live_blocks",
+                        functools.partial(la.absorbed_attention_live_blocks,
+                                          interpret=True))
+
+
+def test_kv_read_layer_is_the_work_lists_blocks_where_the_kernel_runs(
+        monkeypatch):
+    """`kv_read_layer` of the "stats" leaf: slots x positions under the
+    einsums, the work list's `total x block` under the kernel — from
+    `live` and the positions, an idle row nothing —, `kv_pool_layer`
+    slots x positions either way; `span_counts` multiplies by the layers
+    and the bytes of a position."""
+    block = la.LATENT_BLOCK
+    cfg = make_cfg(kv_lora_rank=128, num_layers=2, max_seq_len=2 * block)
+    params = _serving_params(make_params(cfg))
+    cache = m.init_cache(cfg, 4, 2 * block)
+    pos = jnp.asarray([3, block - 1, block, 9], jnp.int32)
+    live = jnp.asarray([True, True, True, False])[:, None]
+    toks = jnp.ones((4, 1), jnp.int32)
+    _, plain = m.joyai_llm_flash_forward_cached(params, toks, cache, pos,
+                                                cfg, live)
+    assert [int(v) for v in plain["stats"][2:]] == [8 * block, 8 * block]
+    _as_tpu(monkeypatch)
+    _, cache = m.joyai_llm_flash_forward_cached(params, toks, cache, pos,
+                                                cfg, live)
+    assert [int(v) for v in cache["stats"][2:]] == [4 * block, 8 * block]
+    counts = m.span_counts(cfg, cache["stats"])
+    assert counts["kv_positions_read"] == 2 * 4 * block
+    assert counts["kv_positions_pool"] == 2 * 8 * block
+    assert counts["latent_bytes"] == 2 * 4 * block * (128 + 4) * 4
 
 
 def test_the_named_scopes_are_in_both_programs(setup):
@@ -676,6 +718,22 @@ def test_the_named_scopes_are_in_both_programs(setup):
         assert scope in tick and scope in prompt, scope
     assert "mla_absorbed" in tick and "mla_prefill" not in tick
     assert "mla_prefill" in prompt and "mla_absorbed" not in prompt
+
+
+def test_the_kernel_sits_in_the_ticks_own_scope(monkeypatch):
+    """Where the tick's attention is the Pallas kernel it keeps the
+    einsums' scope: `mla_absorbed/mla_absorbed_live_blocks`."""
+    _as_tpu(monkeypatch)
+    cfg = make_cfg(kv_lora_rank=128, num_layers=2)
+    params = _serving_params(make_params(cfg))
+    tick = jax.jit(lambda p, t, c, pos: m.joyai_llm_flash_forward_cached(
+        p, t, c, pos, cfg)).trace(
+        params, jnp.zeros((2, 1), jnp.int32),
+        m.init_cache(cfg, 2, la.LATENT_BLOCK),
+        jnp.zeros((2,), jnp.int32)).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "mla_absorbed/mla_absorbed_live_blocks" in tick
+    assert "bhs,bsc->bhc" not in tick                 # no einsum beside it
 
 
 @pytest.mark.parametrize("option,kw", [

@@ -125,6 +125,19 @@ def _decode_live_blocks():
         interpret=True))(q, pool)
 
 
+def _mla_live_blocks():
+    from paddle_tpu.kernels import decode_attention as da
+    from paddle_tpu.kernels import latent_attention as la
+    S = la.LATENT_BLOCK
+    ckv = jnp.zeros((2, 2, S, 128), jnp.bfloat16)
+    kpe = jnp.zeros((2, 2, S, 64), jnp.bfloat16)
+    return jax.make_jaxpr(lambda ckv, kpe: la.absorbed_attention_live_blocks(
+        jnp.zeros((2, 8, 128), jnp.bfloat16),
+        jnp.zeros((2, 8, 64), jnp.bfloat16), ckv, kpe, jnp.int32(1),
+        da.work_list(jnp.int32(5), None, 2, S, S), 192,
+        interpret=True))(ckv, kpe)
+
+
 KERNELS = {
     "flash_fwd": _flash_fwd,
     "flash_bwd_dq": _flash_bwd,
@@ -137,6 +150,7 @@ KERNELS = {
     "adamw_update": _adamw,
     "quant_matmul": _quant,
     "decode_attention_live_blocks": _decode_live_blocks,
+    "mla_absorbed_live_blocks": _mla_live_blocks,
 }
 
 
