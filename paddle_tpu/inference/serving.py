@@ -245,7 +245,8 @@ tick_records() / telemetry_jsonl=), request-scoped tracing
 (tracing= — parented spans submit -> prefill chunks -> decode ->
 the exactly-once terminal _finish; profiler/tracing) and
 RecordEvent spans through the tick (serving.tick > admit > prefill,
-upload, decode_tick — docs/observability.md has the table) —
+upload, decode_tick > decode_dispatch + decode_pull, emit —
+docs/observability.md has the table) —
 tools/telemetry_report.py summarizes them (including TTFT /
 inter-token-latency percentiles from `export_slo_jsonl` and a
 "kv pool" block), tools/bench_serving.py measures the engine against
@@ -1090,6 +1091,8 @@ class ServingEngine:
         self._max_new = np.zeros(n, np.int32)
         self._daux = None
         self._dstate = None                       # device state tuple
+        self._tick_rows = None         # request of each row in the last tick
+        self._last_pull_end = 0.0      # perf_counter when its pull returned
         self._dirty = True
         self._slot_req: List[Optional[Request]] = [None] * n
         self._queue: collections.deque = collections.deque()
@@ -1502,7 +1505,6 @@ class ServingEngine:
         The spill/swap-in COUNTERS advance at event time instead
         (_spill_page / _admit_paged) — process-global counters can't
         take last-writer deltas with several engines alive."""
-        self._m_ticks_pull.set(self.mt_k)
         if self._host_tier is not None:
             self._m_kv_host.set(self._host_tier.bytes)
 
@@ -2031,6 +2033,7 @@ class ServingEngine:
             self._pt_dirty = False
         self._cache = self._new_cache()
         self._dstate = None
+        self._tick_rows = None          # no row carries across a reset
         self._dirty = True
         self._flight.configure(last_serving_fault=f"hard_reset: {reason}")
         self._flight.dump("serving_hard_reset")
@@ -2148,6 +2151,7 @@ class ServingEngine:
                                  active=int(self._active.sum()),
                                  slots=self.num_slots,
                                  **self._kv_read_counts()) as dev:
+                    rows = self._since_last_tick(dev)
                     if self.spec:
                         dpoison = self._poison_ones
                         if draft_slot is not None:
@@ -2157,14 +2161,14 @@ class ServingEngine:
                         draft_slot = None     # injected at most once
                         args = (self._params, self._cache, self._dstate,
                                 self._base_key, poison, dpoison)
-                        if self.mt_k > 1:
-                            args += self._daux
-                        out = self._decode(*args, sampling=sampling)
                     else:
                         args = (self._params, self._cache, self._dstate,
                                 self._base_key, poison)
-                        if self.mt_k > 1:
-                            args += self._daux
+                    if self.mt_k > 1:
+                        args += self._daux
+                    # the HOST enqueueing the program: it returns when
+                    # the program is queued, not when it ran
+                    with RecordEvent("serving.decode_dispatch"):
                         out = self._decode(*args, sampling=sampling)
                     # ONE host pull per tick ([N] non-spec; the
                     # [N, gamma+1] emission matrix under spec) — with
@@ -2179,8 +2183,13 @@ class ServingEngine:
                         fetch = (nxt,)
                     if self.family.counts:
                         fetch += (self._cache["stats"],)
-                    got = self._pull(fetch if len(fetch) > 1 else nxt,
-                                     stall_s)
+                    # the host blocked on the program and on the
+                    # result coming back
+                    with RecordEvent("serving.decode_pull") as pull:
+                        got = self._pull(fetch if len(fetch) > 1 else nxt,
+                                         stall_s)
+                    # only a tick whose pull came back is "the last tick"
+                    self._last_pull_end, self._tick_rows = pull.end_s, rows
                     got = got if len(fetch) > 1 else (got,)
                     toks = got[0]
                     tele_row = got[1] if self._tick_tele else None
@@ -2213,6 +2222,35 @@ class ServingEngine:
             self._m_qmm.add(self._qmm_full
                             + (self.spec_gamma * self._qmm_draft
                                if self.spec else 0))
+        with RecordEvent("serving.emit"):
+            self._emit_tick(toks, tele_row, tick_ms, events)
+
+    def _since_last_tick(self, span: RecordEvent):
+        """Set on the just-opened `serving.decode_tick` span the two
+        counts of what a decoding row waited through since the previous
+        tick: `since_last_ms`, from that tick's pull returning to the span
+        opening (prefills, uploads, emission, the router, the caller's
+        loop), and `carried`, the rows active in both ticks under the same
+        request — host mirrors only. Every reader of the two reads traced
+        spans, so with no profiler session recording nothing is computed
+        and the next traced tick, like an engine's first, carries
+        neither. -> the request of each row in this tick (None untraced),
+        which the caller keeps once the tick's pull has come back."""
+        if not span.in_trace:
+            return None
+        rows = np.where(self._active, self._req_ids, -1)
+        if self._tick_rows is not None:
+            span.set(
+                since_last_ms=(span.start_s - self._last_pull_end) * 1e3,
+                carried=int(((rows == self._tick_rows)
+                             & self._active).sum()))
+        return rows
+
+    def _emit_tick(self, toks, tele_row, tick_ms: float,
+                   events: list) -> None:
+        """The per-row Python after the tick's pull (`serving.emit`): the
+        telemetry record, then mirrors, SLO samples and finish checks of
+        every active row, in the tick's form."""
         if self._tick_log is not None:
             host = {"queue_depth": len(self._queue)}
             if self.paged:
@@ -3033,6 +3071,7 @@ class ServingEngine:
         the SOURCE engine's id — `_slot_keys` folds the mirror, not
         the Request, into the stream, so sampled continuations are
         bit-identical to the undisturbed engine."""
+        self._tick_rows = None     # a restored row did not tick HERE before
         pos = int(snap["pos"])
         kv_k, kv_v = snap["kv_k"], snap["kv_v"]
         if self.paged:

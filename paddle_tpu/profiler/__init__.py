@@ -20,7 +20,9 @@ TPU-native design — ONE recorder, `RecordEvent`, seen from two sides:
   sits on the device trace's own clock beside the XLA ops. "Tracing on"
   means exactly that — a session is recording; there is no other switch.
   With none running an annotation records nothing and a span costs one
-  `deque.append`.
+  `deque.append`. `device_trace` reads the file a session wrote: the
+  device's programs, their scopes and idle gaps beside these spans on one
+  clock (`load_profiler_result`, `Profiler.summary()`).
   `export_chrome_trace(path)` additionally renders the host spans as a
   standalone chrome-trace JSON (Perfetto / chrome://tracing), written
   beside the device trace on Profiler.stop().
@@ -149,20 +151,24 @@ class RecordEvent:
     keyword arguments are the span's COUNTS (ints, floats, short
     strings): they ride the `TraceAnnotation` into the device trace as
     the event's stats and sit in the span's ring record; `set(**counts)`
-    adds the ones known only at the end. After exit `dur_s` holds the
-    duration, so a call site needs no clock pair of its own."""
+    adds the ones known only inside. Once open, `in_trace` says whether
+    a profiler session was recording and `start_s` is the perf_counter
+    reading it opened at; after exit `dur_s` holds the duration and
+    `end_s` the reading it ended at, so a call site needs no clock pair
+    of its own."""
 
     def __init__(self, name: str, event_type=None, **counts):
         self.name = name
         self.counts = counts or None
+        self.in_trace = False
+        self.start_s = None
         self.dur_s = None
-        self._start = None
-        self._annot = None
-        self._in_trace = False
+        self.end_s = None
+        self._annot = None              # the open annotation, else None
 
     def begin(self):
-        self._in_trace = _TraceAnnotation.is_enabled()
-        self._start = time.perf_counter()
+        self.in_trace = _TraceAnnotation.is_enabled()
+        self.start_s = time.perf_counter()
         _LOG.push()
         self._annot = _TraceAnnotation(self.name, **(self.counts or {}))
         self._annot.__enter__()
@@ -178,14 +184,14 @@ class RecordEvent:
             self._annot.set_metadata(**counts)
 
     def end(self):
-        if self._start is None:
+        if self._annot is None:
             return
         self._annot.__exit__(None, None, None)
         self._annot = None
-        self.dur_s = time.perf_counter() - self._start
-        _LOG.pop(self.name, self._start, self.dur_s, self.counts,
-                 self._in_trace)
-        self._start = None
+        self.end_s = time.perf_counter()
+        self.dur_s = self.end_s - self.start_s
+        _LOG.pop(self.name, self.start_s, self.dur_s, self.counts,
+                 self.in_trace)
 
     __enter__ = begin
 
@@ -352,7 +358,10 @@ class Profiler:
     def summary(self, sorted_by=None, op_detail=True, thread_sep=False,
                 time_unit="ms") -> str:
         """Host-span stats table + step-time stats (the reference's
-        profiler_statistic tables, host side)."""
+        profiler_statistic tables, host side), and, where this
+        profiler's `trace_dir` holds an `.xplane.pb`, the device view of
+        the newest one (`device_trace`: programs, scopes, the clock, idle
+        by innermost span)."""
         unit = {"s": 1.0, "ms": 1e3, "us": 1e6}.get(time_unit, 1e3)
         agg = {}
         for name, _start, dur, *_rest in _LOG.snapshot():
@@ -372,6 +381,15 @@ class Profiler:
                 f"p50 {st[n // 2] * unit:.3f}{time_unit}  "
                 f"min {st[0] * unit:.3f}{time_unit}  "
                 f"max {st[-1] * unit:.3f}{time_unit}")
+        if self._trace_dir is not None:
+            from . import device_trace
+            try:
+                view = device_trace.load_device_view(self._trace_dir)
+            except FileNotFoundError:       # no session wrote there yet
+                pass
+            else:
+                lines += ["", f"trace {view['path']}",
+                          device_trace.format_view(view)]
         return "\n".join(lines)
 
     @property
@@ -389,10 +407,19 @@ def clear_profiler_spans():
     _LOG.clear()
 
 
-def load_profiler_result(filename: str):
-    raise NotImplementedError(
-        "XLA traces are TensorBoard artifacts; point TensorBoard at the "
-        "trace dir passed to export_chrome_tracing instead.")
+def load_profiler_result(filename: str, window: str = None) -> dict:
+    """The device view of a profiler trace (reference profiler.py
+    `load_profiler_result`): `filename` is an `.xplane.pb` or the trace
+    directory a session wrote (`Profiler(trace_dir=...)`, the newest
+    trace under it). Returns `device_trace.load_device_view`'s dict —
+    programs of the `XLA Modules` line, own device time by scope, the
+    device line's offset against the host's with launch and return
+    latency, idle gaps by the innermost program span (over `window`, the
+    name of a host span the caller put around what it measures; first to
+    last device event without one), the spans' self times — which
+    `device_trace.format_view` prints."""
+    from .device_trace import load_device_view
+    return load_device_view(filename, window)
 
 
 def cost_analysis(fn, *example_args, **jit_kwargs):
@@ -429,7 +456,8 @@ def __getattr__(name):
     # (serving_telemetry / tracing / slo are jax-free but ride the same
     # lazy seam so the profiler package stays import-light)
     if name in ("telemetry", "flight_recorder", "serving_telemetry",
-                "tracing", "slo", "hlo_audit", "mem_audit"):
+                "tracing", "slo", "hlo_audit", "mem_audit",
+                "device_trace"):
         import importlib
         return importlib.import_module(f".{name}", __name__)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
@@ -448,7 +476,10 @@ class SortedKeys(enum.Enum):
 
 
 class SummaryView(enum.Enum):
-    """reference profiler.py:46 — summary views."""
+    """reference profiler.py:46 — summary views. `Profiler.summary()`
+    prints the host spans (OverView) and, where its trace directory holds
+    a trace, `device_trace`'s tables: the programs (DeviceView) and
+    their own time by scope and kernel (KernelView)."""
     DeviceView = 0
     OverView = 1
     ModelView = 2
