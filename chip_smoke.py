@@ -62,6 +62,8 @@ class Sizes:
     qmm_shapes: Tuple[Tuple[int, int, int], ...]          # [M, K, N]
     decode_pool: Tuple[int, int, int, int, int]           # [L, B, S, KV, hd]
     latent_pool: Tuple[int, int, int, int, int, int]      # [L, B, S, C, R, H]
+    # one chunk of a prompt's queries: [Tq, T, H, KV, hd, window, q_offset]
+    prompt_chunk: Tuple[int, int, int, int, int, int, int]
 
 
 # the published widths: GPT-350M (24L x 1024d x 16 heads, hd 64) trains at
@@ -78,6 +80,8 @@ REAL = Sizes(
     decode_pool=(4, 8, 1024, 16, 128),      # four layers of the serve pool
     # two layers of JoyAI-LLM-Flash's latent pools at a quarter the length
     latent_pool=(2, 8, 4096, 512, 64, 32),
+    # Command A+'s third prompt chunk of an 8,192 bucket, window layer
+    prompt_chunk=(2048, 8192, 128, 8, 128, 4096, 4096),
 )
 
 _TINY_GPT = dict(vocab_size=640, hidden_size=128, num_layers=2, num_heads=2,
@@ -95,6 +99,7 @@ TINY = Sizes(
     qmm_shapes=((16, 256, 512), (16, 512, 256)),
     decode_pool=(2, 4, 256, 8, 128),
     latent_pool=(2, 4, 2048, 128, 64, 8),
+    prompt_chunk=(256, 512, 32, 2, 128, 200, 256),
 )
 
 # bf16 compute rounds to 8 bits of mantissa; losses of two layouts of one
@@ -395,6 +400,20 @@ def kernel_cases(sizes: Sizes):
                   (S((LL, LB, LP, C), bf16), S((LL, LB, LP, R), bf16),
                    S((LB, H, C), bf16), S((LB, H, R), bf16),
                    S((LB,), i32)), 1e-2))
+
+    # a prompt chunk's attention against blocked_attention's jnp blocks
+    Tq, PT, PH, PKV, phd, window, offset = sizes.prompt_chunk
+
+    def prompt(q, k, v):
+        return pallas_attention.prompt_flash(q, k, v, window=window,
+                                             q_offset=offset)
+
+    def prompt_oracle(q, k, v):
+        return da._blocked_scan(q, k, v, window, 512, offset)
+
+    cases.append(("prompt_flash", prompt, prompt_oracle,
+                  (S((1, Tq, PH, phd), bf16),) + (S((1, PT, PKV, phd), bf16),)
+                  * 2, 1e-2))
     return cases
 
 

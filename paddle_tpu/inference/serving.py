@@ -368,8 +368,11 @@ class ModelFamily:
     leaves a row of int32 counts in the cache's "stats" leaf: it turns
     the pulled row into span counts by name. The forward then takes
     `live=` (which rows are real tokens), and the row rides the tick's
-    one pull onto the `serving.decode_tick` / `serving.prefill` spans. `refuses` names the engine options (`REFUSABLE`) that raise
-    UnsupportedOptionError for this family."""
+    one pull onto the `serving.decode_tick` / `serving.prefill` spans.
+    `prompt_counts` is for a family that counts, on the host, what a
+    prompt bucket's program does by its shapes alone: the engine sets
+    them on `serving.prefill`. `refuses` names the engine options
+    (`REFUSABLE`) that raise UnsupportedOptionError for this family."""
     name: str
     forward_cached: Callable    # (params, tokens[B,T], cache, pos, cfg)
     init_cache: Callable        # (cfg, batch, max_len) -> pools by kind
@@ -377,6 +380,7 @@ class ModelFamily:
     prefill: Optional[Callable] = None   # (params, cache, padded [1,Tb],
     #                 true_len, slot, cfg) -> (last logits [1,V], cache)
     counts: Optional[Callable] = None    # (cfg, stats row) -> dict
+    prompt_counts: Optional[Callable] = None    # (cfg, bucket) -> dict
     refuses: Tuple[str, ...] = ()
 
 
@@ -402,21 +406,23 @@ def _cohere2_moe_family() -> ModelFamily:
     from ..models import cohere2_moe as m
     return ModelFamily("cohere2_moe", m.cohere2_moe_forward_cached,
                        m.init_kv_cache, None, prefill=m.prefill_into_slot,
-                       counts=m.span_counts, refuses=REFUSABLE)
+                       counts=m.span_counts, prompt_counts=m.prompt_counts,
+                       refuses=REFUSABLE)
 
 
 def _jamba_family() -> ModelFamily:
     from ..models import jamba as m
     return ModelFamily("jamba", m.jamba_forward_cached, m.init_cache, None,
                        prefill=m.prefill_into_slot, counts=m.span_counts,
-                       refuses=REFUSABLE)
+                       prompt_counts=m.prompt_counts, refuses=REFUSABLE)
 
 
 def _joyai_llm_flash_family() -> ModelFamily:
     from ..models import joyai_llm_flash as m
     return ModelFamily("joyai_llm_flash", m.joyai_llm_flash_forward_cached,
                        m.init_cache, None, prefill=m.prefill_into_slot,
-                       counts=m.span_counts, refuses=REFUSABLE)
+                       counts=m.span_counts, prompt_counts=m.prompt_counts,
+                       refuses=REFUSABLE)
 
 
 # name -> factory; a family's module is imported when it is asked for
@@ -2512,8 +2518,10 @@ class ServingEngine:
                 self._rep([req.temperature], np.float32),
                 self._rep([req.top_k], np.int32),
                 self._rep([req.id], np.int32))
+        shapes = (self.family.prompt_counts(self.cfg, tb)
+                  if self.family.prompt_counts else {})
         with RecordEvent("serving.prefill", request=req.id, true_len=t0,
-                         bucket=tb) as pf:
+                         bucket=tb, **shapes) as pf:
             first, self._cache = self._prefill(
                 self._params, self._cache, *args, self._base_key,
                 sampling=req.temperature > 0.0)

@@ -45,6 +45,11 @@ einsum reads every position of every slot and masks afterwards, and in
 a serving pool most of them are dead. Same arithmetic class as 'dense';
 only the order of summation differs.
 
+A whole prompt attends its own keys through `blocked_attention`, which
+on a TPU hands the call to the Pallas prompt kernel
+(pallas_attention.prompt_flash) where `prompt_kernel_engages`, and
+otherwise runs its `jnp` blocks.
+
 The attention math is one of two, chosen by DECODE_ATTN_IMPL below
 (`cached_attention(impl=)` overrides it for one call):
 - 'dense'  f32 scores AND f32 context accumulation (exactly the
@@ -81,6 +86,7 @@ __all__ = ["write_kv", "cached_attention", "gather_pages",
            "write_kv_paged", "layer_view", "cache_pspecs",
            "attended_tokens", "kv_view_extent", "ring_positions",
            "ring_rows", "blocked_attention", "DECODE_BLOCK",
+           "prompt_kernel_engages", "causal_pairs",
            "kv_positions_read", "length_aware", "length_aware_attention",
            "live_block_plan", "work_list"]
 
@@ -539,6 +545,38 @@ def _native_attention(q, kc, vc, pos, window):
     return ctx.reshape(B, T, H, hd)
 
 
+def prompt_kernel_engages(Tq: int, T: int, H: int, KV: int, hd: int,
+                          vd: int, dtype, q_offset=0) -> bool:
+    """Whether a `blocked_attention` call of these shapes runs the Pallas
+    prompt kernel (pallas_attention.prompt_flash) — by what the call can
+    observe, no option: a TPU, no ambient mesh of several devices (GSPMD
+    cannot partition a Mosaic kernel), a static query offset whose rows
+    lie among the keys, whole groups of query heads a K/V head, bf16 or
+    float32 operands, and `prompt_tile` has a tile for the shapes. The
+    families' prompt counts ask the same question on the host."""
+    from ..device import is_tpu
+    from ..parallel.mesh import get_mesh
+    from .pallas_attention import prompt_tile
+    mesh = get_mesh()
+    dtype = jnp.dtype(dtype)
+    return (is_tpu() and (mesh is None or mesh.size == 1)
+            and isinstance(q_offset, int) and 0 <= q_offset
+            and q_offset + Tq <= T and H % KV == 0
+            and dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+            and prompt_tile(Tq, T, hd, vd, H // KV, dtype.itemsize)
+            is not None)
+
+
+def causal_pairs(start: int, stop: int, window: int | None = None) -> int:
+    """Host-side: the (query, key) pairs `blocked_attention` admits for
+    the queries at positions start..stop-1 — query i sees min(i + 1,
+    window) keys."""
+    def upto(n):
+        w = n if window is None else min(n, window)
+        return w * (w + 1) // 2 + (n - w) * w
+    return upto(stop) - upto(start)
+
+
 _MASKED = -1e30     # finite: a block a row sees nothing of leaves no nan
 
 
@@ -555,7 +593,23 @@ def blocked_attention(q, k, v, window: int | None = None,
     blocks it can see with a running softmax (float32 max, sum and
     accumulator; operands in their own dtype), so the scores of a 16k
     prompt never exist at once, and key blocks wholly ahead of the
-    rows or wholly outside the window are never touched."""
+    rows or wholly outside the window are never touched.
+
+    Where `prompt_kernel_engages` (a TPU, shapes its tile rule admits)
+    the same arithmetic runs as one Pallas kernel,
+    pallas_attention.prompt_flash, whose running softmax stays in VMEM;
+    the `jnp` blocks below are the other platforms' form and the
+    kernel's reference."""
+    B, Tq, H, hd = q.shape
+    if prompt_kernel_engages(Tq, k.shape[1], H, k.shape[2], hd, v.shape[-1],
+                             k.dtype, q_offset):
+        from .pallas_attention import prompt_flash
+        return prompt_flash(q, k, v, window=window, q_offset=q_offset)
+    return _blocked_scan(q, k, v, window, block, q_offset)
+
+
+def _blocked_scan(q, k, v, window, block, q_offset):
+    """`blocked_attention`'s `jnp` form."""
     B, Tq, H, hd = q.shape
     T, KV, vd = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV

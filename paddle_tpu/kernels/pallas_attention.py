@@ -1,6 +1,12 @@
 """Hand-tiled Pallas TPU flash-attention kernels: the 128x128 forward /
 dq / dkv kernels over [B*H, S, D] (on no path of the TPU's; ROADMAP D2),
-and below them the tiled pair the training attention runs (`tiled_mha`).
+below them the tiled pair the training attention runs (`tiled_mha`), and
+last the serving families' prompt kernel (`prompt_flash`, named
+`prompt_flash_fwd`: decode_attention.blocked_attention's contract, the
+query heads of a K/V head folded into the rows of one tile). The serving
+path's other Pallas kernels are decode_attention's
+`decode_attention_live_blocks` and latent_attention's
+`mla_absorbed_live_blocks` (docs/kernel_selection.md has who runs which).
 
 Reference analog: the external flash-attention CUDA library the reference
 wires in via cmake/external/flashattn.cmake and exposes through
@@ -20,6 +26,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -640,3 +647,203 @@ def _tiled_mha_bwd_rule(causal, interpret, res, do):
 
 
 tiled_mha.defvjp(_tiled_mha_fwd_rule, _tiled_mha_bwd_rule)
+
+
+# ------------------------------------------------------------ prompt flash
+# The serving families' prompt attention (decode_attention.blocked_attention
+# on a TPU): a prompt's queries against the prompt's own keys, causal with
+# an optional window, q and k of one width and v of its own. The arrays are
+# read where they lie, as [B, T, heads * width] — a K/V head's keys are one
+# lane block, the G = H / KV query heads that share it the next G — and
+# the kernel makes the G heads' queries the rows of ONE tile, [G * bq, hd]
+# head by head, so a key block is read once for the group. A grid step owns
+# one q tile of one K/V head and walks, in loops inside the kernel, the key
+# blocks its rows can see; the running max, sum and accumulator sit in VMEM
+# scratch (float32), the operands stay in their dtype. A K/V head's first
+# step fetches the key and value blocks the call's queries can reach, and
+# no other (blocks wholly ahead of the queries or wholly outside their
+# window stay in HBM); blocks a tile's rows cannot see are not computed
+# (the loops' bounds); only the blocks on the diagonal or across the
+# window's edge are masked. The query offset is an operand, not a constant
+# of the kernel: the chunks of one prompt share one compiled kernel.
+PROMPT_ROWS = 1024                    # rows of a q tile, G * bq, at most
+PROMPT_VMEM_BUDGET = 80 * 2 ** 20     # of a v5e core's 128 MiB of VMEM
+
+
+def prompt_vmem_bytes(T: int, hd: int, vd: int, rows: int, bk: int,
+                      itemsize: int) -> int:
+    """What one grid step holds in VMEM: the reachable keys and values (at
+    most T positions), the q and out tiles double-buffered, the q rows,
+    the float32 accumulator and the lane-padded max and sum, and half a
+    dozen (rows x bk) float32 temporaries of the score tile."""
+    io = (T + 2 * rows) * (hd + vd) * itemsize
+    own = rows * hd * itemsize + rows * vd * 4 + 2 * rows * LANES * 4
+    return io + own + 6 * rows * bk * 4
+
+
+def prompt_tile(Tq: int, T: int, hd: int, vd: int, G: int,
+                itemsize: int = 2):
+    """(bq, bk) for Tq queries of G heads a K/V head over T keys, or None
+    where the kernel does not apply. One rule for every model: widths of
+    whole 128-lane groups (a head is a lane block of the array as it
+    lies); key blocks of the largest of 1024 / 512 / 256 / 128 that
+    divides T (a loop trip rescales the whole accumulator, so fewer,
+    longer trips win: docs/kernel_selection.md); q tiles of the largest
+    power of two bq <= 512 that divides Tq with G * bq <= PROMPT_ROWS
+    rows (at least 16: whole bf16 sublanes), halved while a grid step
+    does not fit PROMPT_VMEM_BUDGET."""
+    if hd % LANES or vd % LANES:
+        return None
+    bk = next((b for b in (1024, 512, 256, 128) if T % b == 0), None)
+    bq = 512
+    while bq >= 16 and (Tq % bq or G * bq > PROMPT_ROWS or
+                        prompt_vmem_bytes(T, hd, vd, G * bq, bk or 0,
+                                          itemsize) > PROMPT_VMEM_BUDGET):
+        bq //= 2
+    return (bq, bk) if bk and bq >= 16 else None
+
+
+def _prompt_fwd_kernel(at_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
+                       sem, q_rows, m_ref, l_ref, acc_ref, *, scale, bq,
+                       bk):
+    """One q tile (`bq` positions of G heads, the first at `first + i *
+    bq`) of one K/V head against the reachable keys: `n` blocks from
+    position `lo` (at_ref = [first, lo, n, window]; a layer without a
+    window passes one no distance reaches), which the head's first step
+    copies into k_buf / v_buf. Key blocks [j0, j1] hold a key some row
+    sees; of them [a, b) every row sees whole, and the rest are masked.
+    Block arithmetic is on non-negative integers, bk a power of two."""
+    b_, h, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    first, lo, n, window = at_ref[0], at_ref[1], at_ref[2], at_ref[3]
+    rows, hd = q_rows.shape
+    vd = acc_ref.shape[1]
+    G, shift = rows // bq, bk.bit_length() - 1
+
+    def fetch(j):
+        keys = pl.ds(pl.multiple_of(lo + j * bk, bk), bk)
+        into = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        return (pltpu.make_async_copy(
+                    k_hbm.at[b_, keys, pl.ds(h * hd, hd)], k_buf.at[into],
+                    sem.at[0]),
+                pltpu.make_async_copy(
+                    v_hbm.at[b_, keys, pl.ds(h * vd, vd)], v_buf.at[into],
+                    sem.at[1]))
+
+    @pl.when(i == 0)
+    def _():
+        def each(j, start):
+            for c in fetch(j):
+                c.start() if start else c.wait()
+        jax.lax.fori_loop(0, n, lambda j, c: each(j, True), None)
+        jax.lax.fori_loop(0, n, lambda j, c: each(j, False), None)
+
+    top = first + i * bq - lo             # the tile's first query, from lo
+    bottom = top + bq - 1
+    j0 = jax.lax.max(top - (window - 1), 0) >> shift
+    j1 = bottom >> shift
+    b = (top + 1) >> shift                # the first block with a key > top
+    # the first block whose oldest key the newest row still sees
+    a = jax.lax.min(jax.lax.max(
+        jax.lax.max(bottom - (window - 1), 0) + (bk - 1) >> shift, j0), b)
+    q = q_ref[...]
+    for g in range(G):
+        q_rows[g * bq:(g + 1) * bq, :] = q[:, g * hd:(g + 1) * hd]
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(j, masked):
+        start = pl.multiple_of(j * bk, bk)
+        k = k_buf[pl.ds(start, bk), :]
+        v = v_buf[pl.ds(start, bk), :]
+        s = jax.lax.dot_general(q_rows[...], k, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            qpos = top + (jax.lax.broadcasted_iota(
+                jnp.int32, (rows, bk), 0) & (bq - 1))
+            kpos = tile_positions(j, bk, (rows, bk), 1)
+            seen = jnp.logical_and(causal_mask(qpos, kpos),
+                                   qpos - kpos < window)
+            s = jnp.where(seen, s, _NEG_INF)
+        m_new, l_new, p, corr = online_softmax_update(m_ref[...], l_ref[...],
+                                                      s)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        l_ref[...] = l_new
+
+    # in key order: the masked blocks across the window's edge [j0, a),
+    # the whole ones [a, b), the masked ones on the diagonal [b, j1]
+    edge = a - j0
+    masked = lambda t, c: block(jax.lax.select(t < edge, j0 + t,
+                                               b + t - edge), True)
+    jax.lax.fori_loop(0, edge, masked, None)
+    jax.lax.fori_loop(a, b, lambda j, c: block(j, False), None)
+    jax.lax.fori_loop(edge, edge + j1 + 1 - b, masked, None)
+    out = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    for g in range(G):
+        o_ref[:, g * vd:(g + 1) * vd] = out[g * bq:(g + 1) * bq]
+
+
+def prompt_flash(q, k, v, window=None, q_offset=0, tile=None,
+                 interpret=False):
+    """decode_attention.blocked_attention's contract as one Pallas kernel:
+    q [B, Tq, H, hd] at positions q_offset.. (an int, or a traced one
+    with q_offset + Tq <= T), k [B, T, KV, hd] and v [B, T, KV, vd] at
+    0..T-1; query i sees key j iff j <= i and, with `window`, i - j <
+    window; scores scaled by 1/sqrt(hd), operands in k's dtype, float32
+    statistics -> [B, Tq, H, vd] in q's dtype. `tile` (bq, bk) defaults
+    to `prompt_tile`'s."""
+    B, Tq, H, hd = q.shape
+    T, KV, vd = k.shape[1], k.shape[2], v.shape[-1]
+    tile = tile or prompt_tile(Tq, T, hd, vd, H // KV, k.dtype.itemsize)
+    # host integers stay host constants: no device array is made to trace
+    at = [np.int32(x) if isinstance(x, int) else x
+          for x in (q_offset, T if window is None else min(window, T))]
+    out = _prompt_flash_call(
+        *at, q.astype(k.dtype).reshape(B, Tq, H * hd),
+        k.reshape(B, T, KV * hd), v.reshape(B, T, KV * vd), heads=(H, KV),
+        tile=tile, interpret=interpret)
+    return out.reshape(B, Tq, H, vd)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "tile", "interpret"))
+def _prompt_flash_call(first, window, q, k, v, heads, tile, interpret):
+    """The kernel over the arrays as they lie, [B, T, heads * width]: one
+    traced and lowered body for every call of one shape, whatever its
+    query offset and window (a prompt's chunks and its layers of both
+    kinds share it)."""
+    (B, Tq, _), T = q.shape, k.shape[1]
+    H, KV = heads
+    G, hd, vd = H // KV, q.shape[2] // H, v.shape[2] // KV
+    bq, bk = tile
+    # the key blocks the call's queries can reach: [lo, lo + n * bk)
+    lo = jnp.maximum(first - (window - 1), 0) // bk * bk
+    n = (first + Tq + bk - 1) // bk - lo // bk
+    return pl.pallas_call(
+        functools.partial(_prompt_fwd_kernel, scale=1.0 / math.sqrt(hd),
+                          bq=bq, bk=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, KV, Tq // bq),
+            in_specs=[
+                pl.BlockSpec((None, bq, G * hd),
+                             lambda b, h, i, at: (b, i, h)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, bq, G * vd),
+                                   lambda b, h, i, at: (b, i, h)),
+            scratch_shapes=[pltpu.VMEM((T, hd), k.dtype),
+                            pltpu.VMEM((T, vd), v.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.VMEM((G * bq, hd), k.dtype),
+                            pltpu.VMEM((G * bq, 1), jnp.float32),
+                            pltpu.VMEM((G * bq, 1), jnp.float32),
+                            pltpu.VMEM((G * bq, vd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Tq, H * vd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=PROMPT_VMEM_BUDGET + 16 * 2 ** 20),
+        name="prompt_flash_fwd",
+        interpret=interpret,
+    )(jnp.stack([first, lo, n, window]).astype(jnp.int32), q, k, v)

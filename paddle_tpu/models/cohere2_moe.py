@@ -39,13 +39,15 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.decode_attention import (blocked_attention, cached_attention,
-                                        layer_view, ring_rows, write_kv)
+                                        causal_pairs, layer_view,
+                                        prompt_kernel_engages, ring_rows,
+                                        write_kv)
 from ..parallel.moe import dropless_experts, sigmoid_topk
 from .llama import _apply_rope
 
 __all__ = ["Cohere2MoeConfig", "init_cohere2_moe_params", "init_kv_cache",
            "cohere2_moe_forward_cached", "prefill_into_slot", "COUNTS",
-           "span_counts"]
+           "span_counts", "prompt_counts"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -69,6 +71,28 @@ def span_counts(cfg, stats) -> Dict[str, int]:
     counts["kv_positions_window"] = window * cfg.layers_of(SLIDING)
     counts["kv_positions_full"] = full * cfg.layers_of(FULL)
     counts["kv_positions_uniform"] = full * cfg.num_layers
+    return counts
+
+
+def prompt_counts(cfg, bucket: int) -> Dict[str, int]:
+    """What a prompt bucket's attention does, from its shapes alone
+    (ModelFamily.prompt_counts): the (query, key) pairs its layers admit,
+    summed over the layers, and those of them whose `blocked_attention`
+    call — one a chunk of `prefill_chunk` rows a layer — runs the Pallas
+    prompt kernel (`prompt_kernel_engages`, asked on the host of the same
+    shapes)."""
+    chunk = min(cfg.prefill_chunk, bucket)
+    hd = cfg.head_dim
+    counts = {"attn_pairs": 0, "attn_pairs_kernel": 0}
+    for kind, window in ((SLIDING, cfg.sliding_window), (FULL, None)):
+        for start in range(0, bucket, chunk):
+            pairs = cfg.layers_of(kind) * causal_pairs(start, start + chunk,
+                                                       window)
+            counts["attn_pairs"] += pairs
+            if prompt_kernel_engages(chunk, bucket, cfg.num_heads,
+                                     cfg.num_kv_heads, hd, hd, cfg.dtype,
+                                     start):
+                counts["attn_pairs_kernel"] += pairs
     return counts
 
 

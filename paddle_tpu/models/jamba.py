@@ -43,12 +43,13 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.decode_attention import (blocked_attention, cached_attention,
+                                        causal_pairs, prompt_kernel_engages,
                                         write_kv)
 from ..kernels.selective_scan import selective_scan, selective_state_update
 
 __all__ = ["JambaConfig", "init_jamba_params", "init_cache",
            "jamba_forward_cached", "prefill_into_slot", "COUNTS",
-           "span_counts", "slot_state_bytes"]
+           "span_counts", "prompt_counts", "slot_state_bytes"]
 
 MAMBA, ATTENTION = "mamba", "attention"
 # positions a chunk of the prompt's selective scan runs as one fused pass:
@@ -227,6 +228,25 @@ def span_counts(cfg: JambaConfig, stats) -> Dict[str, int]:
             "kv_positions_pool": pool * layers}
 
 
+def prompt_counts(cfg: JambaConfig, bucket: int) -> Dict[str, int]:
+    """What a prompt bucket's attention layers do, from its shapes alone
+    (ModelFamily.prompt_counts): the (query, key) pairs they admit, summed
+    over the layers, and those of them computed by the Pallas prompt
+    kernel — a whole prompt's `blocked_attention` where
+    `prompt_kernel_engages`, asked on the host of the same shapes."""
+    pairs = cfg.layers_of(ATTENTION) * causal_pairs(0, bucket)
+    kernel = (_whole_prompt(bucket) and prompt_kernel_engages(
+        bucket, bucket, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+        cfg.head_dim, cfg.dtype))
+    return {"attn_pairs": pairs, "attn_pairs_kernel": pairs if kernel else 0}
+
+
+def _whole_prompt(T: int) -> bool:
+    """Whether a run of T tokens into an empty cache attends its own keys
+    in blocks (`blocked_attention`) rather than reading the pool."""
+    return T > 1 and T % min(ATTENTION_BLOCK, T) == 0
+
+
 def _at_layer(stack, at):
     """Layer `at` (a traced index) of a stacked pool or leaf: a read XLA
     fuses into its consumer."""
@@ -352,8 +372,7 @@ def _hidden(params, tokens, cache, pos, cfg: JambaConfig, live=None):
         else jnp.sum(live, axis=1).astype(jnp.int32)
     # a whole prompt into an empty cache attends its own keys in blocks;
     # anything else (decode, a run that continues a cache) reads the pool
-    whole_prompt = (T > 1 and isinstance(pos, int) and pos == 0
-                    and T % min(ATTENTION_BLOCK, T) == 0)
+    whole_prompt = isinstance(pos, int) and pos == 0 and _whole_prompt(T)
     x = jnp.take(params["wte"], tokens, axis=0).astype(cfg.dtype)
 
     def residual(x, out):

@@ -62,7 +62,8 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.decode_attention import (_query_positions, blocked_attention,
-                                        layer_view, write_kv)
+                                        causal_pairs, layer_view,
+                                        prompt_kernel_engages, write_kv)
 from ..kernels.latent_attention import (LATENT_BLOCK,
                                         absorbed_attention_live_blocks,
                                         live_latent_plan)
@@ -71,7 +72,7 @@ from .llama import _apply_rope
 
 __all__ = ["JoyaiLlmFlashConfig", "init_joyai_llm_flash_params", "init_cache",
            "joyai_llm_flash_forward_cached", "prefill_into_slot",
-           "mtp_logits", "COUNTS", "span_counts"]
+           "mtp_logits", "COUNTS", "span_counts", "prompt_counts"]
 
 # a whole prompt's attention runs in blocks of this many positions
 ATTENTION_BLOCK = 512
@@ -257,6 +258,19 @@ def span_counts(cfg: JoyaiLlmFlashConfig, stats) -> Dict[str, int]:
                       kv_positions_pool=pool * cfg.num_layers,
                       latent_bytes=read * cfg.num_layers * position)
     return counts
+
+
+def prompt_counts(cfg: JoyaiLlmFlashConfig, bucket: int) -> Dict[str, int]:
+    """What a prompt bucket's attention does, from its shapes alone
+    (ModelFamily.prompt_counts): the (query, key) pairs the decompressed
+    attention of every layer admits, summed over the layers, and those of
+    them computed by the Pallas prompt kernel (`prompt_kernel_engages`,
+    asked on the host of the same shapes: q/k 192 wide, v 128)."""
+    pairs = cfg.num_layers * causal_pairs(0, bucket)
+    kernel = prompt_kernel_engages(bucket, bucket, cfg.num_heads,
+                                   cfg.num_heads, cfg.qk_head_dim,
+                                   cfg.v_head_dim, cfg.dtype)
+    return {"attn_pairs": pairs, "attn_pairs_kernel": pairs if kernel else 0}
 
 
 def _at_layer(stack, at):

@@ -39,7 +39,7 @@ KERNELS = ["flash_fwd_hd64", "flash_bwd_hd64", "flash_fwd_hd128",
            "flash_bwd_hd128", "flash_tiled_hd64", "flash_tiled_hd128",
            "jax_flash", "splash", "ce", "ce_fused",
            "fused_adamw", "quant_matmul_k2048", "quant_matmul_k8192",
-           "decode_live_blocks", "mla_live_blocks"]
+           "decode_live_blocks", "mla_live_blocks", "prompt_flash"]
 
 
 @pytest.fixture(scope="module")

@@ -448,6 +448,12 @@ def test_counts_ride_the_one_pull_onto_the_spans(setup):
         assert c["kv_positions_full"] == n * (n + 1) // 2
         assert c["kv_positions_window"] == 3 * sum(
             min(i + 1, WINDOW) for i in range(n))
+        # over the bucket, padding included; off a TPU no pair is the
+        # prompt kernel's (tests/test_prompt_flash_kernel.py has the TPU)
+        b = c["bucket"]
+        assert c["attn_pairs"] == 3 * sum(
+            min(i + 1, WINDOW) for i in range(b)) + b * (b + 1) // 2
+        assert c["attn_pairs_kernel"] == 0
     for s in ticks:
         c = s.counts
         assert c["expert_choices"] == c["active"] * k * layers

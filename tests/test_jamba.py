@@ -454,6 +454,10 @@ def test_counts_ride_the_one_pull_onto_the_spans(setup):
         assert s.counts["scan_chunks"] == mamba * -(
             -s.counts["bucket"] // m.SCAN_CHUNK)
         assert "state_bytes" not in s.counts
+        b = s.counts["bucket"]
+        assert s.counts["attn_pairs"] == cfg.layers_of(m.ATTENTION) \
+            * b * (b + 1) // 2
+        assert s.counts["attn_pairs_kernel"] == 0        # off a TPU
     state = m.slot_state_bytes(cfg)
     assert state == mamba * (4 * 64 * 4 + 3 * 64 * 4)
     for s in ticks:

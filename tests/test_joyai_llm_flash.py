@@ -651,6 +651,9 @@ def test_counts_ride_the_one_pull_onto_the_spans(setup):
         assert 0 < c["expert_tokens"] <= c["true_len"] * k * expert_layers
         assert 0 < c["expert_max_load"] <= c["true_len"]
         assert "kv_positions_read" not in c              # a tick's counts
+        b = c["bucket"]
+        assert c["attn_pairs"] == cfg.num_layers * b * (b + 1) // 2
+        assert c["attn_pairs_kernel"] == 0
     for s in ticks:
         c = s.counts
         assert c["expert_tokens"] <= c["active"] * k * expert_layers

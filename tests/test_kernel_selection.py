@@ -126,6 +126,16 @@ SITES = {
                    da.length_aware(5, jax.ShapeDtypeStruct(
                        (24, 16, 1024, 16, 128), jnp.bfloat16))),
         {"tpu": (True, False), "cpu": (False, False)}),
+    # a prompt's attention at Command A+'s last chunk of a 16,384 bucket,
+    # Jamba's 4,096 bucket, and JoyAI's 192-wide q/k
+    "prompt_attention_kernel": (
+        lambda m: (da.prompt_kernel_engages(2048, 16384, 128, 8, 128, 128,
+                                            jnp.bfloat16, 14336),
+                   da.prompt_kernel_engages(4096, 4096, 20, 1, 128, 128,
+                                            jnp.bfloat16),
+                   da.prompt_kernel_engages(8192, 8192, 32, 32, 192, 128,
+                                            jnp.bfloat16)),
+        {"tpu": (True, True, False), "cpu": (False, False, False)}),
     "quant_matmul": (lambda m: (qm.resolve_quant("auto"),
                                 qm.matmul_impl()),
                      {"tpu": (False, "xla"), "cpu": (False, "xla")}),

@@ -138,6 +138,14 @@ def _mla_live_blocks():
         interpret=True))(ckv, kpe)
 
 
+def _prompt_flash():
+    from paddle_tpu.kernels.pallas_attention import prompt_flash
+    q = jnp.zeros((1, 256, 16, 128), jnp.bfloat16)
+    kv = jnp.zeros((1, 256, 2, 128), jnp.bfloat16)
+    return jax.make_jaxpr(functools.partial(
+        prompt_flash, window=100, interpret=True))(q, kv, kv)
+
+
 KERNELS = {
     "flash_fwd": _flash_fwd,
     "flash_bwd_dq": _flash_bwd,
@@ -151,6 +159,7 @@ KERNELS = {
     "quant_matmul": _quant,
     "decode_attention_live_blocks": _decode_live_blocks,
     "mla_absorbed_live_blocks": _mla_live_blocks,
+    "prompt_flash_fwd": _prompt_flash,
 }
 
 
