@@ -349,7 +349,10 @@ class ModelFamily:
     positions, qk_rope_head_dim]: what every head's key and value are
     made from, and the one rotated key they share). A recurrent kind has
     NO position axis (jamba's `"ssm"` [layers, slots, d_state, d_inner]
-    float32 and `"conv"` [layers, slots, d_conv - 1, d_inner]): a step
+    float32 and `"conv"` [layers, slots, d_conv - 1, d_inner];
+    gigachat3_5's `"dn_state"` [layers, slots, heads, key dim, value dim]
+    float32 — a matrix a head — and `"dn_conv"`, beside its latent
+    `"ckv"` / `"kpe"`): a step
     overwrites a slot's rows, so such a family's forward must leave a
     padded position and an idle row's state alone (it is told which
     tokens are real, `live=`), and its `prefill` replaces a slot's rows
@@ -425,10 +428,19 @@ def _joyai_llm_flash_family() -> ModelFamily:
                        refuses=REFUSABLE)
 
 
+def _gigachat3_5_family() -> ModelFamily:
+    from ..models import gigachat3_5 as m
+    return ModelFamily("gigachat3_5", m.gigachat3_5_forward_cached,
+                       m.init_cache, None, prefill=m.prefill_into_slot,
+                       counts=m.span_counts, prompt_counts=m.prompt_counts,
+                       refuses=REFUSABLE)
+
+
 # name -> factory; a family's module is imported when it is asked for
 _FAMILIES = {"gpt": _gpt_family, "llama": _llama_family,
              "cohere2_moe": _cohere2_moe_family, "jamba": _jamba_family,
-             "joyai_llm_flash": _joyai_llm_flash_family}
+             "joyai_llm_flash": _joyai_llm_flash_family,
+             "gigachat3_5": _gigachat3_5_family}
 
 
 def family_for(name: str) -> ModelFamily:

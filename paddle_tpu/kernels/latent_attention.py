@@ -190,7 +190,25 @@ def absorbed_attention_live_blocks(q_lat, q_pe, ckv, kpe, layer, plan,
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, C), jnp.float32)]),
         name="mla_absorbed_live_blocks",
-        interpret=interpret,
+        interpret=interpret, **_vmem_limit(B, H, C, R, ckv.dtype),
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), *plan,
       q_lat.astype(ckv.dtype), q_pe.astype(kpe.dtype), ckv,
       jnp.swapaxes(kpe, 2, 3))
+
+
+# VMEM a kernel is given when it asks for no more (Mosaic's scoped default)
+SCOPED_VMEM = 16 * 2 ** 20
+
+
+def _vmem_limit(B: int, H: int, C: int, R: int, dtype) -> dict:
+    """The kernel keeps the queries and the output whole in VMEM beside
+    its two block buffers: past the scoped default (128 rows of 64 heads:
+    28 MiB) it asks for what it holds, and below it for nothing (the
+    pallas_call's own default)."""
+    size = jnp.dtype(dtype).itemsize
+    held = B * H * ((C + R) * size + C * 4) \
+        + 2 * LATENT_BLOCK * (C + R) * size + H * (C + 256) * 4
+    if held < SCOPED_VMEM:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=held + SCOPED_VMEM)}

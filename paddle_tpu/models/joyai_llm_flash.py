@@ -67,7 +67,7 @@ from ..kernels.decode_attention import (_query_positions, blocked_attention,
 from ..kernels.latent_attention import (LATENT_BLOCK,
                                         absorbed_attention_live_blocks,
                                         live_latent_plan)
-from ..parallel.moe import dropless_experts, sigmoid_topk
+from ..parallel.moe import dropless_experts, sigmoid_topk, swiglu
 from .llama import _apply_rope
 
 __all__ = ["JoyaiLlmFlashConfig", "init_joyai_llm_flash_params", "init_cache",
@@ -411,18 +411,19 @@ def _attention(lp, u, ckv, kpe, at, pos, cos, sin, live, plan,
     return out, ckv, kpe
 
 
-def _mlp(u, gate_w, up_w, down_w):
-    g = jax.nn.silu(jnp.einsum("...d,df->...f", u, gate_w)) \
-        * jnp.einsum("...d,df->...f", u, up_w)
+def _mlp(u, gate_w, up_w, down_w, limit=None):
+    g = swiglu(jnp.einsum("...d,df->...f", u, gate_w),
+               jnp.einsum("...d,df->...f", u, up_w), limit)
     return jnp.einsum("...f,fd->...d", g, down_w,
                       preferred_element_type=jnp.float32)
 
 
-def _experts(tree, j, h, live, cfg: JoyaiLlmFlashConfig):
+def _experts(tree, j, h, live, cfg: JoyaiLlmFlashConfig, limit=None):
     """Experts(h) for rows h [R, D] of expert layer `j` (a traced index
     into the stacks of `tree`) -> ([R, D] float32, load [Eh]): the held
     routed experts' part under `noaux_tc` routing plus the shared expert.
-    The routed leaves stay whole stacks (dropless_experts picks layer j)."""
+    The routed leaves stay whole stacks (dropless_experts picks layer j).
+    `limit` clamps every expert's gated product (parallel/moe.swiglu)."""
     with jax.named_scope("moe_router"):
         logits = jnp.dot(h.astype(jnp.float32),
                          _at_layer(tree["router_w"], j).astype(jnp.float32),
@@ -434,10 +435,11 @@ def _experts(tree, j, h, live, cfg: JoyaiLlmFlashConfig):
     with jax.named_scope("moe_experts"):
         routed, load = dropless_experts(
             h, choice, weight, tree["exp_gate_w"], tree["exp_up_w"],
-            tree["exp_down_w"], first=cfg.first_expert, live=live, layer=j)
+            tree["exp_down_w"], first=cfg.first_expert, live=live, layer=j,
+            limit=limit)
     with jax.named_scope("shared_expert"):
         shared = _mlp(h, *(_at_layer(tree["shared_" + n], j)
-                           for n in ("gate_w", "up_w", "down_w")))
+                           for n in ("gate_w", "up_w", "down_w")), limit)
     return routed + shared, load
 
 

@@ -122,12 +122,26 @@ def sigmoid_topk(router_logits, k: int, normalize: bool = True, bias=None,
 # rows up to which every held expert computes every row (`dense`): the
 # step is then bound by reading the experts' weights once, which the
 # dense form does and nothing else; the sorted form's sort, gathers and
-# grouped matmuls cost more than they save (PERF.md section 6, PR 31)
-DENSE_FORM_ROWS = 64
+# grouped matmuls cost more than they save (PERF.md section 6).
+# A row is 2 FLOPs a bf16 weight, so the dense form stays bound by the
+# weights up to ~240 rows on a v5e (197 TFLOP/s over 819 GB/s); at 128
+# rows the sorted form took ~12.5 of a 31.7-ms tick on a v5e, and its
+# time follows how the seed's routing groups the rows (PERF.md section 6)
+DENSE_FORM_ROWS = 128
+
+
+def swiglu(g, u, limit: Optional[float] = None):
+    """The gated-SiLU product silu(g) * u of a gate and an up projection;
+    with `limit` (a config's `swiglu_limit`) clamped first:
+    silu(min(g, limit)) * clip(u, -limit, limit)."""
+    if limit is None:
+        return jax.nn.silu(g) * u
+    return jax.nn.silu(jnp.minimum(g, limit)) * jnp.clip(u, -limit, limit)
 
 
 def dropless_experts(x, choice, weight, gate_w, up_w, down_w, *,
-                     first: int = 0, live=None, layer=None):
+                     first: int = 0, live=None, layer=None,
+                     limit: Optional[float] = None):
     """The held experts' part of a gated-SiLU expert layer, no drops.
 
     x [T, D]; choice / weight [T, k] from `sigmoid_topk` (indices over
@@ -141,7 +155,8 @@ def dropless_experts(x, choice, weight, gate_w, up_w, down_w, *,
     weights are a stack [L, Eh, ...] and layer `layer`'s experts are
     meant: the grouped matmuls then run over the whole stack with every
     other layer's groups empty, so that no layer's 400 MB is sliced out
-    into a buffer of its own first.
+    into a buffer of its own first. `limit` clamps the gated product
+    (`swiglu`).
 
     -> (y [T, D] float32, load [Eh] int32: live pairs on each held
     expert).
@@ -165,8 +180,8 @@ def dropless_experts(x, choice, weight, gate_w, up_w, down_w, *,
     if T <= DENSE_FORM_ROWS:
         if layer is not None:
             gate_w, up_w, down_w = gate_w[layer], up_w[layer], down_w[layer]
-        h = jax.nn.silu(jnp.einsum("td,edf->etf", x, gate_w)) \
-            * jnp.einsum("td,edf->etf", x, up_w)
+        h = swiglu(jnp.einsum("td,edf->etf", x, gate_w),
+                   jnp.einsum("td,edf->etf", x, up_w), limit)
         out = jnp.einsum("etf,efd->etd", h, down_w,
                          preferred_element_type=jnp.float32)
         mix = jnp.sum(jnp.where(
@@ -183,8 +198,8 @@ def dropless_experts(x, choice, weight, gate_w, up_w, down_w, *,
         gate_w, up_w, down_w = (w.reshape((stack * held,) + w.shape[2:])
                                 for w in (gate_w, up_w, down_w))
     rows = jnp.take(x, order // k, axis=0)                  # [T*k, D]
-    h = jax.nn.silu(jax.lax.ragged_dot(rows, gate_w, sizes)) \
-        * jax.lax.ragged_dot(rows, up_w, sizes)
+    h = swiglu(jax.lax.ragged_dot(rows, gate_w, sizes),
+               jax.lax.ragged_dot(rows, up_w, sizes), limit)
     out = jax.lax.ragged_dot(h, down_w, sizes,
                              preferred_element_type=jnp.float32)
     back = jnp.argsort(order)                               # pair -> row

@@ -80,7 +80,12 @@ HEAD_LEAF = "wte"
 # matmul stacks of both mixers and the MLP, the convolution and the
 # embedding: stored in bf16 they pass through; `a_log`, `d`, `dt_b` and
 # the norm scales are float32 operands of float32 arithmetic and are not
-# here.
+# here. gigachat3_5's are the matmul stacks of both mixers (the linear
+# layers' projections and convolution, the latent attention's and its
+# output gate), of the dense MLP and the experts, the embedding and the
+# untied head; the router (whose scores are float32 at the highest
+# precision), `dn_a_log`, `dn_dt_bias`, the routing bias and the norm
+# scales are float32 operands and are not here.
 COMPUTE_LEAVES: Dict[str, tuple] = {
     "gpt": QUANT_LEAVES["gpt"] + ("qkv_b", "attn_out_b", "mlp_up_b",
                                   "mlp_down_b", "wte"),
@@ -88,6 +93,12 @@ COMPUTE_LEAVES: Dict[str, tuple] = {
     "jamba": ("in_w", "conv_w", "conv_b", "x_w", "dt_w", "out_w",
               "q_w", "k_w", "v_w", "o_w", "gate_w", "up_w", "down_w",
               "wte"),
+    "gigachat3_5": ("dn_q_w", "dn_k_w", "dn_v_w", "dn_z_w", "dn_b_w",
+                    "dn_a_w", "dn_conv_w", "dn_o_w", "q_a_w", "q_b_w",
+                    "kv_a_w", "k_b_w", "v_b_w", "o_w", "attn_gate_w",
+                    "gate_w", "up_w", "down_w", "shared_gate_w",
+                    "shared_up_w", "shared_down_w", "exp_gate_w",
+                    "exp_up_w", "exp_down_w", "wte", "head_w"),
 }
 
 

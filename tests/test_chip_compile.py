@@ -432,3 +432,78 @@ def test_joyai_llm_flash_tick_and_longest_prompt_fit_and_move_no_pool(
     assert "mla_absorbed_live_blocks" not in compiled.as_text()
     # the longest prompt program did not grow (13.13 GB before the kernel)
     assert 12.8e9 < _device_bytes(compiled) < 13.2e9 < 15.75e9
+
+
+def test_gigachat3_5_tick_and_prompt_bucket_fit_and_move_no_pool(topo,
+                                                                 as_tpu):
+    """The gigachat3.5 cell's two largest bodies (128 slots x 8,192; layers
+    0 and 4-7 of 40, 16 of 256 experts, 16,032 rows, every width as
+    published): the decode tick — four linear layers' delta-rule state
+    (2.15 GB float32) and convolution rows and one latent layer's pools in
+    both layer passes' carry, every row written in place, the latent
+    attention the Pallas kernel at 64 heads x 128 rows (which asks for the
+    VMEM its whole queries and output need) — and the 4,096 prompt
+    bucket, whose chunked delta rule makes each chunk's arrays inside its
+    step (made for every chunk ahead of the walk they held 0.78 GB more).
+    What `sizing.compile_bytes` of the configuration file states."""
+    from paddle_tpu.inference.serving import (_decode_tick, _prefill_slot,
+                                              family_for)
+    from paddle_tpu.models import gigachat3_5 as m
+    cfg = m.GigaChat35Config(num_layers=5, full_attention_layers=(4,),
+                             first_k_dense_replace=1, experts_held=16,
+                             vocab_size=16032, max_seq_len=8192)
+    fam = family_for("gigachat3_5")
+    one = SingleDeviceSharding(topo.devices[0])
+    slots, max_len = 128, 8192
+    params = _on(one, jax.eval_shape(lambda: m.init_gigachat3_5_params(
+        cfg, jax.random.PRNGKey(0))))
+    assert sum(int(np.prod(v.shape)) for v in params.values()) \
+        == 4_731_722_752
+    cache = _on(one, jax.eval_shape(
+        lambda: m.init_cache(cfg, slots, max_len)))
+    pools = {k: v for k, v in cache.items() if k != "stats"}
+    assert pools["dn_state"].shape == (4, 128, 64, 128, 128)
+    assert pools["dn_state"].dtype == jnp.float32
+    assert pools["dn_conv"].shape == (4, 128, 3, 16384)
+    assert pools["ckv"].shape == (1, 128, 8192, 512)
+    held = sum(int(np.prod(v.shape)) * v.dtype.itemsize
+               for v in pools.values())
+    assert held == 3_405_774_848
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+    state = tuple(S((slots,), dt) for dt in (
+        jnp.int32, jnp.int32, jnp.bool_, jnp.float32, jnp.int32,
+        jnp.int32, jnp.int32))
+    tick = jax.jit(
+        functools.partial(_decode_tick, fwd=fam.forward_cached, cfg=cfg,
+                          max_top_k=0, guard=True, oor_pos=None,
+                          cache_pin=None, tele=True),
+        donate_argnums=(1, 2), static_argnames=("sampling",))
+    compiled = tick.lower(params, cache, state, S((2,), jnp.uint32),
+                          S((slots,), jnp.float32), sampling=False).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= held           # donated through
+    text = compiled.as_text()
+    # the latent kernel, once: one latent layer
+    assert sum(1 for ln in text.splitlines() if " custom-call(" in ln
+               and "mla_absorbed_live_blocks" in ln) == 1
+    moved = ("copy", "dynamic-slice", "pad", "transpose", "AllocateBuffer")
+    shapes = ["f32[4,128,64,128,128]", "f32[128,64,128,128]",
+              "bf16[4,128,3,16384]", "bf16[1,128,8192,512]",
+              "bf16[128,8192,512]", "bf16[1,128,64,8192]"]
+    assert [ln.strip()[:160] for ln in text.splitlines()
+            if any(f"= {shape}" in ln for shape in shapes)
+            and any(f" {op}(" in ln for op in moved)] == []
+    assert ma.temp_size_in_bytes < 0.5e9
+    assert 13.1e9 < _device_bytes(compiled) < 13.4e9 < HBM_BYTES
+    prefill = jax.jit(
+        functools.partial(_prefill_slot, fwd=fam.forward_cached,
+                          init_cache=fam.init_cache, cfg=cfg, max_top_k=0,
+                          guard=True, cache_pin=None,
+                          family_prefill=fam.prefill),
+        donate_argnums=(1,), static_argnames=("sampling",))
+    compiled = prefill.lower(
+        params, cache, S((1, 4096), jnp.int32), S((), jnp.int32),
+        S((), jnp.int32), S((1,), jnp.float32), S((1,), jnp.int32),
+        S((1,), jnp.int32), S((2,), jnp.uint32), sampling=False).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+    assert 14.2e9 < _device_bytes(compiled) < 14.5e9 < 15.75e9

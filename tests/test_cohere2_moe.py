@@ -263,9 +263,9 @@ def _by_loop(x, choice, weight, gate_w, up_w, down_w, first):
     return out
 
 
-@pytest.mark.parametrize("rows", [7, 100])      # the dense and sorted forms
+@pytest.mark.parametrize("rows", [7, 100, 200])    # dense, dense, sorted
 def test_no_token_is_dropped_whatever_the_imbalance(rows):
-    assert 7 <= moe.DENSE_FORM_ROWS < 100
+    assert 100 <= moe.DENSE_FORM_ROWS < 200
     gate_w, up_w, down_w = _expert_weights(8)
     x = jax.random.normal(jax.random.PRNGKey(1), (rows, 16))
     # every token chooses the same 4 experts, all of them held here, and

@@ -315,12 +315,12 @@ def test_routing_falls_as_the_references_ties_included(setup):
     assert any(tied) and all(9 not in row or 3 in row for row in both)
 
 
-@pytest.mark.parametrize("rows", [6, 80])
+@pytest.mark.parametrize("rows", [6, 80, 160])
 def test_the_shares_add_up_to_the_uncut_layer(rows):
     """(e) the parts the 4 shares of 4 experts give, with the shared expert
     (which every chip computes alike) counted once, add up to the uncut
-    reference's expert layer — in the dense form (6 rows) and the sorted
-    one (80)."""
+    reference's expert layer — in the dense form (6 and 80 rows) and the
+    sorted one (160)."""
     whole = make_cfg(experts_held=None, first_expert=0)
     params = make_params(whole, 9)
     j = 1                                           # an expert layer

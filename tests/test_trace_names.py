@@ -62,6 +62,44 @@ def test_the_decode_tick_names_its_phases():
     assert "attention/decode_attention" in text
 
 
+def test_the_hybrid_tick_and_prompt_name_their_phases():
+    """gigachat3_5: the linear-attention layer's phases (the chunked delta
+    rule in a prompt's program, the one step in the tick's), the latent
+    attention's path and the expert layer's, each in the program that runs
+    them — what the device view's by-scope table groups."""
+    from paddle_tpu.models import gigachat3_5 as m
+    cfg = m.GigaChat35Config(
+        vocab_size=64, hidden_size=32, num_layers=2,
+        full_attention_layers=(1,), num_heads=2, q_lora_rank=16,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+        v_head_dim=8, linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=8, ffn_hidden=48,
+        moe_ffn_hidden=16, first_k_dense_replace=1, n_routed_experts=8,
+        experts_held=4, num_experts_per_tok=2, max_seq_len=32,
+        dtype=jnp.float32, param_dtype=jnp.float32, prefill_chunk=8)
+    params = m.init_gigachat3_5_params(cfg, jax.random.PRNGKey(0))
+    tick = jax.jit(lambda p, t, c, pos: m.gigachat3_5_forward_cached(
+        p, t, c, pos, cfg)).lower(
+        params, jnp.zeros((2, 1), jnp.int32), m.init_cache(cfg, 2, 16),
+        jnp.zeros((2,), jnp.int32)).as_text(debug_info=True)
+    prompt = jax.jit(lambda p, t, c: m.gigachat3_5_forward_cached(
+        p, t, c, 0, cfg)).lower(
+        params, jnp.zeros((1, 16), jnp.int32),
+        m.init_cache(cfg, 1, 16)).as_text(debug_info=True)
+    for scope in ("gated_delta/proj", "gated_delta/conv", "gated_delta/out",
+                  "moe_router", "moe_experts", "shared_expert", "dense_mlp",
+                  "lm_head"):
+        assert scope in tick and scope in prompt, scope
+    for only, other, text in (
+            (("gated_delta/step", "mla_absorbed"),
+             ("gated_delta/chunked", "mla_prefill"), tick),
+            (("gated_delta/chunked", "mla_prefill"),
+             ("gated_delta/step", "mla_absorbed"), prompt)):
+        assert all(_has_scope(text, s.split("/")[-1]) for s in only)
+        assert all(s in text for s in only)
+        assert not any(s in text for s in other)
+
+
 def _flash_fwd():
     from paddle_tpu.kernels.pallas_attention import mha_fwd
     q = jnp.zeros((1, 128, 2, 64), jnp.float32)
