@@ -64,6 +64,7 @@ class Sizes:
     latent_pool: Tuple[int, int, int, int, int, int]      # [L, B, S, C, R, H]
     # one chunk of a prompt's queries: [Tq, T, H, KV, hd, window, q_offset]
     prompt_chunk: Tuple[int, int, int, int, int, int, int]
+    delta_pool: Tuple[int, int, int, int, int]            # [L, B, H, dk, dv]
 
 
 # the published widths: GPT-350M (24L x 1024d x 16 heads, hd 64) trains at
@@ -82,6 +83,8 @@ REAL = Sizes(
     latent_pool=(2, 8, 4096, 512, 64, 32),
     # Command A+'s third prompt chunk of an 8,192 bucket, window layer
     prompt_chunk=(2048, 8192, 128, 8, 128, 4096, 4096),
+    # four layers of GigaChat3.5's delta-rule state pool, 8 slots
+    delta_pool=(4, 8, 64, 128, 128),
 )
 
 _TINY_GPT = dict(vocab_size=640, hidden_size=128, num_layers=2, num_heads=2,
@@ -100,6 +103,7 @@ TINY = Sizes(
     decode_pool=(2, 4, 256, 8, 128),
     latent_pool=(2, 4, 2048, 128, 64, 8),
     prompt_chunk=(256, 512, 32, 2, 128, 200, 256),
+    delta_pool=(2, 3, 2, 128, 128),
 )
 
 # bf16 compute rounds to 8 bits of mantissa; losses of two layouts of one
@@ -414,6 +418,35 @@ def kernel_cases(sizes: Sizes):
     cases.append(("prompt_flash", prompt, prompt_oracle,
                   (S((1, Tq, PH, phd), bf16),) + (S((1, PT, PKV, phd), bf16),)
                   * 2, 1e-2))
+
+    # the tick's delta-rule step on the last layer of the state pool
+    # against the `jnp` step on that layer, put back; q and k L2-normed,
+    # g <= 0 and beta in (0, 1) as the model makes them, every third slot
+    # idle by the draw
+    from paddle_tpu.kernels import gated_delta as gd
+    DL, DB, DH, dk, dv = sizes.delta_pool
+
+    def step_args(q, k, g, beta, draw):
+        def l2(x):
+            return x / jnp.sqrt(jnp.sum(x * x, -1, keepdims=True))
+        return (l2(q) / dk ** 0.5, l2(k), -jax.nn.softplus(g),
+                jax.nn.sigmoid(beta), draw % 3 != 0)
+
+    def delta(s, q, k, v, g, beta, draw):
+        q, k, g, beta, live = step_args(q, k, g, beta, draw)
+        return gd.gated_delta_tick(s, jnp.int32(DL - 1), q, k, v, g, beta,
+                                   live)
+
+    def delta_oracle(s, q, k, v, g, beta, draw):
+        q, k, g, beta, live = step_args(q, k, g, beta, draw)
+        o, new = gd.gated_delta_step(s[DL - 1], q, k, v, g, beta, live)
+        return o, s.at[DL - 1].set(new)
+
+    cases.append(("gated_delta_tick", delta, delta_oracle,
+                  (S(sizes.delta_pool, f32), S((DB, DH, dk), f32),
+                   S((DB, DH, dk), f32), S((DB, DH, dv), f32),
+                   S((DB, DH), f32), S((DB, DH), f32), S((DB,), i32)),
+                  1e-5))
     return cases
 
 

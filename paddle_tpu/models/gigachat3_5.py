@@ -66,7 +66,8 @@ import numpy as np
 from ..kernels.decode_attention import (_query_positions, causal_pairs,
                                         prompt_kernel_engages)
 from ..kernels.gated_delta import (CHUNK, gated_delta_chunked,
-                                   gated_delta_step)
+                                   gated_delta_step, gated_delta_tick,
+                                   tick_kernel_engages)
 from ..kernels.latent_attention import LATENT_BLOCK, live_latent_plan
 from . import joyai_llm_flash as mla
 from .jamba import _put_layer
@@ -320,16 +321,25 @@ def span_counts(cfg: GigaChat35Config, stats) -> Dict[str, int]:
     convolution rows its live rows read and wrote, the latent positions
     its MLA may touch (each live row's whole blocks under the kernel,
     every position of every slot under the einsums) over those the pools
-    hold, and the latent bytes behind the former."""
+    hold, and the latent bytes behind the former; the live rows x linear
+    layers whose delta-rule state the tick stepped, and those of them
+    the Pallas tick kernel stepped (`tick_kernel_engages`, asked on the
+    host of the pool's shapes)."""
     tokens, busiest, read, pool, rows = (int(v) for v in stats)
     counts = {"expert_tokens": tokens, "expert_max_load": busiest}
     if pool:
         layers = cfg.layers_of(FULL)
         position = cfg.latent_width * jnp.dtype(cfg.dtype).itemsize
+        stepped = rows * cfg.layers_of(LINEAR)
+        kernel = tick_kernel_engages(
+            cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+            cfg.linear_value_head_dim, jnp.float32)
         counts.update(state_bytes=2 * rows * slot_state_bytes(cfg),
                       kv_bytes=read * layers * position,
                       kv_positions_read=read * layers,
-                      kv_positions_pool=pool * layers)
+                      kv_positions_pool=pool * layers,
+                      dn_state_rows=stepped,
+                      dn_state_rows_kernel=stepped if kernel else 0)
     return counts
 
 
@@ -429,18 +439,22 @@ def _gated_delta(lp, u, conv, state, at, lengths, cfg: GigaChat35Config):
     v = v.reshape(B, T, hv, dv)
     beta = jax.nn.sigmoid(b)
     g = -jnp.exp(lp["dn_a_log"]) * jax.nn.softplus(a + lp["dn_dt_bias"])
-    held = mla._at_layer(state, at)
     if T == 1:
         with jax.named_scope("gated_delta/step"):
-            o, held = gated_delta_step(held, q[:, 0], k[:, 0], v[:, 0],
-                                       g[:, 0], beta[:, 0], live=lengths > 0)
+            step = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+            if tick_kernel_engages(hv, dk, dv, state.dtype):
+                o, state = gated_delta_tick(state, at, *step, lengths > 0)
+            else:
+                o, held = gated_delta_step(mla._at_layer(state, at), *step,
+                                           live=lengths > 0)
+                state = _put_layer(state, held, at)
             o = o[:, None]
-            state = _put_layer(state, held, at)
     else:
         with jax.named_scope("gated_delta/chunked"):
             # one sequence, unbatched (as jamba's scan)
             o, held = gated_delta_chunked(q[0], k[0], v[0], g[0], beta[0],
-                                          held[0], lengths[0])
+                                          mla._at_layer(state, at)[0],
+                                          lengths[0])
             o, held = o[None], held[None]
             state = _put_layer(state, held, at)
     with jax.named_scope("gated_delta/out"):

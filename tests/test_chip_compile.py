@@ -16,6 +16,7 @@ monkeypatch — in the test, not through an option of the program.
 """
 import functools
 import os
+import re
 import sys
 
 import numpy as np
@@ -39,7 +40,8 @@ KERNELS = ["flash_fwd_hd64", "flash_bwd_hd64", "flash_fwd_hd128",
            "flash_bwd_hd128", "flash_tiled_hd64", "flash_tiled_hd128",
            "jax_flash", "splash", "ce", "ce_fused",
            "fused_adamw", "quant_matmul_k2048", "quant_matmul_k8192",
-           "decode_live_blocks", "mla_live_blocks", "prompt_flash"]
+           "decode_live_blocks", "mla_live_blocks", "prompt_flash",
+           "gated_delta_tick"]
 
 
 @pytest.fixture(scope="module")
@@ -434,6 +436,75 @@ def test_joyai_llm_flash_tick_and_longest_prompt_fit_and_move_no_pool(
     assert 12.8e9 < _device_bytes(compiled) < 13.2e9 < 15.75e9
 
 
+_GIGACHAT_TICK = {}
+
+
+def _gigachat3_5_tick(topo, fam, cfg, params, cache, slots):
+    """The gigachat3.5 cell's decode tick compiled once for the file (two
+    tests read it)."""
+    if "tick" not in _GIGACHAT_TICK:
+        from paddle_tpu.inference.serving import _decode_tick
+        one = SingleDeviceSharding(topo.devices[0])
+        S = functools.partial(jax.ShapeDtypeStruct, sharding=one)
+        state = tuple(S((slots,), dt) for dt in (
+            jnp.int32, jnp.int32, jnp.bool_, jnp.float32, jnp.int32,
+            jnp.int32, jnp.int32))
+        tick = jax.jit(
+            functools.partial(_decode_tick, fwd=fam.forward_cached, cfg=cfg,
+                              max_top_k=0, guard=True, oor_pos=None,
+                              cache_pin=None, tele=True),
+            donate_argnums=(1, 2), static_argnames=("sampling",))
+        _GIGACHAT_TICK["tick"] = tick.lower(
+            params, cache, state, S((2,), jnp.uint32),
+            S((slots,), jnp.float32), sampling=False).compile()
+    return _GIGACHAT_TICK["tick"]
+
+
+def _gigachat3_5_cell(topo):
+    """The gigachat3.5 cell's cut (layers 0 and 4-7 of 40, 16 of 256
+    experts, 16,032 rows, every width as published) as shapes on one
+    described chip -> (family, cfg, params, cache)."""
+    from paddle_tpu.inference.serving import family_for
+    from paddle_tpu.models import gigachat3_5 as m
+    cfg = m.GigaChat35Config(num_layers=5, full_attention_layers=(4,),
+                             first_k_dense_replace=1, experts_held=16,
+                             vocab_size=16032, max_seq_len=8192)
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on(one, jax.eval_shape(lambda: m.init_gigachat3_5_params(
+        cfg, jax.random.PRNGKey(0))))
+    cache = _on(one, jax.eval_shape(lambda: m.init_cache(cfg, 128, 8192)))
+    return family_for("gigachat3_5"), cfg, params, cache
+
+
+def test_gigachat3_5_tick_steps_the_state_in_place_with_the_kernel(topo,
+                                                                   as_tpu):
+    """The delta-rule step of each of the cell's four linear layers is the
+    Pallas kernel `gated_delta_tick` on the carried pool itself: what has
+    the pool's shape is the parameter and the kernel's aliased outputs —
+    no layer's select + dynamic-update-slice write (which read the state
+    a second time), no S^T k / S^T q reduction fusion over [128, 64, 128]
+    in the step's scope, no pool-sized temporary — and the tick is no
+    larger than the `jnp` step's 13,262,326,272 B (0.39 GB of
+    temporaries)."""
+    fam, cfg, params, cache = _gigachat3_5_cell(topo)
+    compiled = _gigachat3_5_tick(topo, fam, cfg, params, cache, 128)
+    text = compiled.as_text()
+    lines = text.splitlines()
+    assert sum(1 for ln in lines if " custom-call(" in ln
+               and "gated_delta_tick" in ln) == 4
+    pool = "= f32[4,128,64,128,128]"
+    assert {re.search(r"\} ([\w-]+)\(", ln).group(1)
+            for ln in lines if pool in ln} <= {"parameter",
+                                               "get-tuple-element"}
+    assert "select_dynamic-update-slice" not in text
+    assert [ln.strip()[:160] for ln in lines
+            if "= f32[128,64,128]" in ln and " reduce(" in ln
+            and "gated_delta/step" in ln] == []
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 0.39e9
+    assert _device_bytes(compiled) <= 13.26e9
+
+
 def test_gigachat3_5_tick_and_prompt_bucket_fit_and_move_no_pool(topo,
                                                                  as_tpu):
     """The gigachat3.5 cell's two largest bodies (128 slots x 8,192; layers
@@ -446,21 +517,12 @@ def test_gigachat3_5_tick_and_prompt_bucket_fit_and_move_no_pool(topo,
     bucket, whose chunked delta rule makes each chunk's arrays inside its
     step (made for every chunk ahead of the walk they held 0.78 GB more).
     What `sizing.compile_bytes` of the configuration file states."""
-    from paddle_tpu.inference.serving import (_decode_tick, _prefill_slot,
-                                              family_for)
-    from paddle_tpu.models import gigachat3_5 as m
-    cfg = m.GigaChat35Config(num_layers=5, full_attention_layers=(4,),
-                             first_k_dense_replace=1, experts_held=16,
-                             vocab_size=16032, max_seq_len=8192)
-    fam = family_for("gigachat3_5")
+    from paddle_tpu.inference.serving import _prefill_slot
+    fam, cfg, params, cache = _gigachat3_5_cell(topo)
     one = SingleDeviceSharding(topo.devices[0])
-    slots, max_len = 128, 8192
-    params = _on(one, jax.eval_shape(lambda: m.init_gigachat3_5_params(
-        cfg, jax.random.PRNGKey(0))))
+    slots = 128
     assert sum(int(np.prod(v.shape)) for v in params.values()) \
         == 4_731_722_752
-    cache = _on(one, jax.eval_shape(
-        lambda: m.init_cache(cfg, slots, max_len)))
     pools = {k: v for k, v in cache.items() if k != "stats"}
     assert pools["dn_state"].shape == (4, 128, 64, 128, 128)
     assert pools["dn_state"].dtype == jnp.float32
@@ -470,16 +532,7 @@ def test_gigachat3_5_tick_and_prompt_bucket_fit_and_move_no_pool(topo,
                for v in pools.values())
     assert held == 3_405_774_848
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one)
-    state = tuple(S((slots,), dt) for dt in (
-        jnp.int32, jnp.int32, jnp.bool_, jnp.float32, jnp.int32,
-        jnp.int32, jnp.int32))
-    tick = jax.jit(
-        functools.partial(_decode_tick, fwd=fam.forward_cached, cfg=cfg,
-                          max_top_k=0, guard=True, oor_pos=None,
-                          cache_pin=None, tele=True),
-        donate_argnums=(1, 2), static_argnames=("sampling",))
-    compiled = tick.lower(params, cache, state, S((2,), jnp.uint32),
-                          S((slots,), jnp.float32), sampling=False).compile()
+    compiled = _gigachat3_5_tick(topo, fam, cfg, params, cache, slots)
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= held           # donated through
     text = compiled.as_text()

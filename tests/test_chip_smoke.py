@@ -44,7 +44,8 @@ def test_rehearsal_runs_every_one_chip_phase():
     assert set(phases["kernels"]["kernels"]) >= {
         "flash_fwd_hd64", "flash_bwd_hd128", "flash_tiled_hd64",
         "flash_tiled_hd128", "jax_flash", "splash", "ce",
-        "ce_fused", "fused_adamw", "decode_live_blocks", "mla_live_blocks"}
+        "ce_fused", "fused_adamw", "decode_live_blocks", "mla_live_blocks",
+        "gated_delta_tick"}
     losses = phases["train"]["losses"]
     assert losses[1] < losses[0]
     variants = phases["serve"]["variants"]

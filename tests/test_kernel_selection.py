@@ -29,6 +29,7 @@ from paddle_tpu.inference import spec_decode as sd
 from paddle_tpu.inference.serving import ServingEngine
 from paddle_tpu.kernels import decode_attention as da
 from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels import gated_delta as gd
 from paddle_tpu.kernels import pallas_ce, pallas_update
 from paddle_tpu.kernels import quant_matmul as qm
 from paddle_tpu.models import cohere2_moe, gpt, llama, losses
@@ -136,6 +137,13 @@ SITES = {
                    da.prompt_kernel_engages(8192, 8192, 32, 32, 192, 128,
                                             jnp.bfloat16)),
         {"tpu": (True, True, False), "cpu": (False, False, False)}),
+    # the delta-rule step of GigaChat3.5's tick: 64 value heads of
+    # 128 x 128 float32 state; the same in bfloat16; 64-wide heads
+    "delta_rule_tick_kernel": (
+        lambda m: (gd.tick_kernel_engages(64, 128, 128, jnp.float32),
+                   gd.tick_kernel_engages(64, 128, 128, jnp.bfloat16),
+                   gd.tick_kernel_engages(64, 64, 64, jnp.float32)),
+        {"tpu": (True, False, False), "cpu": (False, False, False)}),
     "quant_matmul": (lambda m: (qm.resolve_quant("auto"),
                                 qm.matmul_impl()),
                      {"tpu": (False, "xla"), "cpu": (False, "xla")}),

@@ -100,6 +100,58 @@ def test_the_hybrid_tick_and_prompt_name_their_phases():
         assert not any(s in text for s in other)
 
 
+def _eqns(jaxpr, outer=""):
+    """Every equation with its whole scope path, into the bodies of scans,
+    loops and nested jits."""
+    for e in jaxpr.eqns:
+        scope = "/".join(p for p in (outer, str(e.source_info.name_stack))
+                         if p)
+        yield e, scope
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else (p,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if isinstance(inner, jax.extend.core.Jaxpr):
+                    yield from _eqns(inner, scope)
+
+
+def test_the_hybrid_ticks_state_kernel_sits_in_its_step_scope(monkeypatch):
+    """gigachat3_5 where the delta-rule tick kernel engages (a TPU, 128-wide
+    heads; here in the interpreter): one `pallas_call` named
+    `gated_delta_tick` a linear layer of the tick, each under the tick's
+    `gated_delta/step` scope, so the device view books it there and
+    `breakdown.device_ops` lists it by name; none in a prompt."""
+    import paddle_tpu.device as device
+    from paddle_tpu.kernels import gated_delta as gd
+    from paddle_tpu.models import gigachat3_5 as m
+    monkeypatch.setattr(device, "is_tpu", lambda: True)
+    monkeypatch.setattr(m, "gated_delta_tick", functools.partial(
+        gd.gated_delta_tick, interpret=True))
+    cfg = m.GigaChat35Config(
+        vocab_size=64, hidden_size=32, num_layers=3,
+        full_attention_layers=(2,), num_heads=2, q_lora_rank=16,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+        v_head_dim=8, linear_num_key_heads=1, linear_num_value_heads=2,
+        linear_key_head_dim=128, linear_value_head_dim=128, ffn_hidden=48,
+        moe_ffn_hidden=16, first_k_dense_replace=1, n_routed_experts=8,
+        experts_held=4, num_experts_per_tok=2, max_seq_len=32,
+        dtype=jnp.float32, param_dtype=jnp.float32, prefill_chunk=8)
+    params = m.init_gigachat3_5_params(cfg, jax.random.PRNGKey(0))
+    tick = jax.make_jaxpr(lambda c: m.gigachat3_5_forward_cached(
+        params, jnp.zeros((2, 1), jnp.int32), c, jnp.zeros((2,), jnp.int32),
+        cfg))(m.init_cache(cfg, 2, 16))
+    calls = [(e, scope) for e, scope in _eqns(tick.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == cfg.layers_of(m.LINEAR) == 2
+    for e, scope in calls:
+        assert e.params["name"] == "gated_delta_tick"
+        assert scope.startswith("gated_delta/step/"), scope
+    prompt = jax.make_jaxpr(lambda c: m.gigachat3_5_forward_cached(
+        params, jnp.zeros((1, 16), jnp.int32), c, 0, cfg))(
+        m.init_cache(cfg, 1, 16))
+    assert not [e for e, _ in _eqns(prompt.jaxpr)
+                if e.primitive.name == "pallas_call"]
+
+
 def _flash_fwd():
     from paddle_tpu.kernels.pallas_attention import mha_fwd
     q = jnp.zeros((1, 128, 2, 64), jnp.float32)
@@ -184,6 +236,16 @@ def _prompt_flash():
         prompt_flash, window=100, interpret=True))(q, kv, kv)
 
 
+def _gated_delta_tick():
+    from paddle_tpu.kernels import gated_delta as gd
+    pool = jnp.zeros((2, 2, 2, 128, 128), jnp.float32)
+    rows = jnp.zeros((2, 2, 128), jnp.float32)
+    heads = jnp.zeros((2, 2), jnp.float32)
+    return jax.make_jaxpr(lambda pool: gd.gated_delta_tick(
+        pool, 1, rows, rows, rows, heads, heads, jnp.ones((2,), bool),
+        interpret=True))(pool)
+
+
 KERNELS = {
     "flash_fwd": _flash_fwd,
     "flash_bwd_dq": _flash_bwd,
@@ -198,6 +260,7 @@ KERNELS = {
     "decode_attention_live_blocks": _decode_live_blocks,
     "mla_absorbed_live_blocks": _mla_live_blocks,
     "prompt_flash_fwd": _prompt_flash,
+    "gated_delta_tick": _gated_delta_tick,
 }
 
 
