@@ -113,6 +113,64 @@ SMOKE_FILES = {
 }
 
 
+# ------------------------------------------------------------ compile effort
+# Most of the suite's time is XLA:CPU compiling small programs it then runs
+# once. `jax_disable_most_optimizations` lowers only the backend's LLVM
+# effort (HLO passes, fusion and buffer assignment are unchanged) and
+# roughly halves a compile-bound test. Modules that read what a compile
+# produced keep jax's default options; so does the benchmark's own
+# rehearsal under tests/benchmark/, which this harness leaves as it is.
+DEFAULT_COMPILE_FILES = {
+    # compiles the chip's programs for a described v5e: memory fit and
+    # the tick's aliasing are read off the compiled executables
+    "test_chip_compile.py",
+    # reads the sharding markup of a lowered program
+    "test_auto_parallel.py",
+    # compiled.memory_analysis() / cost_analysis() against the ledgers
+    "test_mem_observability.py",
+    # ranks plans by their measured CPU step times
+    "test_auto_parallel_planner.py",
+    # profiler.hlo_audit reads the compiled HLO's collectives
+    "test_67b_lowering.py", "test_plan4d.py", "test_train_observability.py",
+    # XLA cost analysis (paddle.flops, cost_model.CostModel)
+    "test_hub_costmodel.py",
+    # profiler.mem_audit reads compiled.memory_analysis()
+    "test_serving_weights.py",
+    # a kernel in the interpreter against XLA's matmul, bit for bit
+    "test_quant_serving.py",
+}
+_light_since_clear = False
+
+
+def compile_light(path) -> bool:
+    """Whether the tests of the module at `path` (under tests/) compile
+    with the light XLA:CPU setting."""
+    import pathlib
+    rel = pathlib.Path(path).resolve().relative_to(
+        pathlib.Path(__file__).resolve().parent)
+    return rel.parts[0] != "benchmark" and rel.name not in DEFAULT_COMPILE_FILES
+
+
+def set_compile_effort(light: bool):
+    """Switch the process's compiles to or from the light setting. The flag
+    is not part of jax's jit key, so entering a default module after a
+    light one clears jax's caches: no executable compiled light is reused
+    where the default is checked."""
+    global _light_since_clear
+    if light:
+        _light_since_clear = True
+    elif _light_since_clear:
+        jax.clear_caches()
+        _light_since_clear = False
+    jax.config.update("jax_disable_most_optimizations", light)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compile_effort(request):
+    set_compile_effort(compile_light(request.path))
+    yield
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "smoke: fast cross-subsystem slice (<5 min; see conftest)")

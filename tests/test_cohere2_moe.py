@@ -14,6 +14,7 @@ RoPE on a full layer each move logits by 1e-2 and more at these sizes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -87,17 +88,16 @@ def serve_logits(cfg, params, cache, slot, prompt, forced):
     slots = cache["k"].shape[1]
     padded = np.zeros((1, _bucket(len(prompt))), np.int32)
     padded[0, :len(prompt)] = prompt
-    first, cache = jax.jit(m.prefill_into_slot, static_argnums=5)(
+    first, cache = _prefill(
         params, cache, jnp.asarray(padded), jnp.int32(len(prompt)),
         jnp.int32(slot), _Static(cfg))
     rows = [np.asarray(first[0])]
-    step = jax.jit(m.cohere2_moe_forward_cached, static_argnums=4)
     pos = np.zeros(slots, np.int32)
     toks = np.zeros((slots, 1), np.int32)
     for i, tok in enumerate(forced):
         pos[slot], toks[slot, 0] = len(prompt) + i, tok
-        logits, cache = step(params, jnp.asarray(toks), cache,
-                             jnp.asarray(pos), _Static(cfg))
+        logits, cache = _step(params, jnp.asarray(toks), cache,
+                              jnp.asarray(pos), _Static(cfg))
         rows.append(np.asarray(logits[slot, 0]))
     return np.stack(rows), cache
 
@@ -119,10 +119,23 @@ class _Static:
         return getattr(self.cfg, name)
 
 
+_prefill = jax.jit(m.prefill_into_slot, static_argnums=5)
+_step = jax.jit(m.cohere2_moe_forward_cached, static_argnums=4)
+_forward = jax.jit(m.cohere2_moe_forward_cached, static_argnums=(3, 4))
+
+
+@functools.lru_cache(maxsize=None)
+def _reference(kw_items):
+    kw = dict(kw_items)
+    return jax.jit(lambda params, seq: ref.forward(params, seq, **kw))
+
+
 def reference_rows(cfg, params, prompt, forced):
+    """The reference's logits from the last prompt position on, one
+    program a model."""
     seq = np.concatenate([prompt, forced]).astype(np.int32)
-    logits = ref.forward(params, jnp.asarray(seq), **ref_kw(cfg))
-    return np.asarray(logits[len(prompt) - 1:])
+    fn = _reference(tuple(sorted(ref_kw(cfg).items())))
+    return np.asarray(fn(params, jnp.asarray(seq))[len(prompt) - 1:])
 
 
 # ------------------------------------------------------- parity, logits
@@ -173,8 +186,8 @@ def test_a_long_prompt_keeps_its_last_window_positions(setup):
     ordered = m.init_kv_cache(cfg, 1, 32, ring=False)
     padded = np.zeros((1, 32), np.int32)
     padded[0, :21] = prompt
-    _, ordered = m.cohere2_moe_forward_cached(
-        params, jnp.asarray(padded), ordered, 0, cfg)
+    _, ordered = _forward(params, jnp.asarray(padded), ordered, 0,
+                          _Static(cfg))
     assert cache["k_win"].shape[2] == WINDOW
     for row in range(WINDOW):
         position = max(p for p in range(21) if p % WINDOW == row)
@@ -225,6 +238,8 @@ def test_ring_writes_and_positions():
 
 def test_blocked_attention_matches_dense_scores():
     from paddle_tpu.kernels.decode_attention import blocked_attention
+    blocked_attention = jax.jit(blocked_attention,
+                                static_argnames=("window", "block"))
     key = jax.random.PRNGKey(0)
     q = jax.random.normal(key, (2, 32, 4, 8))
     k = jax.random.normal(jax.random.fold_in(key, 1), (2, 32, 2, 8))
@@ -252,12 +267,15 @@ def _expert_weights(held, d=16, f=24, seed=0):
                                        (held, f, d)])]
 
 
+_expert = jax.jit(ref.expert)
+
+
 def _by_loop(x, choice, weight, gate_w, up_w, down_w, first):
     out = np.zeros(x.shape, np.float64)
     for t in range(x.shape[0]):
         for c, w in zip(np.asarray(choice[t]), np.asarray(weight[t])):
             if first <= c < first + gate_w.shape[0]:
-                out[t] += w * np.asarray(ref.expert(
+                out[t] += w * np.asarray(_expert(
                     x[t], gate_w[c - first], up_w[c - first],
                     down_w[c - first]))
     return out

@@ -115,6 +115,14 @@ _prefill = jax.jit(m.prefill_into_slot, static_argnums=5)
 _step = jax.jit(m.gigachat3_5_forward_cached, static_argnums=4)
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _whole_prompt(params, cfg, tokens):
+    """One prompt from position 0 into an empty single-row cache of its own
+    length -> its logits."""
+    return m.gigachat3_5_forward_cached(
+        params, tokens, m.init_cache(cfg, 1, tokens.shape[1]), 0, cfg)[0]
+
+
 def prefill(cfg, params, cache, slot, prompt, pad_with=0):
     padded = np.full((1, _bucket(len(prompt))), pad_with, np.int32)
     padded[0, :len(prompt)] = prompt
@@ -177,9 +185,7 @@ def test_the_prompt_path_matches_the_reference(setup, length):
     latent attention, every position's logits."""
     cfg, params = setup
     tokens = _tokens(length, 3)
-    got, _ = m.gigachat3_5_forward_cached(
-        params, jnp.asarray(tokens[None]), m.init_cache(cfg, 1, length), 0,
-        cfg)
+    got = _whole_prompt(params, _Static(cfg), jnp.asarray(tokens[None]))
     want = reference_logits(cfg, params, tokens)
     assert np.abs(want).max() > 0.3            # logits worth comparing
     np.testing.assert_allclose(np.asarray(got[0]), want, atol=TOL, rtol=0)

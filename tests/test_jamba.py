@@ -16,6 +16,8 @@ test of the scan's section plants two of them).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,8 +28,7 @@ from paddle_tpu.inference.router import create_router
 from paddle_tpu.inference.serving import (REFUSABLE, ServingEngine,
                                           UnsupportedOptionError,
                                           family_for)
-from paddle_tpu.kernels.selective_scan import (selective_scan,
-                                               selective_state_update)
+from paddle_tpu.kernels import selective_scan as ss
 from paddle_tpu.models import jamba as m
 from paddle_tpu.profiler import clear_profiler_spans, get_profiler_spans
 from paddle_tpu.quantization.serving import (COMPUTE_LEAVES,
@@ -103,13 +104,31 @@ class _Static:
         return getattr(self.cfg, name)
 
 
+_prefill = jax.jit(m.prefill_into_slot, static_argnums=5)
+_step = jax.jit(m.jamba_forward_cached, static_argnums=4)
+_forward = jax.jit(m.jamba_forward_cached, static_argnums=(3, 4))
+selective_scan = jax.jit(ss.selective_scan, static_argnums=8)
+selective_state_update = jax.jit(ss.selective_state_update)
+
+
+@functools.lru_cache(maxsize=None)
+def _reference(kw_items):
+    kw = dict(kw_items)
+    return jax.jit(lambda params, seq: ref.forward(params, seq, **kw))
+
+
+def reference_logits(cfg, params, seq):
+    """The reference's logits [len(seq), V], one program a model."""
+    fn = _reference(tuple(sorted(ref_kw(cfg).items())))
+    return fn(params, jnp.asarray(seq))
+
+
 def _decode_on(cfg, params, cache, slot, position, forced, others=None):
     """`slot` decodes `forced`, fed back token by token from `position` on
     through the per-row-position tick every slot rides -> (logits after
     each, the pools). `others` maps other slots to (position, token) rows
     that decode alongside, LIVE; every slot else is idle."""
     slots = cache["k"].shape[1]
-    step = jax.jit(m.jamba_forward_cached, static_argnums=4)
     pos, toks = np.zeros(slots, np.int32), np.zeros((slots, 1), np.int32)
     live = np.zeros((slots, 1), bool)
     live[slot] = True
@@ -118,9 +137,9 @@ def _decode_on(cfg, params, cache, slot, position, forced, others=None):
     rows = []
     for i, tok in enumerate(forced):
         pos[slot], toks[slot, 0] = position + i, tok
-        logits, cache = step(params, jnp.asarray(toks), cache,
-                             jnp.asarray(pos), _Static(cfg),
-                             live=jnp.asarray(live))
+        logits, cache = _step(params, jnp.asarray(toks), cache,
+                              jnp.asarray(pos), _Static(cfg),
+                              live=jnp.asarray(live))
         rows.append(np.asarray(logits[slot, 0]))
         for other in (others or {}):
             pos[other] += 1
@@ -134,7 +153,7 @@ def serve_logits(cfg, params, cache, slot, prompt, forced, others=None,
     and after each forced token, the pools)."""
     padded = np.zeros((1, bucket or _bucket(len(prompt))), np.int32)
     padded[0, :len(prompt)] = prompt
-    first, cache = jax.jit(m.prefill_into_slot, static_argnums=5)(
+    first, cache = _prefill(
         params, cache, jnp.asarray(padded), jnp.int32(len(prompt)),
         jnp.int32(slot), _Static(cfg))
     rest, cache = _decode_on(cfg, params, cache, slot, len(prompt), forced,
@@ -144,8 +163,7 @@ def serve_logits(cfg, params, cache, slot, prompt, forced, others=None,
 
 def reference_rows(cfg, params, prompt, forced):
     seq = np.concatenate([prompt, forced]).astype(np.int32)
-    logits = ref.forward(params, jnp.asarray(seq), **ref_kw(cfg))
-    return np.asarray(logits[len(prompt) - 1:])
+    return np.asarray(reference_logits(cfg, params, seq)[len(prompt) - 1:])
 
 
 # ------------------------------------------------------- parity, logits
@@ -191,12 +209,11 @@ def test_a_run_continues_from_the_state_its_cache_holds(setup):
     cfg, params = setup
     seq = _tokens(29, 6)
     cache = m.init_cache(cfg, 1, 64)
-    a, cache = m.jamba_forward_cached(params, jnp.asarray(seq[None, :13]),
-                                      cache, 0, cfg)
-    b, cache = m.jamba_forward_cached(params, jnp.asarray(seq[None, 13:]),
-                                      cache, jnp.asarray([13], jnp.int32),
-                                      cfg)
-    want = ref.forward(params, jnp.asarray(seq), **ref_kw(cfg))
+    a, cache = _forward(params, jnp.asarray(seq[None, :13]), cache, 0,
+                        _Static(cfg))
+    b, cache = _step(params, jnp.asarray(seq[None, 13:]), cache,
+                     jnp.asarray([13], jnp.int32), _Static(cfg))
+    want = reference_logits(cfg, params, seq)
     np.testing.assert_allclose(np.concatenate([a[0], b[0]]), want, atol=TOL,
                                rtol=0)
 
@@ -223,16 +240,16 @@ def test_attention_has_one_kv_head_and_no_positions(setup):
     seq = _tokens(9, 8)
     swapped = seq.copy()
     swapped[[1, 5]] = seq[[5, 1]]
-    logits = [m.jamba_forward_cached(
-        params, jnp.asarray(s[None]), m.init_cache(only, 1, 16), 0, only)[0]
-        for s in (seq, swapped)]
+    logits = [_forward(params, jnp.asarray(s[None]),
+                       m.init_cache(only, 1, 16), 0, _Static(only))[0]
+              for s in (seq, swapped)]
     np.testing.assert_allclose(logits[0][0, -1], logits[1][0, -1],
                                atol=1e-6, rtol=0)
     # and with Mamba layers in the stack they do
     cfg, params = setup
-    logits = [m.jamba_forward_cached(
-        params, jnp.asarray(s[None]), m.init_cache(cfg, 1, 16), 0, cfg)[0]
-        for s in (seq, swapped)]
+    logits = [_forward(params, jnp.asarray(s[None]),
+                       m.init_cache(cfg, 1, 16), 0, _Static(cfg))[0]
+              for s in (seq, swapped)]
     assert np.abs(np.asarray(logits[0][0, -1] - logits[1][0, -1])).max() \
         > 1e-3
 

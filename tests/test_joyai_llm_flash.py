@@ -110,6 +110,26 @@ _prefill = jax.jit(m.prefill_into_slot, static_argnums=5)
 _step = jax.jit(m.joyai_llm_flash_forward_cached, static_argnums=4)
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _whole_prompt(params, cfg, tokens):
+    """Prompts from position 0 into an empty cache of their own length ->
+    their logits."""
+    return m.joyai_llm_flash_forward_cached(
+        params, tokens, m.init_cache(cfg, *tokens.shape), 0, cfg)[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _reference(arch_items):
+    arch = dict(arch_items)
+    return jax.jit(lambda params, seq: ref.forward(params, seq, arch))
+
+
+def reference_logits(cfg, params, seq):
+    """The reference's logits [len(seq), V], one program a model."""
+    fn = _reference(tuple(sorted(arch_of(cfg).items())))
+    return np.asarray(fn(params, jnp.asarray(seq)))
+
+
 def prefill(cfg, params, cache, slot, prompt, pad_with=0):
     padded = np.full((1, _bucket(len(prompt))), pad_with, np.int32)
     padded[0, :len(prompt)] = prompt
@@ -145,8 +165,7 @@ def serve_logits(cfg, params, cache, slot, prompt, forced, others=None):
 
 def reference_rows(cfg, params, prompt, forced):
     seq = np.concatenate([prompt, forced]).astype(np.int32)
-    logits = ref.forward(params, jnp.asarray(seq), arch_of(cfg))
-    return np.asarray(logits[len(prompt) - 1:])
+    return reference_logits(cfg, params, seq)[len(prompt) - 1:]
 
 
 # ------------------------------------------------------- parity, logits
@@ -156,10 +175,8 @@ def test_the_prompt_path_matches_the_reference(setup, length):
     logits: one, two and four chunks of the FFN, one block of attention."""
     cfg, params = setup
     tokens = _tokens(length, 3)
-    got, _ = m.joyai_llm_flash_forward_cached(
-        params, jnp.asarray(tokens[None]), m.init_cache(cfg, 1, length), 0,
-        cfg)
-    want = np.asarray(ref.forward(params, jnp.asarray(tokens), arch_of(cfg)))
+    got = _whole_prompt(params, _Static(cfg), jnp.asarray(tokens[None]))
+    want = reference_logits(cfg, params, tokens)
     assert np.abs(want).max() > 0.3            # logits worth comparing
     np.testing.assert_allclose(np.asarray(got[0]), want, atol=TOL, rtol=0)
 
@@ -242,8 +259,7 @@ def test_the_tick_and_the_prompt_path_agree_on_the_next_position(setup):
     (decompressed) and the tick after a prefill of n (absorbed)."""
     cfg, params = setup
     tokens = _tokens(16, 12)
-    whole, _ = m.joyai_llm_flash_forward_cached(
-        params, jnp.asarray(tokens[None]), m.init_cache(cfg, 1, 16), 0, cfg)
+    whole = _whole_prompt(params, _Static(cfg), jnp.asarray(tokens[None]))
     ticked, _ = serve_logits(cfg, params, m.init_cache(cfg, 2, 64), 0,
                              tokens[:15], tokens[15:])
     np.testing.assert_allclose(ticked[1], np.asarray(whole[0, 15]),
@@ -412,6 +428,13 @@ def test_a_stream_is_slot_and_batch_invariant(setup, company):
 
 
 # ------------------------------------------------ multi-token prediction
+@functools.partial(jax.jit, static_argnums=4)
+def _mtp(params, seqs, next_tokens, positions, cfg):
+    hidden, _ = m._hidden(params, seqs, m.init_cache(cfg, *seqs.shape), 0,
+                          cfg)
+    return m.mtp_logits(params, hidden, next_tokens, positions, cfg)
+
+
 def test_mtp_logits_match_the_reference(setup):
     """(g) the module over whole sequences, two of them, against the
     reference's one at a time; and it is a second head: the main logits do
@@ -422,23 +445,21 @@ def test_mtp_logits_match_the_reference(setup):
                            "mtp_exp_gate_w"}
     seqs = np.stack([_tokens(17, 22), _tokens(17, 23)])
     positions = jnp.broadcast_to(jnp.arange(16), (2, 16))
-    hidden, _ = m._hidden(params, jnp.asarray(seqs[:, :16]),
-                          m.init_cache(cfg, 2, 16), 0, cfg)
-    got = np.asarray(m.mtp_logits(params, hidden,
-                                  jnp.asarray(seqs[:, 1:17]), positions,
-                                  cfg))
+    got = np.asarray(_mtp(params, jnp.asarray(seqs[:, :16]),
+                          jnp.asarray(seqs[:, 1:17]), positions,
+                          _Static(cfg)))
     arch = arch_of(cfg)
+    ref_mtp = jax.jit(lambda p, seq, nxt, positions: ref.mtp_logits(
+        p, ref.hidden(p, seq, arch), nxt, positions, arch))
     for b in range(2):
-        h = ref.hidden(params, jnp.asarray(seqs[b, :16]), arch)
-        want = np.asarray(ref.mtp_logits(
-            params, h, jnp.asarray(seqs[b, 1:17]), jnp.arange(16), arch))
+        want = np.asarray(ref_mtp(params, jnp.asarray(seqs[b, :16]),
+                                  jnp.asarray(seqs[b, 1:17]),
+                                  jnp.arange(16)))
         assert np.abs(want).max() > 0.3
         np.testing.assert_allclose(got[b], want, atol=TOL, rtol=0)
     main = _serving_params(params)
-    a, _ = m.joyai_llm_flash_forward_cached(
-        main, jnp.asarray(seqs[:1, :16]), m.init_cache(cfg, 1, 16), 0, cfg)
-    b, _ = m.joyai_llm_flash_forward_cached(
-        params, jnp.asarray(seqs[:1, :16]), m.init_cache(cfg, 1, 16), 0, cfg)
+    a = _whole_prompt(main, _Static(cfg), jnp.asarray(seqs[:1, :16]))
+    b = _whole_prompt(params, _Static(cfg), jnp.asarray(seqs[:1, :16]))
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -691,12 +712,14 @@ def test_kv_read_layer_is_the_work_lists_blocks_where_the_kernel_runs(
     pos = jnp.asarray([3, block - 1, block, 9], jnp.int32)
     live = jnp.asarray([True, True, True, False])[:, None]
     toks = jnp.ones((4, 1), jnp.int32)
-    _, plain = m.joyai_llm_flash_forward_cached(params, toks, cache, pos,
-                                                cfg, live)
+
+    def tick():             # traced anew: is_tpu() decides what it takes
+        forward = functools.partial(m.joyai_llm_flash_forward_cached, cfg=cfg)
+        return jax.jit(forward)(params, toks, cache, pos, live=live)[1]
+    plain = tick()
     assert [int(v) for v in plain["stats"][2:]] == [8 * block, 8 * block]
     _as_tpu(monkeypatch)
-    _, cache = m.joyai_llm_flash_forward_cached(params, toks, cache, pos,
-                                                cfg, live)
+    cache = tick()
     assert [int(v) for v in cache["stats"][2:]] == [4 * block, 8 * block]
     counts = m.span_counts(cfg, cache["stats"])
     assert counts["kv_positions_read"] == 2 * 4 * block

@@ -329,13 +329,14 @@ def test_the_engine_serves_the_references_tokens_and_counts_what_it_read(
                 first_expert=cfg.first_expert,
                 norm_topk_prob=cfg.norm_topk_prob,
                 routed_scaling_factor=cfg.routed_scaling_factor)
+    forward = jax.jit(lambda p, seq: ref.forward(p, seq, arch))
     for prompt, tokens in zip(prompts, got):
         # causal: padded to whole blocks of the reference's query rows
         n = len(prompt) + gen - 1
         seq = np.zeros(-(-n // ref.QUERY_ROWS) * ref.QUERY_ROWS, np.int32)
         seq[:n] = np.concatenate([prompt, tokens[:-1]])
-        rows = np.asarray(ref.forward(params, jnp.asarray(seq), arch)
-                          )[len(prompt) - 1:n]
+        rows = np.asarray(forward(params, jnp.asarray(seq)))[
+            len(prompt) - 1:n]
         top2 = np.sort(rows, axis=-1)[:, -2:]
         clear = top2[:, 1] - top2[:, 0] > 1e-4
         assert clear.sum() >= gen - 1
